@@ -286,6 +286,12 @@ class ClusterBus:
             if link.task is not None:
                 link.task.cancel()
             link._drop_conn()
+        # Readers first: since Python 3.12 `wait_closed()` waits for every
+        # accepted connection to finish, and a reader parked in `read()`
+        # never does — two buses stopping together then wait on each
+        # other's readers forever.
+        for t in list(self._reader_tasks):
+            t.cancel()
         if self._server is not None:
             self._server.close()
             try:
@@ -293,8 +299,6 @@ class ClusterBus:
             except Exception:
                 pass
             self._server = None
-        for t in list(self._reader_tasks):
-            t.cancel()
 
     # -------------------------------------------------------------- send
 

@@ -928,9 +928,13 @@ class ConsoleServer:
             n = min(256, max(1, int(request.query.get("n", 64))))
         except (TypeError, ValueError):
             return _err(400, "n must be an integer")
+        describe = getattr(backend, "describe", None)
         return web.json_response(
             {
                 **DEVOBS.stats(),
+                # Where the matchmaker's kernels really run: platform,
+                # and whether Pallas is interpreting (None = host oracle).
+                "backend": describe() if describe is not None else None,
                 "mesh": describe_mesh(
                     mesh,
                     pool_capacity=getattr(pool, "capacity", 0),

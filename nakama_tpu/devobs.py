@@ -57,6 +57,10 @@ from collections import deque
 from . import tracing as trace_api
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# Persistent compile cache (jaxenv.enable_compile_cache): one request
+# event per compile that consults it, one hit event per compile it saved.
+_CACHE_REQUEST_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 # Kernel name used for compiles that happen outside any device_call
 # context (library warmup, test scaffolding): counted, never judged.
@@ -194,8 +198,7 @@ class DeviceTelemetry:
     def __init__(self):
         self._lock = threading.Lock()
         self._tls = threading.local()
-        self._listener_installed = False  # install attempted (latch)
-        self._listener_active = False  # install actually succeeded
+        self._listener_active = False
         self.metrics = None
         self.logger = None
         self._apply_defaults()
@@ -217,6 +220,8 @@ class DeviceTelemetry:
         self._transfers: dict[tuple[str, str], list[int]] = {}
         self.compiles_total = 0
         self.recompiles_total = 0
+        self.cache_requests = 0
+        self.cache_hits = 0
 
     def configure(
         self,
@@ -304,25 +309,18 @@ class DeviceTelemetry:
         return clock
 
     def _ensure_listener(self) -> None:
-        if self._listener_installed or "jax" not in sys.modules:
+        if self._listener_active or "jax" not in sys.modules:
             return
         with self._lock:
-            if self._listener_installed:
+            if self._listener_active:
                 return
-            try:
-                from jax._src import monitoring as _mon
+            import jax.monitoring as monitoring
 
-                _mon.register_event_duration_secs_listener(
-                    _compile_listener
-                )
-                self._listener_active = True
-            except Exception:
-                # No monitoring surface in this jax build: kernel
-                # clocks and the memory ledger still work; compile
-                # counts stay zero, and stats() reports the listener
-                # as NOT active so zero reads as "can't", not "didn't".
-                self._listener_active = False
-            self._listener_installed = True
+            monitoring.register_event_duration_secs_listener(
+                _compile_listener
+            )
+            monitoring.register_event_listener(_cache_listener)
+            self._listener_active = True
 
     def device_call(self, kernel: str, expect_compile: bool = False):
         """Context manager timing one device call under `kernel` and
@@ -546,6 +544,8 @@ class DeviceTelemetry:
                 "total": self.compiles_total,
                 "recompiles_total": self.recompiles_total,
                 "listener": self._listener_active,
+                "cache_requests": self.cache_requests,
+                "cache_hits": self.cache_hits,
             },
             "memory": {
                 "by_owner": mem,
@@ -597,6 +597,15 @@ class DeviceTelemetry:
 def _compile_listener(event: str, duration: float, **kw) -> None:
     if event == _COMPILE_EVENT:
         DEVOBS.on_compile(duration)
+
+
+def _cache_listener(event: str, **kw) -> None:
+    if event == _CACHE_REQUEST_EVENT:
+        with DEVOBS._lock:
+            DEVOBS.cache_requests += 1
+    elif event == _CACHE_HIT_EVENT:
+        with DEVOBS._lock:
+            DEVOBS.cache_hits += 1
 
 
 # The process-wide plane (faults.PLANE precedent): configured by

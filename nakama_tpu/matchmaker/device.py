@@ -86,13 +86,13 @@ def _scatter(pool: dict, idx: jnp.ndarray, rows: dict) -> dict:
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
-def _invalidate(pool: dict, idx: jnp.ndarray) -> dict:
+def _invalidate(flags: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """Clear slots by flags alone — a removal needs no row data, so the
     H2D payload is 4 bytes/slot instead of a full ~600-byte empty row
-    (matched-ticket churn at the 100k bench is ~50k removals/interval)."""
-    out = dict(pool)
-    out["flags"] = pool["flags"].at[idx].set(0)
-    return out
+    (matched-ticket churn at the 100k bench is ~50k removals/interval).
+    Takes the flags column only, so prewarming one removal bucket costs
+    a scratch column, not a scratch pool."""
+    return flags.at[idx].set(0)
 
 
 class _SlotOfView:
@@ -309,10 +309,13 @@ class PoolBuffer:
 
     def prewarm(self):
         """Compile both add-scatter pad shapes (small tail + full chunk)
-        on a daemon thread: the first naturally-occurring small tail
-        otherwise pays its multi-second XLA compile inside a timed
-        interval (jit cache is process-wide; the dummy scatter rewrites
-        identical rows, a no-op on pool contents)."""
+        and every removal pad bucket on a daemon thread: the first
+        naturally-occurring small tail otherwise pays its multi-second
+        XLA compile inside a timed interval, and a removal count that
+        first falls in a new power-of-two bucket did the same whenever
+        it came (on the chip: interval 11 of a run whose other programs
+        came from the compile cache, PR 21). The jit cache is
+        process-wide; the dummy scatters touch scratch arrays only."""
         if getattr(self, "_prewarmed", False) or self.sharding is not None:
             # Sharded pools: a scratch clone would donate unsharded
             # buffers into the sharded scatter (warning + no reuse);
@@ -321,7 +324,7 @@ class PoolBuffer:
         self._prewarmed = True
         import threading
 
-        scatter = self._scatter
+        scatter, invalidate = self._scatter, self._invalidate
         shapes = {k: (v.shape, v.dtype) for k, v in self.device.items()}
 
         def _warm():
@@ -351,6 +354,12 @@ class PoolBuffer:
                         }
                         out = scatter(scratch, idx, rows)
                         jax.block_until_ready(out)
+                    shp, dt = shapes["flags"]
+                    for u_pad in self._removal_buckets():
+                        jax.block_until_ready(invalidate(
+                            jnp.zeros(shp, dt),
+                            jnp.zeros(u_pad, dtype=jnp.int32),
+                        ))
             except Exception as e:
                 # One-shot: a persistent failure (device OOM on the
                 # scratch clone) must not silently re-spawn an allocating
@@ -364,6 +373,25 @@ class PoolBuffer:
 
         self._prewarm_thread = threading.Thread(target=_warm, daemon=True)
         self._prewarm_thread.start()
+
+    def _removal_pad(self, u: int) -> int:
+        """Everything at or under one chunk pads to exactly the chunk
+        size: ONE compiled scatter shape covers the steady state (pow2
+        buckets above that). Distinct pow2 tails were costing a ~1.3s XLA
+        compile on scattered intervals, dominating the bench p99."""
+        if u <= self.flush_chunk:
+            return self.flush_chunk
+        return 1 << (u - 1).bit_length()
+
+    def _removal_buckets(self) -> list[int]:
+        """Every size `_removal_pad` can return for this pool."""
+        top = self._removal_pad(self.capacity)
+        out = [self.flush_chunk]
+        b = 1 << self.flush_chunk.bit_length()  # first pow2 above a chunk
+        while b <= top:
+            out.append(b)
+            b *= 2
+        return out
 
     def join_prewarm(self, timeout=None):
         t = getattr(self, "_prewarm_thread", None)
@@ -386,25 +414,19 @@ class PoolBuffer:
         self._pending_rm = []
         self._pending_rm_n = 0
 
-        # Everything at or under one chunk pads to exactly the chunk size:
-        # ONE compiled scatter shape covers the steady state (pow2 buckets
-        # above that). Distinct pow2 tails were costing a ~1.3s XLA compile
-        # on scattered intervals, dominating the bench p99.
-        def _pad(u: int) -> int:
-            if u <= self.flush_chunk:
-                return self.flush_chunk
-            return 1 << (u - 1).bit_length()
-
         if rm_parts:
             rm = np.concatenate(rm_parts).astype(np.int32, copy=False)
             u = len(rm)
-            u_pad = _pad(u)
+            u_pad = self._removal_pad(u)
             idx = np.empty(u_pad, dtype=np.int32)
             idx[:u] = rm
             idx[u:] = rm[-1]
             with DEVOBS.device_call("matchmaker.scatter"):
-                self.device = self._invalidate(
-                    self.device, jnp.asarray(idx)
+                self.device = dict(
+                    self.device,
+                    flags=self._invalidate(
+                        self.device["flags"], jnp.asarray(idx)
+                    ),
                 )
             DEVOBS.transfer("pool.flush", "h2d", int(idx.nbytes))
 
@@ -585,9 +607,7 @@ def scan_columns(
         jnp.full((br, k), -1, dtype=jnp.int32),
     )
     if varying_axis is not None:
-        from ..jaxcompat import pvary
-
-        init = pvary(init, varying_axis)
+        init = jax.lax.pcast(init, (varying_axis,), to="varying")
     (best_s, best_i), _ = jax.lax.scan(
         col_step, init, jnp.arange(n_col_blocks)
     )

@@ -25,8 +25,12 @@ Stage 2 (XLA): the per-block winners (n_col_blocks per row, ~64-128 at
 bench size) are gathered and re-checked *exactly* — full interval/term/
 forbidden compares, count-range, party/self/pool/validity, mutual (rev)
 when on, exact should-boost and embedding scores — then lexicographically
-sorted by (-score, created) on device. Stage-1 false positives die here;
-true candidates are never lost because stage 1 is a superset filter.
+sorted by (-score, created) on device. Stage-1 false positives die here.
+Stage 1's eligibility test is a superset filter, so it never rejects a
+true candidate; its per-block argmax can still drop one, when false
+positives of the same block outrank it (a required string term whose
+hash shares bucket 0 with tickets that lack the property makes the whole
+pool look eligible). Such a row waits for a later interval.
 
 The candidate lists feed the same native greedy assembler as the small-pool
 path. Reference hot loop replaced: server/matchmaker_process.go:27-334.
@@ -44,7 +48,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .device import FLAG_NEVER, FLAG_VALID
 from .device import _accepts  # exact per-field predicate (block form)
-from ..jaxcompat import pvary, shard_map, vma_struct
 
 NUM_BUCKETS = 16  # per numeric field
 STR_BUCKETS = 8  # per string field
@@ -54,6 +57,15 @@ MAX_COLS = 1 << COL_BITS
 PRIO_MAX = 8191  # 13-bit priority
 JITTER_AMP = 256  # selection-jitter range (stays below 1 emb-score unit)
 PACKED_NONE = -(2**31)  # plain int: pallas kernels must not capture arrays
+# Mosaic's scoped-VMEM limit for one kernel on a v5e (the compiler's
+# default; a kernel over it is refused at compile time, not at run time).
+VMEM_LIMIT_BYTES = 16 << 20
+MIN_ROW_TILE = 128
+# Stage 2 re-ranks the active rows in stripes sized so one stripe's
+# candidate gather stays under this many bytes: its temporaries then do
+# not grow with the pool (un-striped, a 131072-row dispatch needs 9 GB
+# of HBM without mutual matching and 32 GB with it).
+STAGE2_GATHER_BYTES = 256 << 20
 
 # Every pool field the row (query) side of the kernels reads.
 ROWQ_KEYS = (
@@ -264,12 +276,67 @@ def _stage1_kernel(
     out_ref[:] = acc
 
 
+def stage1_row_tile(bm: int, bn: int, d: int, de: int, dq: int,
+                    out_w: int) -> int:
+    """Largest row tile <= `bm` (halving) whose stage-1 footprint fits
+    the scoped-VMEM limit: every operand and output block double-buffered
+    at its lane-padded size, plus the [bm, bn] 32-bit epilogue tiles the
+    compiler keeps live (score, packed word, and at large grids most of
+    a third — 1.75 tiles bounds every shape the v5e compiler was asked
+    about, tests/test_chip_compile.py holds the shipped ones). Only the
+    ROW tile moves: rows are independent and the jitter keys on the row
+    index, so the winner set is the same at any `bm`; the column tile
+    decides which per-block winners survive and stays as configured."""
+
+    def lanes(w: int) -> int:
+        return -(-w // 128) * 128
+
+    def footprint(rows: int) -> int:
+        blocks = (
+            (rows + bn) * 2 * (lanes(d) + lanes(de) + lanes(dq))  # bf16
+            + 2 * 8 * bn * 4  # col_mix, col_gidx: [1, bn] i32, 8 sublanes
+            + 2 * rows * 128 * 4  # row_mix, row_slot: [rows, 1] i32
+            + rows * lanes(out_w) * 4
+        )
+        return 2 * blocks + 7 * rows * bn
+
+    tile = bm
+    while tile > MIN_ROW_TILE and footprint(tile) > VMEM_LIMIT_BYTES:
+        tile //= 2
+    if footprint(tile) > VMEM_LIMIT_BYTES:
+        raise ValueError(
+            f"stage-1 tile [{tile}, {bn}] at encoding width {d} (+{de}"
+            f" embedding, +{dq} mutual) needs {footprint(tile)} bytes of"
+            f" VMEM, over the {VMEM_LIMIT_BYTES} the kernel may use:"
+            " lower numeric_fields/string_fields or the column block"
+        )
+    return tile
+
+
+def stage1_plan(
+    *, n: int, n_local: int, k: int, bm: int, bn: int, fn: int, fs: int,
+    de: int, rev: bool,
+) -> tuple[int, int, int]:
+    """What one stage-1 launch does at these shapes: (winners kept per
+    column block, lane width of its packed-winner output, row tile).
+    `n` is the column extent the dispatch scores, `n_local` the part of
+    it one launch sees (== `n` off the mesh), `de` the embedding operand
+    width. Derived here only: the kernels, the mesh gather accounting
+    and the dispatch breadcrumb all read it."""
+    d = encoding_dims(fn, fs)
+    # Enough total candidate width even when the pool spans few blocks.
+    m = max(1, -(-2 * k // (n // bn)))
+    out_w = -(-(n_local // bn * m) // 128) * 128  # lane dim: 128-aligned
+    return m, out_w, stage1_row_tile(bm, bn, d, de, d if rev else 8, out_w)
+
+
 def _stage1_call(
     uq, vv, col_mix, col_gidx, row_mix, row_slot, ue, ve, uv, vq,
     *,
     fn: int,
     fs: int,
     m: int,
+    out_w: int,
     bm: int,
     bn: int,
     with_embedding: bool,
@@ -281,14 +348,14 @@ def _stage1_call(
     """One pallas stage-1 launch over the column range held in `vv`
     (the whole pool unsharded; one device's shard under the mesh —
     `vma` names the mesh axes the output varies over in that case).
-    Returns packed per-block winners [a_pad, out_w]."""
+    `m`, `out_w` and the row tile `bm` come from `stage1_plan`. Returns
+    packed per-block winners [a_pad, out_w]."""
     a_pad = uq.shape[0]
     n = vv.shape[0]
     d = encoding_dims(fn, fs)
     n_blocks = n // bn
     de = ue.shape[1]
     dq = uv.shape[1]
-    out_w = -(-(n_blocks * m) // 128) * 128  # lane-dim must be 128-aligned
     kernel = functools.partial(
         _stage1_kernel,
         f_tot=float(fn + fs + 1),
@@ -317,7 +384,7 @@ def _stage1_call(
         out_specs=pl.BlockSpec(
             (bm, out_w), lambda i, j: (i, 0), memory_space=pltpu.VMEM
         ),
-        out_shape=vma_struct((a_pad, out_w), jnp.int32, vma),
+        out_shape=jax.ShapeDtypeStruct((a_pad, out_w), jnp.int32, vma=vma),
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
             flops=2 * a_pad * n * (d + (de if with_embedding else 0)),
@@ -360,11 +427,6 @@ def topk_candidates_big(
     assert n_cols <= MAX_COLS
     a_pad = active_slots.shape[0]
     n = n_cols
-    d = encoding_dims(fn, fs)
-    n_blocks = n // bn
-    # Winners per block: enough total candidate width even when the pool
-    # spans few blocks.
-    m = max(1, -(-2 * k // n_blocks))
 
     pool_n = {key: v[:n] for key, v in pool.items()}
     safe = jnp.maximum(active_slots, 0)
@@ -395,12 +457,17 @@ def topk_candidates_big(
         uv = jnp.zeros((a_pad, 8), jnp.bfloat16)
         vq = jnp.zeros((n, 8), jnp.bfloat16)
 
+    m, out_w, tile = stage1_plan(
+        n=n, n_local=n, k=k, bm=bm, bn=bn, fn=fn, fs=fs, de=ue.shape[1],
+        rev=rev,
+    )
     winners = _stage1_call(
         uq, vv, col_mix, col_gidx, row_mix, row_slot, ue, ve, uv, vq,
         fn=fn,
         fs=fs,
         m=m,
-        bm=bm,
+        out_w=out_w,
+        bm=tile,
         bn=bn,
         with_embedding=with_embedding,
         rev=rev,
@@ -454,8 +521,12 @@ def topk_candidates_big_sharded(
     smaller than the score matrix), and ONE exact stage-2 re-rank runs on
     the merged set. Because the per-block winner count `m` derives from
     the GLOBAL block count and the packed words carry pool-global column
-    ids, the merged winner SET is identical to the unsharded kernel's —
-    sharding changes where the matmuls run, not what they select.
+    ids, the merged winner SET is identical to the unsharded kernel's
+    over the same column extent — sharding changes where the matmuls
+    run, not what they select. The extent here is always the whole
+    pool capacity; the single-device dispatch trims its own to the
+    high-water bucket (tpu.py), so a part-filled pool has fewer blocks
+    there and may keep more winners per block.
 
     Reference seam this replaces: the `node` string threaded through
     server/matchmaker.go:169-183 (cross-node matching absent in OSS)."""
@@ -467,8 +538,6 @@ def topk_candidates_big_sharded(
     assert n_local % bn == 0, (n_local, bn)
     assert n <= MAX_COLS
     a_pad = active_slots.shape[0]
-    n_blocks_global = n // bn
-    m = max(1, -(-2 * k // n_blocks_global))
 
     # Row (query) side: gathered across shards by GSPMD, then replicated —
     # every device scores ALL active rows against its column shard.
@@ -493,6 +562,10 @@ def topk_candidates_big_sharded(
         uv = _value_vectors(rowq, a_pad, fn, fs, grid_lo, grid_inv)
     else:
         uv = jnp.zeros((a_pad, 8), jnp.bfloat16)
+    m, out_w, tile = stage1_plan(
+        n=n, n_local=n_local, k=k, bm=bm, bn=bn, fn=fn, fs=fs,
+        de=ue.shape[1], rev=rev,
+    )
 
     # Column side: per-shard constants carrying GLOBAL column ids.
     col_idx = jnp.arange(n, dtype=jnp.int32)
@@ -505,12 +578,15 @@ def topk_candidates_big_sharded(
     )
     pool_cols = {key: pool[key] for key in sorted(set(col_keys))}
 
+    def varying(x):
+        return jax.lax.pcast(x, (axis,), to="varying")
+
     def per_device(pool_local, col_mix_l, col_gidx_l, uq, row_mix,
                    row_slot, ue, uv, grid_lo, grid_inv):
         # Replicated row-side inputs meet device-varying column data in
         # the kernel: mark them varying explicitly (vma typing).
-        (uq, row_mix, row_slot, ue, uv, grid_lo, grid_inv) = pvary(
-            (uq, row_mix, row_slot, ue, uv, grid_lo, grid_inv), axis
+        (uq, row_mix, row_slot, ue, uv, grid_lo, grid_inv) = varying(
+            (uq, row_mix, row_slot, ue, uv, grid_lo, grid_inv)
         )
         nloc = pool_local["num"].shape[0]
         vv_l = _value_vectors(pool_local, nloc, fn, fs, grid_lo, grid_inv)
@@ -519,18 +595,19 @@ def topk_candidates_big_sharded(
                 pool_local, fn, fs, grid_lo, grid_inv, with_counts=False
             )
         else:
-            vq_l = pvary(jnp.zeros((nloc, 8), jnp.bfloat16), axis)
+            vq_l = varying(jnp.zeros((nloc, 8), jnp.bfloat16))
         if with_embedding:
             ve_l = pool_local["emb"].astype(jnp.bfloat16)
         else:
-            ve_l = pvary(jnp.zeros((nloc, 8), jnp.bfloat16), axis)
+            ve_l = varying(jnp.zeros((nloc, 8), jnp.bfloat16))
         win = _stage1_call(
             uq, vv_l, col_mix_l, col_gidx_l, row_mix, row_slot, ue,
             ve_l, uv, vq_l,
             fn=fn,
             fs=fs,
             m=m,
-            bm=bm,
+            out_w=out_w,
+            bm=tile,
             bn=bn,
             with_embedding=with_embedding,
             rev=rev,
@@ -544,7 +621,7 @@ def topk_candidates_big_sharded(
 
     if with_embedding:
         pool_cols["emb"] = pool["emb"]
-    winners = shard_map(
+    winners = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(
@@ -556,7 +633,7 @@ def topk_candidates_big_sharded(
         # constants with empty vma and the checker rejects the mix — the
         # error text itself prescribes disabling the check as the
         # workaround. Real Mosaic lowering (TPU) keeps the check on.
-        check=not interpret,
+        check_vma=not interpret,
     )(
         pool_cols, col_mix, col_gidx, uq, row_mix, row_slot, ue, uv,
         grid_lo, grid_inv,
@@ -587,22 +664,51 @@ def _stage2(
     with_embedding, order_exact=True,
 ):
     """Exact re-rank of the per-block winners: [A_pad, B] packed → slots
-    [A_pad, k] ordered by (-score, created)."""
-    # Pre-trim the block winners to ~k by packed stage-1 priority BEFORE
-    # any gather: at an 8-pool 160k bench the [A, 256, F] gather of every
-    # pool field was a ~28 GB allocation (OOM on a 16 GB chip). The packed
-    # word sorts by (priority << COL_BITS | col), so top_k keeps the
-    # best-prioritised candidates; the exact re-rank below then orders the
-    # survivors precisely. Keep 2x headroom over k so bucket-granular
-    # false positives rarely crowd out true candidates.
-    keep = min(winners.shape[1], max(2 * k, 8))
-    if winners.shape[1] > keep:
-        winners, _ = jax.lax.top_k(winners, keep)
-    cand = winners & (MAX_COLS - 1)  # [A, B]
-    alive = winners != PACKED_NONE
+    [A_pad, k] ordered by (-score, created). Rows are independent, so the
+    pass runs stripe by stripe (lax.map) with identical output and
+    temporaries bounded by the stripe, not by A_pad."""
+    a_pad = active_slots.shape[0]
+    keep = min(winners.shape[1], max(2 * k, 8))  # see _stage2_rows
+    words = sum(
+        int(np.prod(pool_n[key].shape[1:]))
+        for key in _stage2_columns(rev)
+    )
+    stripe = a_pad
+    while (
+        stripe % 2 == 0
+        and stripe * keep * words * 4 > STAGE2_GATHER_BYTES
+    ):
+        stripe //= 2
 
-    # Gather only what the exact checks read — the candidate's VALUES and
-    # slot metadata always; its QUERY mirrors only under rev (mutual).
+    def rerank(args):
+        rq, act, win = args
+        return _stage2_rows(
+            pool_n, rq, act, win, k=k, keep=keep, rev=rev,
+            with_should=with_should, with_embedding=with_embedding,
+            order_exact=order_exact,
+        )
+
+    if stripe == a_pad:
+        return rerank((rowq, active_slots, winners))
+
+    def striped(x):
+        return x.reshape((a_pad // stripe, stripe) + x.shape[1:])
+
+    out = jax.lax.map(
+        rerank,
+        (
+            {key: striped(v) for key, v in rowq.items()},
+            striped(active_slots),
+            striped(winners),
+        ),
+    )
+    return out.reshape(a_pad, k)
+
+
+def _stage2_columns(rev: bool) -> list[str]:
+    """Pool columns the exact checks gather per candidate — the
+    candidate's VALUES and slot metadata always; its QUERY mirrors only
+    under rev (mutual)."""
     needed = [
         "num", "str", "emb", "min_count", "max_count", "party", "pool_id",
         "flags", "created",
@@ -612,7 +718,31 @@ def _stage2(
             "n_lo", "n_hi", "n_flo", "n_fhi", "s_req", "s_forb",
             "sh_op", "sh_fld", "sh_lo", "sh_hi", "sh_term", "sh_boost",
         ]
-    col = {key: pool_n[key][cand] for key in needed}  # [A, B, ...]
+    return needed
+
+
+def _stage2_rows(
+    pool_n, rowq, active_slots, winners, *, k, keep, rev, with_should,
+    with_embedding, order_exact,
+):
+    """One stripe of `_stage2`: rows [R] against their winners [R, B],
+    pre-trimmed to the `keep` best by stage-1 priority."""
+    # Pre-trim the block winners to ~k by packed stage-1 priority BEFORE
+    # any gather: at an 8-pool 160k bench the [A, 256, F] gather of every
+    # pool field was a ~28 GB allocation (OOM on a 16 GB chip). The packed
+    # word sorts by (priority << COL_BITS | col), so top_k keeps the
+    # best-prioritised candidates; the exact re-rank below then orders the
+    # survivors precisely. Keep 2x headroom over k so bucket-granular
+    # false positives rarely crowd out true candidates.
+    if winners.shape[1] > keep:
+        winners, _ = jax.lax.top_k(winners, keep)
+    cand = winners & (MAX_COLS - 1)  # [A, B]
+    alive = winners != PACKED_NONE
+
+    # Gather only what the exact checks read.
+    col = {
+        key: pool_n[key][cand] for key in _stage2_columns(rev)
+    }  # [A, B, ...]
 
     # Exact per-field predicate, reusing the small-kernel form: _accepts
     # wants fcol [Bc,...] vs qrow [Br,...]; vmap over rows gives

@@ -166,27 +166,37 @@ def lists_to_batch(
 
 
 def _select_backend(config: MatchmakerConfig, logger, metrics):
-    """config.backend: "cpu" → oracle; "tpu" → device backend (raises
-    without one); "auto" → device backend only when an accelerator is the
-    default JAX device — CPU-only hosts (and the CPU-forced test env) get
-    the exact oracle, accelerator deployments get the production kernel
-    (SURVEY §7.5: the swappable-backends seam)."""
+    """config.backend: "cpu" → oracle; "tpu" → device backend, and an
+    error where the default JAX device is not a TPU (an interpreting
+    backend is never handed out in its place); "auto" → device backend
+    when an accelerator is the default JAX device, the exact oracle on a
+    CPU-only host. A failing `jax.devices()` propagates: a host whose
+    accelerator cannot be reached must not come up quietly on the
+    oracle. (SURVEY §7.5: the swappable-backends seam.)"""
     choice = getattr(config, "backend", "auto")
     if choice == "cpu":
         return CpuBackend()
-    use_device = choice == "tpu"
-    if choice == "auto":
-        try:
-            import jax
+    import jax
 
-            use_device = jax.devices()[0].platform not in ("cpu",)
-        except Exception:
-            use_device = False
-    if not use_device:
+    devices = jax.devices()
+    platform = devices[0].platform
+    chosen = dict(
+        configured=choice,
+        platform=platform,
+        kind=devices[0].device_kind,
+        count=len(devices),
+    )
+    if choice == "tpu" and platform != "tpu":
+        raise RuntimeError(
+            f'matchmaker.backend is "tpu" but the default JAX device is'
+            f" {platform} ({devices[0].device_kind})"
+        )
+    if platform == "cpu":
+        logger.info("matchmaker backend selected", backend="cpu", **chosen)
         return CpuBackend()
     from .tpu import TpuBackend
 
-    logger.info("matchmaker device backend selected")
+    logger.info("matchmaker backend selected", backend="device", **chosen)
     return TpuBackend(config, logger, metrics)
 
 

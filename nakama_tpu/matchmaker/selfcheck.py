@@ -20,6 +20,8 @@ numbers.
 
 from __future__ import annotations
 
+import re
+
 import numpy as np
 
 from ..config import MatchmakerConfig
@@ -27,6 +29,9 @@ from ..logger import test_logger
 from .local import CpuBackend, LocalMatchmaker
 from .tpu import TpuBackend
 from .types import MatchmakerPresence
+
+
+_TERM = re.compile(r"\+properties\.(\w+):(>=|<=)?(\S+)")
 
 
 def _specs(rng, n):
@@ -49,14 +54,18 @@ def _specs(rng, n):
 
 
 def _run(mm, specs, intervals):
+    """Add `specs`, run `intervals`; returns (matched batches, info) with
+    info[ticket id] = (query, min_count, max_count) for `validate_match`."""
     matched = []
+    info = {}
     mm.on_matched = matched.append
     for i, s in enumerate(specs):
         p = MatchmakerPresence(user_id=f"u{i}", session_id=f"s{i}")
-        mm.add(
+        tid, _ = mm.add(
             [p], p.session_id, "", s["query"], 2, 2, 1, s["strs"],
             s["nums"],
         )
+        info[tid] = (s["query"], 2, 2)
     for _ in range(intervals):
         mm.process()
     wait = getattr(mm.backend, "wait_idle", None)
@@ -64,26 +73,69 @@ def _run(mm, specs, intervals):
         wait(30)
         mm.process()  # collect any pipelined tail
     mm.stop()
-    return matched
+    return matched, info
 
 
-def _validate(matched, specs, label):
+def query_accepts(query: str, strs: dict, nums: dict) -> bool:
+    """Does `query` accept a ticket with these properties? Exact (f64,
+    whole strings) and independent of the system's own parser, compiler
+    and kernels: it reads only the must-terms the selfcheck and
+    `chip_smoke.py` recipes write (`+properties.f:term`,
+    `+properties.f:>=x`, `+properties.f:<=x`) and refuses any other."""
+    terms = _TERM.findall(query)
+    if len(terms) != len(query.split()):
+        raise ValueError(f"query outside the validator's grammar: {query}")
+    for field, op, value in terms:
+        if op:
+            x = nums.get(field)
+            if x is None:
+                return False
+            if (op == ">=" and not x >= float(value)) or (
+                op == "<=" and not x <= float(value)
+            ):
+                return False
+        elif strs.get(field) != value:
+            return False
+    return True
+
+
+def validate_match(entries, info, rev: bool, label="") -> None:
+    """Host re-check of one formed match: sessions distinct, the size
+    inside every member's [min_count, max_count], and some member (the
+    active ticket that searched) whose query accepts every other member
+    — mutually when `rev`. Raises AssertionError."""
+    sessions = [e.presence.session_id for e in entries]
+    assert len(set(sessions)) == len(sessions), (label, "session twice")
+    props = {
+        e.ticket: (e.string_properties, e.numeric_properties)
+        for e in entries
+    }
+    size = len(entries)
+    for tid in props:
+        _, lo, hi = info[tid]
+        assert lo <= size <= hi, (label, "size", size, lo, hi)
+
+    def accepts(a, b):
+        return query_accepts(info[a][0], *props[b])
+
+    def searched(a):
+        return all(
+            accepts(a, b) and (not rev or accepts(b, a))
+            for b in props
+            if b != a
+        )
+
+    assert any(searched(a) for a in props), (
+        label, "no member's query accepts the rest", sorted(props),
+    )
+
+
+def _validate(matched, info, label):
     total = 0
     for batch in matched:
         for entry_set in batch:
             assert len(entry_set) == 2, (label, "match size")
-            a, b = entry_set
-            ia = int(a.presence.user_id[1:])
-            ib = int(b.presence.user_id[1:])
-            assert a.presence.session_id != b.presence.session_id, label
-            for x, y in ((ia, ib), (ib, ia)):
-                sx, sy = specs[x], specs[y]
-                assert sx["strs"]["mode"] == sy["strs"]["mode"], (
-                    label, "mode", ia, ib,
-                )
-                lo = int(sx["query"].split(">=")[1].split(" ")[0])
-                hi = int(sx["query"].split("<=")[1].split(" ")[0])
-                assert lo <= sy["nums"]["rank"] <= hi, (label, ia, ib)
+            validate_match(entry_set, info, rev=True, label=label)
             total += 2
     return total
 
@@ -96,58 +148,66 @@ def _pairs(matched):
     )
 
 
-def run_chip_selfcheck(log=print) -> dict:
-    """Run all three device-path parity checks on the current default
-    JAX device. Raises AssertionError on any violation; returns a
-    summary dict."""
-    results = {}
+def _cpu_matches(specs, intervals=2):
+    mm = LocalMatchmaker(
+        test_logger(),
+        MatchmakerConfig(max_intervals=2, backend="cpu"),
+        backend=CpuBackend(),
+    )
+    return _run(mm, specs, intervals)
 
-    def cpu_matches(specs, intervals=2):
-        mm = LocalMatchmaker(
-            test_logger(),
-            MatchmakerConfig(max_intervals=2, backend="cpu"),
-            backend=CpuBackend(),
-        )
-        return _run(mm, specs, intervals)
 
-    # 1. Small-pool exact kernel: match-for-match oracle parity
-    # (synchronous intervals — parity needs same-interval delivery).
-    rng = np.random.default_rng(7)
-    specs = _specs(rng, 96)
-    cpu = cpu_matches(specs)
+def exact_parity(n: int, seed: int, **widths) -> int:
+    """Small-pool exact kernel against the CPU oracle on `n` seeded 1v1
+    tickets: the same matches, pair for pair. Synchronous intervals
+    (parity needs same-interval delivery) and a candidate width of the
+    whole pool (the oracle searches all of it); `widths` override the
+    shipped schema widths. Returns the number of matches."""
+    specs = _specs(np.random.default_rng(seed), n)
+    cpu, _ = _cpu_matches(specs)
+    cap = 1 << (n - 1).bit_length()
     cfg = MatchmakerConfig(
-        pool_capacity=256, candidates_per_ticket=256, numeric_fields=8,
-        string_fields=8, max_constraints=8, max_intervals=2,
-        interval_pipelining=False,
+        pool_capacity=cap, candidates_per_ticket=cap, max_intervals=2,
+        big_pool_threshold=2 * cap, interval_pipelining=False, **widths,
     )
     mm = LocalMatchmaker(
         test_logger(), cfg, backend=TpuBackend(cfg, test_logger())
     )
-    dev = _run(mm, specs, 2)
+    dev, _ = _run(mm, specs, 2)
     assert _pairs(dev) == _pairs(cpu), "small kernel != oracle"
-    results["small_exact_parity"] = len(_pairs(dev))
+    return len(_pairs(dev))
+
+
+def run_chip_selfcheck(log=print) -> dict:
+    """Run the device-path parity checks on the current default JAX
+    device. Raises AssertionError on any violation; returns a summary
+    dict."""
+    results = {}
+
+    # 1. Small-pool exact kernel: match-for-match oracle parity.
+    results["small_exact_parity"] = exact_parity(
+        96, 7, numeric_fields=8, string_fields=8, max_constraints=8
+    )
     log(f"selfcheck small kernel: {results['small_exact_parity']} matches,"
         " exact oracle parity")
 
-    # 2. Big (two-stage MXU) kernel + native assembler: exact validity +
-    # oracle coverage (device_pairing off pins the assembler path — the
-    # pure-1v1 pool would otherwise take the pairing handshake).
+    # 2. Big (two-stage MXU) kernel + native assembler at the shipped
+    # default widths: exact validity + oracle coverage (device_pairing
+    # off pins the assembler path — the pure-1v1 pool would otherwise
+    # take the pairing handshake).
     rng = np.random.default_rng(11)
     specs = _specs(rng, 600)
-    cpu_total = _validate(cpu_matches(specs), specs, "oracle")
+    cpu_total = _validate(*_cpu_matches(specs), "oracle")
     cfg = MatchmakerConfig(
-        pool_capacity=1024, candidates_per_ticket=64, numeric_fields=8,
-        string_fields=8, max_constraints=8, max_intervals=2,
-        big_pool_threshold=256, interval_pipelining=True,
-        device_pairing=False,
+        pool_capacity=1024, max_intervals=2, big_pool_threshold=256,
+        interval_pipelining=True, device_pairing=False,
     )
     mm = LocalMatchmaker(
         test_logger(), cfg, backend=TpuBackend(
             cfg, test_logger(), big_row_block=256, big_col_block=256,
         )
     )
-    dev = _run(mm, specs, 3)
-    dev_total = _validate(dev, specs, "big")
+    dev_total = _validate(*_run(mm, specs, 3), "big")
     assert dev_total >= cpu_total - 4, (dev_total, cpu_total)
     results["big_valid_entries"] = dev_total
     log(f"selfcheck big kernel: {dev_total} valid entries"
@@ -165,8 +225,7 @@ def run_chip_selfcheck(log=print) -> dict:
             cfg, test_logger(), big_row_block=256, big_col_block=256,
         )
     )
-    dev = _run(mm, specs, 2)
-    pair_total = _validate(dev, specs, "pairs")
+    pair_total = _validate(*_run(mm, specs, 2), "pairs")
     assert pair_total >= cpu_total - 8, (pair_total, cpu_total)
     results["pairing_valid_entries"] = pair_total
     log(f"selfcheck device pairing: {pair_total} valid entries"
@@ -186,8 +245,7 @@ def run_chip_selfcheck(log=print) -> dict:
             cfg, test_logger(), big_row_block=256, big_col_block=256,
         )
     )
-    dev = _run(mm, specs, 3)
-    pipe_total = _validate(dev, specs, "pairs-pipelined")
+    pipe_total = _validate(*_run(mm, specs, 3), "pairs-pipelined")
     assert pipe_total >= cpu_total - 8, (pipe_total, cpu_total)
     results["pairing_pipelined_valid_entries"] = pipe_total
     log(f"selfcheck pipelined device pairing: {pipe_total} valid entries"

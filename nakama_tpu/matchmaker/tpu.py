@@ -76,6 +76,19 @@ _CQ_MISS = object()  # cache-miss sentinel (None is a valid cached value)
 assert (SOP_UNUSED, SOP_ALL, SOP_NUM_RANGE, SOP_STR_EQ) == (0, 1, 2, 3)
 
 
+def _program_refused(exc: BaseException) -> bool:
+    """Did the compiler or the allocator refuse the PROGRAM (out of
+    VMEM/HBM, a Mosaic lowering error) rather than the device having
+    bad weather? Such a failure repeats on every retry, so it is logged
+    with the kernel and its full shapes instead of only being counted
+    on a breaker."""
+    text = f"{type(exc).__name__}: {exc}"
+    return any(
+        mark in text
+        for mark in ("RESOURCE_EXHAUSTED", "Mosaic", "LoweringException")
+    )
+
+
 def _pow2_blocks(blocks: int) -> int:
     """Smallest power of two >= blocks (>=1)."""
     return 1 << max(0, blocks - 1).bit_length()
@@ -189,7 +202,21 @@ class TpuBackend:
             on_flush=self._observe_chunk,
             sharding=sharding,
         )
-        self._interpret = jax.devices()[0].platform not in ("tpu",)
+        # Pallas kernels lower through Mosaic on a TPU and run in
+        # interpret mode anywhere else (the CPU tests construct this
+        # backend directly). Which of the two is live is logged here and
+        # shown on /v2/console/device: an interpreting backend must never
+        # pass for the chip. A config that ASKS for the chip
+        # (backend="tpu") is refused off-TPU in local._select_backend.
+        device0 = jax.devices()[0]
+        self._interpret = device0.platform != "tpu"
+        self._where = dict(
+            platform=device0.platform,
+            kind=device0.device_kind,
+            devices=len(jax.devices()),
+            pallas_interpret=self._interpret,
+        )
+        self.logger.info("matchmaker device backend", **self._where)
         self._gather_rows = None
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
@@ -262,6 +289,7 @@ class TpuBackend:
         # thread cancelled mid-XLA-compile at interpreter teardown
         # aborts the process ("FATAL: exception not rethrown").
         self._warm_threads: list[threading.Thread] = []
+        self.prewarm_failures = 0
         # Insertion-ordered slot ring: adds append here, so the ring IS
         # the (created_at, created_seq) dispatch order — the per-dispatch
         # lexsort over ~100k actives measured 8.7ms/interval. Entries of
@@ -321,6 +349,10 @@ class TpuBackend:
         # holder's address for the next cohort's, which would make a
         # new head look already-guard-joined.
         self._dispatch_counter = 0
+        # Kernel + full shapes of the dispatch being launched: copied
+        # onto the cohort's holder (→ the interval breadcrumb's
+        # `kernel`) and named by the ERROR a refused program logs.
+        self._dispatching: dict = {}
         # Cohorts accepted by the CURRENT process/collect call:
         # (ledger entry, matched slot array) pairs, so the ticket-trace
         # closer attributes each matched ticket to ITS cohort's stage
@@ -351,6 +383,33 @@ class TpuBackend:
                 self.metrics.mesh_shard_slots.labels(
                     device=str(d.id)
                 ).set(cap // n_dev)
+
+    def describe(self) -> dict:
+        """Where the kernels really run (console /v2/console/device)."""
+        return dict(
+            self._where,
+            breaker=self.breaker.state,
+            mesh_breaker=self.mesh_breaker.state,
+        )
+
+    def device_path_faults(self) -> list[str]:
+        """Every way this backend has left the device path since it was
+        built: breaker or mesh-breaker failures and transitions, and row
+        buckets that failed to prewarm. The ladder keeps players matched
+        through all of these, so a measurement or a bring-up must ask:
+        `chip_smoke.py` and `bench.py` fail unless this is empty."""
+        out = []
+        for name, b in (
+            ("breaker", self.breaker), ("mesh_breaker", self.mesh_breaker)
+        ):
+            if b.failures or b.opens or b.state != CLOSED:
+                out.append(
+                    f"{name}: state={b.state} failures={b.failures}"
+                    f" opens={b.opens}"
+                )
+        if self.prewarm_failures:
+            out.append(f"prewarm_failures={self.prewarm_failures}")
+        return out
 
     def attach(self, store):
         """Bind the LocalMatchmaker's SlotStore: one slot space shared by
@@ -555,7 +614,8 @@ class TpuBackend:
         )
 
     def _note_backend_failure(
-        self, stage: str, exc: Exception, crumb: dict, probe: bool = True
+        self, stage: str, exc: Exception, crumb: dict, probe: bool = True,
+        variant: dict | None = None,
     ):
         """Classify + record one device-path failure (dispatch or
         collect). Transient failures count toward the breaker threshold;
@@ -597,6 +657,20 @@ class TpuBackend:
             error=str(exc),
             breaker=self.breaker.state,
         )
+        self._log_if_refused(stage, exc, variant)
+
+    def _log_if_refused(
+        self, stage: str, exc: Exception, variant: dict | None = None
+    ):
+        """`variant` names the failed program where it is not the one
+        being dispatched: a cohort collected late fails as ITS dispatch."""
+        if _program_refused(exc):
+            self.logger.error(
+                "device program refused by the compiler or allocator",
+                stage=stage,
+                error=str(exc),
+                **(self._dispatching if variant is None else variant),
+            )
 
     def _on_mesh_breaker_transition(self, old: str, new: str, reason: str):
         self.tracing.record_breaker(
@@ -637,6 +711,7 @@ class TpuBackend:
             error=str(exc),
             breaker=self.mesh_breaker.state,
         )
+        self._log_if_refused(f"mesh_{stage}", exc)
 
     def _reclaim_inflight(self, slots: np.ndarray, why: str) -> int:
         """Release in-flight claims for `slots` (still-current gen only
@@ -875,6 +950,7 @@ class TpuBackend:
                     self._note_backend_failure("dispatch", e, crumb)
                     react_parts.append(device_slots.astype(np.int32))
                 else:
+                    crumb["kernel"] = pending[1]["variant"]
                     pending[1]["t_window_wall"] = t_window_wall
                     if probe_pending:
                         # Tag the half-open probe cohort: only ITS successful
@@ -1183,6 +1259,7 @@ class TpuBackend:
                 self._note_backend_failure(
                     "collect", e, crumb,
                     probe=bool(w_pending[1].get("probe")),
+                    variant=w_pending[1].get("variant"),
                 )
                 mine = w_slots[w_gen[w_slots] == self.store.gen[w_slots]]
                 n = self._reclaim_inflight(mine, "cohort collect failed")
@@ -1652,6 +1729,10 @@ class TpuBackend:
             # MXU time nobody waits on.
             a_pad = _pow2_blocks(-(-len(slots) // bm)) * bm
             use_pairs = self._use_pairs()
+            self._dispatching = self._variant(
+                "topk_candidates_big" + ("+pair_partners" * use_pairs),
+                a_pad, n_cols, bm, bn, rev, with_should, with_embedding,
+            )
             self._prewarm_row_bucket(
                 a_pad, n_cols, rev, with_should, with_embedding, bm, bn,
                 order_exact=not use_pairs,
@@ -1697,6 +1778,10 @@ class TpuBackend:
             self.col_block * _pow2_blocks(col_blocks),
             self.pool.capacity,
         )
+        self._dispatching = self._variant(
+            "topk_candidates", a_pad, n_cols, self.row_block,
+            self.col_block, rev, with_should, with_embedding,
+        )
         with DEVOBS.device_call("matchmaker.score"):
             scores, cand = topk_candidates(
                 self.pool.device,
@@ -1711,6 +1796,32 @@ class TpuBackend:
                 created_base=np.int32(self._created_base),
             )
         return self._bg_asm("small", (scores, cand), slots, last, rev)
+
+    def _variant(
+        self, kernel, a_pad, n_cols, bm, bn, rev, with_should,
+        with_embedding, n_local=None,
+    ) -> dict:
+        """The dispatched program, as breadcrumbs and refusal logs name
+        it. For the two-stage kernels `row_block` is the tile stage 1
+        really runs (it halves the configured one to fit VMEM), and
+        `winners_per_block` what each column block keeps."""
+        big = {}
+        if kernel.startswith("topk_candidates_big"):
+            from .device2 import stage1_plan
+
+            m, _, bm = stage1_plan(
+                n=n_cols, n_local=n_local or n_cols, k=self.k, bm=bm,
+                bn=bn, fn=self.fn, fs=self.fs,
+                de=self.d if with_embedding else 8, rev=rev,
+            )
+            big = dict(winners_per_block=m)
+        return dict(
+            kernel=kernel, a_pad=int(a_pad), n_cols=int(n_cols),
+            row_block=bm, col_block=bn, **big, fn=self.fn, fs=self.fs,
+            constraints=self.s, k=self.k, emb_dims=self.d, rev=bool(rev),
+            with_should=with_should, with_embedding=with_embedding,
+            interpret=self._interpret,
+        )
 
     def _use_pairs(self) -> bool:
         """Device-side 1v1 grouping is eligible when configured and the
@@ -1771,6 +1882,7 @@ class TpuBackend:
         self._dispatch_counter += 1
         holder: dict = {
             "dispatch_seq": self._dispatch_counter,
+            "variant": self._dispatching,
             "t_dispatch": t_disp,
             # Wall-clock twin of t_dispatch: ledger consumers (bench
             # slip gate, profile spans) attribute cohorts to dispatch
@@ -1982,17 +2094,26 @@ class TpuBackend:
         axis = self._mesh_axis
         n_dev = self._mesh.shape[axis]
         if self.pool.high_water >= self.config.big_pool_threshold:
-            from .device2 import topk_candidates_big_sharded
+            from .device2 import stage1_plan, topk_candidates_big_sharded
 
             bm, bn = self.big_row_block, self.big_col_block
             a_pad = _pow2_blocks(-(-len(slots) // bm)) * bm
             grid_lo, grid_inv = self._grid_params()
+            cap = self.pool.capacity
             # The packed-winner all_gather rides inside the fused call;
             # its stripe width is the per-shard stage-1 output.
-            n_blocks_global = self.pool.capacity // bn
-            m = max(1, -(-2 * self.k // n_blocks_global))
-            out_w = -(-(n_blocks_global // n_dev * m) // 128) * 128
+            _, out_w, _ = stage1_plan(
+                n=cap, n_local=cap // n_dev, k=self.k, bm=bm, bn=bn,
+                fn=self.fn, fs=self.fs,
+                de=self.d if with_embedding else 8, rev=rev,
+            )
             self._account_gather(n_dev * a_pad * out_w * 4)
+            self._dispatching = self._variant(
+                f"topk_candidates_big_sharded/{n_dev}"
+                + ("+pair_partners" * self._use_pairs()),
+                a_pad, cap, bm, bn, rev, with_should, with_embedding,
+                n_local=cap // n_dev,
+            )
             faults.fire("mesh.gather")  # chaos: fail the ICI merge
             with DEVOBS.device_call("matchmaker.shard_score"):
                 cand_dev = topk_candidates_big_sharded(
@@ -2029,6 +2150,10 @@ class TpuBackend:
         rows["_slot"] = jnp.asarray(pad_slots.astype(np.int32))
         k = min(self.k, self.pool.capacity)
         w = gather_width(k, n_dev, self._mesh_gather_k)
+        self._dispatching = self._variant(
+            f"mesh_score+mesh_merge/{n_dev}", a_pad, self.pool.capacity,
+            br, self.col_block, rev, with_should, with_embedding,
+        )
         self._prewarm_mesh_bucket(
             a_pad, w, rev, with_should, with_embedding,
             {rk: (rv.shape, rv.dtype) for rk, rv in rows.items()},
@@ -2126,13 +2251,27 @@ class TpuBackend:
                     self._warmed_buckets.discard(
                         ("mesh", size, w, rev, with_should, with_embedding)
                     )
-                self.logger.debug(
-                    "mesh bucket prewarm failed", error=str(e)
+                self._note_prewarm_failure(
+                    e, self._variant(
+                        f"mesh_score+mesh_merge/{n_dev}", sizes[0],
+                        self.pool.capacity, self.row_block,
+                        self.col_block, rev, with_should, with_embedding,
+                    ),
                 )
 
         t = threading.Thread(target=_warm, daemon=True)
         self._warm_threads.append(t)
         t.start()
+
+    def _note_prewarm_failure(self, exc: Exception, variant: dict):
+        """A row bucket that failed to compile ahead of time will fail
+        again when an interval dispatches it: count it and say which."""
+        self.prewarm_failures += 1
+        log = (
+            self.logger.error if _program_refused(exc)
+            else self.logger.warn
+        )
+        log("bucket prewarm failed", error=str(exc), **variant)
 
     def _prewarm_row_bucket(
         self, a_pad, n_cols, rev, with_should, with_embedding, bm, bn,
@@ -2227,8 +2366,11 @@ class TpuBackend:
                         (size, n_cols, rev, with_should, with_embedding,
                          order_exact)
                     )
-                    self.logger.debug(
-                        "bucket prewarm failed", error=str(e)
+                    self._note_prewarm_failure(
+                        e, dict(self._variant(
+                            "topk_candidates_big", size, n_cols, bm, bn,
+                            rev, with_should, with_embedding,
+                        ), order_exact=order_exact),
                     )
 
         t = threading.Thread(target=_warm, daemon=True)

@@ -28,6 +28,22 @@ def _newer(a: str, b: str) -> bool:
     return os.path.getmtime(a) > os.path.getmtime(b)
 
 
+def build(force: bool = False) -> None:
+    """Build the library from the sources in this directory. `force`
+    rebuilds even where make finds it up to date (`chip_smoke.py`: the
+    binary it loads is the one these sources give, whatever the tree
+    carried). The Makefile renames the finished file into place, so a
+    process loading meanwhile never sees half of one."""
+    cmd = ["make", "-C", _DIR, "-s"] + (["-B"] if force else [])
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, text=True)
+    except (subprocess.CalledProcessError, FileNotFoundError) as e:
+        detail = getattr(e, "stderr", "") or str(e)
+        raise NativeUnavailable(
+            f"cannot build native library: {detail}"
+        ) from e
+
+
 def load() -> ctypes.CDLL:
     """Load (building if needed) the native library."""
     global _lib
@@ -41,18 +57,7 @@ def load() -> ctypes.CDLL:
         if not os.path.exists(_LIB_PATH) or any(
             _newer(src, _LIB_PATH) for src in srcs
         ):
-            try:
-                subprocess.run(
-                    ["make", "-C", _DIR, "-s"],
-                    check=True,
-                    capture_output=True,
-                    text=True,
-                )
-            except (subprocess.CalledProcessError, FileNotFoundError) as e:
-                detail = getattr(e, "stderr", "") or str(e)
-                raise NativeUnavailable(
-                    f"cannot build native library: {detail}"
-                ) from e
+            build()
         lib = ctypes.CDLL(_LIB_PATH)
         lib.mm_assemble.restype = ctypes.c_int32
         lib.ts_create.restype = ctypes.c_void_p

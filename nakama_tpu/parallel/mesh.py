@@ -184,10 +184,8 @@ def mesh_score_fn(
         # shard axis the caller merges OUTSIDE shard_map.
         return s.reshape(1, a_pad, k), i.reshape(1, a_pad, k)
 
-    from ..jaxcompat import shard_map
-
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             per_device,
             mesh=mesh,
             in_specs=(P(axis), P(), P()),

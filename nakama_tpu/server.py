@@ -970,6 +970,9 @@ async def _amain(config: Config):
 def main(argv: list[str] | None = None):
     import sys
 
+    from .jaxenv import enable_compile_cache
+
+    enable_compile_cache()
     config = parse_args(argv if argv is not None else sys.argv[1:])
     for warning in config.check():
         print(f"config warning: {warning}")

@@ -27,6 +27,9 @@ from nakama_tpu.matchmaker.tpu import TpuBackend  # noqa: E402
 
 
 def main():
+    from nakama_tpu.jaxenv import enable_compile_cache
+
+    enable_compile_cache()
     rng = np.random.default_rng(42)
     cap = 1 << (POOL + POOL // 2 - 1).bit_length()
     cfg = MatchmakerConfig(

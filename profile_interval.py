@@ -12,10 +12,11 @@ Two views per run:
   run instead of hiding inside an end-to-end number.
 
 `--mesh` (or PROF_MESH=1) profiles the MESH-SHARDED interval instead:
-an 8-way pool-sharded backend (self-provisioned as a virtual CPU mesh
-when the host exposes fewer devices), printing the per-interval
-dispatch→shard_score→gather→merge chain plus each shard's occupancy,
-so a mesh-path regression names its stage from one run.
+a PROF_MESH_DEVICES-way pool-sharded backend (an error when JAX reports
+fewer devices; a CPU rehearsal sets JAX_PLATFORMS=cpu itself), printing
+the per-interval dispatch→shard_score→gather→merge chain plus each
+shard's occupancy, so a mesh-path regression names its stage from one
+run.
 """
 
 import os
@@ -53,47 +54,14 @@ from nakama_tpu.matchmaker import device as dev  # noqa: E402
 from nakama_tpu import native  # noqa: E402
 
 
-def _provision_mesh(n_dev):
-    """Self-provision an n-device virtual CPU mesh for `--mesh` (the
-    __graft_entry__.dryrun_multichip posture): the live config API
-    first, else re-exec with the XLA host-platform flag. Returns a
-    child exit code when this process re-exec'd, None to run inline."""
-    import jax
-
-    if os.environ.get("PROF_MESH_CHILD"):
-        try:
-            jax.config.update("jax_platforms", "cpu")
-            jax.config.update("jax_num_cpu_devices", n_dev)
-        except Exception:
-            pass
-    if len(jax.devices()) >= n_dev:
-        return None
-    if os.environ.get("PROF_MESH_CHILD"):
-        raise RuntimeError(
-            f"mesh child sees {len(jax.devices())} < {n_dev} devices"
-        )
-    import subprocess
-
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={n_dev}"
-    ).strip()
-    env["PROF_MESH_CHILD"] = "1"
-    return subprocess.run(
-        [sys.executable, os.path.abspath(__file__), *sys.argv[1:]],
-        env=env,
-    ).returncode
-
-
 def main():
     import jax
 
+    from nakama_tpu.jaxenv import enable_compile_cache, require_devices
+
+    enable_compile_cache()
     if MESH:
-        rc = _provision_mesh(MESH_DEVICES)
-        if rc is not None:
-            sys.exit(rc)
+        require_devices(MESH_DEVICES)
 
     rng = np.random.default_rng(42)
     cap = 1 << (POOL + POOL // 2 - 1).bit_length()

@@ -30,6 +30,9 @@ MAKERS = {
 
 
 def main():
+    from nakama_tpu.jaxenv import enable_compile_cache
+
+    enable_compile_cache()
     which = os.environ.get("PROF_CFG", "ns")
     maker, overrides = MAKERS[which]
     rng = np.random.default_rng(42)

@@ -23,17 +23,14 @@ if not _TPU_TIER:
             _flags + " --xla_force_host_platform_device_count=8"
         ).strip()
 
-# Some images preload jax at interpreter startup (before conftest runs), so
-# the env vars above may be read too late. Force the same settings through the
-# live config API; this works as long as no backend has been initialised yet.
+# The live config says the same as the env vars above, whichever of the
+# two jax reads first; a jax that cannot be held to the CPU must fail
+# here rather than let the tests take an accelerator.
 import jax
 
 if not _TPU_TIER:
-    try:
-        jax.config.update("jax_platforms", "cpu")
-        jax.config.update("jax_num_cpu_devices", 8)
-    except Exception:  # backend already up — tests will skip
-        pass
+    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_num_cpu_devices", 8)
 
 import pytest
 

@@ -1,0 +1,153 @@
+"""The main path's kernels, compiled for a described v5e at shipped widths.
+
+No chip is attached: the TPU compiler installed here compiles for a
+topology that is only described, and raises what the chip's compiler
+would raise — a kernel over its VMEM, a program over the chip's HBM, a
+Pallas kernel that does not lower. Nothing runs, so these say nothing of
+results or times; `chip_smoke.py` does that on the chip.
+
+The topology is described inside a fixture (never at import or
+collection: only one process may hold the TPU library, and every xdist
+worker imports this file), compiles happen in this process, and all of
+them live in this one file.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (
+    Mesh,
+    NamedSharding,
+    PartitionSpec as P,
+    SingleDeviceSharding,
+)
+
+from nakama_tpu.config import MatchmakerConfig
+from nakama_tpu.leaderboard import tpu as lb
+from nakama_tpu.matchmaker import device, device2
+
+HBM_BYTES = 15.75e9  # one v5e chip
+CFG = MatchmakerConfig()  # the shipped default widths
+CAP = CFG.pool_capacity
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it.
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _pool(sharding, capacity=CAP):
+    host = device.pool_schema(
+        8, CFG.numeric_fields, CFG.string_fields, CFG.max_constraints,
+        CFG.embedding_dims,
+    )
+    return {
+        k: jax.ShapeDtypeStruct(
+            (capacity,) + v.shape[1:], v.dtype, sharding=sharding
+        )
+        for k, v in host.items()
+    }
+
+
+def _fits(compiled, pallas: bool):
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+    assert ("tpu_custom_call" in compiled.as_text()) == pallas
+    return mem
+
+
+@pytest.mark.parametrize("emb_rev", [(False, False), (True, True)])
+def test_big_kernel_full_pool_dispatch(one_chip, emb_rev):
+    """A fresh 100k pool dispatches all actives in one pass: 131072 rows
+    against 131072 columns. With mutual matching this was refused twice
+    over (stage-1 VMEM, stage-2 HBM) before the row tile was derived and
+    stage 2 striped; temporaries must stay far under the chip."""
+    emb, rev = emb_rev
+    a = jax.ShapeDtypeStruct((CAP,), jnp.int32, sharding=one_chip)
+    grid = jax.ShapeDtypeStruct(
+        (CFG.numeric_fields,), jnp.float32, sharding=one_chip
+    )
+    compiled = device2.topk_candidates_big.lower(
+        _pool(one_chip), a, grid, grid,
+        fn=CFG.numeric_fields, fs=CFG.string_fields, n_cols=CAP,
+        k=CFG.candidates_per_ticket, rev=rev, with_should=False,
+        with_embedding=emb, interpret=False,
+    ).compile()
+    assert _fits(compiled, pallas=True).temp_size_in_bytes < 4e9
+
+
+def test_big_kernel_sharded_mutual(topo):
+    """The 4-device mesh variant with embeddings and mutual matching."""
+    mesh = Mesh(np.asarray(topo.devices), ("pool",))
+    rep = NamedSharding(mesh, P())
+    a = jax.ShapeDtypeStruct((CAP,), jnp.int32, sharding=rep)
+    grid = jax.ShapeDtypeStruct(
+        (CFG.numeric_fields,), jnp.float32, sharding=rep
+    )
+    compiled = device2.topk_candidates_big_sharded.lower(
+        _pool(NamedSharding(mesh, P("pool"))), a, grid, grid,
+        mesh=mesh, axis="pool", fn=CFG.numeric_fields,
+        fs=CFG.string_fields, k=CFG.candidates_per_ticket, rev=True,
+        with_should=False, with_embedding=True, interpret=False,
+    ).compile()
+    assert _fits(compiled, pallas=True).temp_size_in_bytes < 4e9
+
+
+def test_pair_partners(one_chip):
+    cand = jax.ShapeDtypeStruct(
+        (CAP, CFG.candidates_per_ticket), jnp.int32, sharding=one_chip
+    )
+    a = jax.ShapeDtypeStruct((CAP,), jnp.int32, sharding=one_chip)
+    _fits(device2.pair_partners.lower(cand, a, cap=CAP).compile(), False)
+
+
+def test_small_exact_kernel(one_chip):
+    """A pool under big_pool_threshold, should-clauses, embeddings and
+    mutual matching on, at TpuBackend's default blocks."""
+    n = CFG.big_pool_threshold // 2
+    a = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    base = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = device.topk_candidates.lower(
+        _pool(one_chip), a, k=CFG.candidates_per_ticket, br=256, bc=2048,
+        rev=True, n_cols=n, with_should=True, with_embedding=True,
+        created_base=base,
+    ).compile()
+    _fits(compiled, pallas=False)
+
+
+def test_leaderboard_rank_lookup(one_chip):
+    """`lex_ranks` at a 65,536-row board. (`sort_boards` takes about a
+    minute to compile at any board size — its 3-key sort — and stays
+    out of tier-1; chip_smoke.py runs it.)"""
+    c = 1 << 16
+    keys = jax.ShapeDtypeStruct((c, 3), jnp.int32, sharding=one_chip)
+    q = jax.ShapeDtypeStruct((1024, 3), jnp.int32, sharding=one_chip)
+    compiled = lb.lex_ranks.lower(
+        keys, q, n_iters=lb.n_search_iters(c)
+    ).compile()
+    _fits(compiled, pallas=False)

@@ -117,7 +117,15 @@ async def _smoke():
                 assert (
                     await b0.recv_until("matchmaker_ticket", 15.0)
                 ) is not None
-            await asyncio.sleep(1.0)  # forwards + replication land
+            # Forwards + replication land: wait for the standby's shadow
+            # pool to hold both tickets (a fixed sleep loses the race on a
+            # loaded host, and the kill below then proves nothing).
+            landed = time.perf_counter() + 15.0
+            while time.perf_counter() < landed:
+                shadow = await bench._cluster_console(http, sb)
+                if shadow.get("matchmaker_tickets", 0) >= 2:
+                    break
+                await asyncio.sleep(0.25)
             pre = await bench._cluster_console(http, o1)
             assert pre["matchmaker_tickets"] >= 2
             sb_pid = sb.proc.pid

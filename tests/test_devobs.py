@@ -343,6 +343,31 @@ def test_recompile_budget_matchmaker_bucket_churn():
     mm.stop()
 
 
+def test_recompile_budget_removal_buckets_are_prewarmed():
+    """A removal count that first falls in a new power-of-two pad bucket
+    must not compile on the hot path: `PoolBuffer.prewarm` compiles every
+    bucket `flush()` can pad to. (On the chip this was the one compile
+    left after warm-up once the score kernels came from the persistent
+    cache and warm-up got short: interval 11, 165 ms, PR 21.)"""
+    from nakama_tpu.matchmaker.device import PoolBuffer
+
+    pool = PoolBuffer(2048, 4, 4, 4, d=4, flush_chunk=64)
+    assert pool._removal_buckets() == [64, 128, 256, 512, 1024, 2048]
+    DEVOBS.configure(warmup_intervals=1000)
+    pool.prewarm()
+    pool.join_prewarm()
+    DEVOBS.mark_warm()
+    compiles_at_warm = DEVOBS.compiles_total
+    for n in (1, 64, 65, 200, 500, 1000, 2048):  # every bucket, once
+        pool.remove_slots(np.arange(n, dtype=np.int32))
+        pool.flush()
+    assert DEVOBS.recompiles_total == 0
+    assert DEVOBS.compiles_total == compiles_at_warm, (
+        "a removal flush compiled after prewarm: "
+        f"{[k for k in DEVOBS.kernel_stats() if k['compiles']]}"
+    )
+
+
 def test_recompile_budget_leaderboard_flush_churn():
     """Leaderboard twin: flush-size churn (dirty counts padded pow2)
     and rank-batch churn inside seen buckets must not recompile after
@@ -457,7 +482,6 @@ def main():
     cfg.socket.grpc_port = -1
     cfg.logger.stdout = False
     mc = cfg.matchmaker
-    mc.backend = "tpu"
     mc.pool_capacity = 64
     mc.candidates_per_ticket = 16
     mc.numeric_fields = 4
@@ -473,7 +497,15 @@ def main():
 
         from nakama_tpu.matchmaker.types import MatchmakerPresence
 
-        server = NakamaServer(cfg)
+        # backend="tpu" in config is refused off-TPU; the CPU rig hands
+        # the server its own (interpreting) device backend instead.
+        from nakama_tpu.logger import setup_logging
+        from nakama_tpu.matchmaker.tpu import TpuBackend
+
+        log = setup_logging(cfg.logger)
+        server = NakamaServer(
+            cfg, log, matchmaker_backend=TpuBackend(cfg.matchmaker, log)
+        )
         await server.start()
         console = f"http://127.0.0.1:{server.console_port}"
         try:
@@ -518,6 +550,7 @@ def main():
                 out["compiles_total"] = d["compiles"]["total"]
                 out["memory_owners"] = sorted(d["memory"]["by_owner"])
                 out["mesh_devices"] = len(d["mesh"]["devices"])
+                out["backend"] = d["backend"]
                 out["timeline_n"] = len(d["timeline"])
                 out["unauth"] = (
                     await http.get(f"{console}/v2/console/device")
@@ -577,6 +610,10 @@ def test_console_device_endpoint_smoke():
     assert "matchmaker.pool" in out["memory_owners"]
     assert "leaderboard.boards" in out["memory_owners"]
     assert out["mesh_devices"] >= 1
+    # Where the kernels really run is on the page: this rig interprets.
+    assert out["backend"]["platform"] == "cpu"
+    assert out["backend"]["pallas_interpret"] is True
+    assert out["backend"]["breaker"] == "closed"
     assert out["timeline_n"] > 0
     assert out["capture_status"] == 200
     assert out["capture_under_data_dir"] and out["capture_exists"]

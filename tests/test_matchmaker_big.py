@@ -165,3 +165,82 @@ def test_big_path_stress_at_scale(rev):
 
     # The pipelined backend must be drainable (no stuck fetch threads).
     mm.stop()
+
+
+# ------------------------------------------- shapes chosen for the chip
+
+
+def _kernel_inputs(rev):
+    """A loaded device pool + padded active rows, as _dispatch hands them
+    to the kernel."""
+    from nakama_tpu.matchmaker.device import pad_to
+
+    mm, _ = make_big_mm(rev_precision=rev)
+    specs = _random_pool(np.random.default_rng(5), 300, party_frac=0.2)
+    _run(mm, specs, intervals=0)
+    rng = np.random.default_rng(6)
+    backend = mm.backend
+    backend.pool.flush()
+    grid_lo, grid_inv = backend._grid_params()
+    slots = pad_to(mm.store.active_slots(), 512, -1)
+    emb = rng.normal(size=backend.pool.device["emb"].shape).astype(np.float32)
+    pool = dict(backend.pool.device, emb=emb)
+    kw = dict(
+        fn=8, fs=8, n_cols=512, k=32, rev=rev, with_should=False,
+        with_embedding=True, bn=64, interpret=True,
+    )
+    return pool, slots, grid_lo, grid_inv, kw
+
+
+@pytest.mark.parametrize("rev", [False, True])
+@pytest.mark.parametrize("change", ["row_tile", "stage2_stripes"])
+def test_big_kernel_output_independent_of_chip_shapes(
+    rev, change, monkeypatch
+):
+    """What was moved to make the kernel fit the chip moves no result: a
+    smaller stage-1 row tile (rows are independent, the jitter keys on
+    the row index) and stage 2 run in row stripes both give the same
+    candidate lists, slot for slot."""
+    from nakama_tpu.matchmaker import device2
+
+    pool, slots, grid_lo, grid_inv, kw = _kernel_inputs(rev)
+    topk = device2.topk_candidates_big
+    base = np.asarray(topk(pool, slots, grid_lo, grid_inv, bm=64, **kw))
+    assert (base >= 0).sum() > 1000  # a real comparison, not all padding
+    if change == "row_tile":
+        got = topk(pool, slots, grid_lo, grid_inv, bm=16, **kw)
+    else:
+        # 512 rows x 64 kept winners x 4 B x words -> 8 stripes of 64 rows
+        words = sum(
+            int(np.prod(pool[c].shape[1:]))
+            for c in device2._stage2_columns(rev)
+        )
+        monkeypatch.setattr(
+            device2, "STAGE2_GATHER_BYTES", 64 * 64 * 4 * words
+        )
+        topk.clear_cache()
+        got = topk(pool, slots, grid_lo, grid_inv, bm=64, **kw)
+        topk.clear_cache()
+    assert np.array_equal(base, np.asarray(got))
+
+
+@pytest.mark.parametrize(
+    "widths,want",
+    [
+        # (d, de, dq) -> row tile from a configured 1024
+        ((200, 16, 8), 1024),  # the old bench widths, no mutual matching
+        ((520, 16, 8), 512),  # shipped default widths
+        ((520, 16, 520), 512),  # ... with mutual matching (was refused)
+        ((1032, 16, 1032), 256),  # 48 numeric + 32 string fields, mutual
+    ],
+)
+def test_stage1_row_tile_fits_vmem(widths, want):
+    from nakama_tpu.matchmaker.device2 import stage1_row_tile
+
+    d, de, dq = widths
+    assert stage1_row_tile(1024, 1024, d, de, dq, 128) == want
+    # Small tiles (the CPU tests') are left alone; the column tile never
+    # moves, so a width nothing fits is an error, not a silent change.
+    assert stage1_row_tile(64, 64, d, de, dq, 128) == 64
+    with pytest.raises(ValueError, match="VMEM"):
+        stage1_row_tile(1024, 1024, 16 * 1024, de, dq, 128)

@@ -43,7 +43,6 @@ def _smoke() -> dict:
     cfg.logger.file = logpath
     cfg.logger.level = "debug"
     mc = cfg.matchmaker
-    mc.backend = "tpu"
     mc.pool_capacity = 64
     mc.candidates_per_ticket = 16
     mc.numeric_fields = 4
@@ -58,7 +57,15 @@ def _smoke() -> dict:
     async def run():
         import aiohttp
 
-        server = NakamaServer(cfg)
+        # backend="tpu" in config is refused off-TPU; the CPU rig hands
+        # the server its own (interpreting) device backend instead.
+        from nakama_tpu.logger import setup_logging
+        from nakama_tpu.matchmaker.tpu import TpuBackend
+
+        log = setup_logging(cfg.logger)
+        server = NakamaServer(
+            cfg, log, matchmaker_backend=TpuBackend(cfg.matchmaker, log)
+        )
         await server.start()
         base = f"http://{'127.0.0.1'}:{server.port}"
         console = f"http://127.0.0.1:{server.console_port}"
