@@ -1167,7 +1167,7 @@ def run_trace_overhead_main() -> int:
     # Per-interval composition on the 100k path (process → dispatch →
     # accept → publish): ONE cohort trace cycle, ~8 guarded
     # instrumentation points reading the contextvar (_finish_ticket_
-    # traces, _stamp_published/SLO, record_breaker, db hooks on the
+    # traces, _deliver's publish stamp/SLO, record_breaker, db hooks on the
     # gap drain), ~4 no-op child spans (db.write on gap-work writes),
     # and ~4 ledger appends (delivery + breadcrumb + drains).
     per_interval_us = (

@@ -470,7 +470,7 @@ async def _matchmaker_phase(args, name, rev, intervals, n_modeled, n_ws,
     check(not any(t.is_alive() for t in backend._warm_threads),
           "prewarm thread outlived stop()")
     check(backend.pipeline_depth() == 0 or all(
-        not w[0][-1].is_alive() for w in backend._pipeline_queue
+        not w.thread.is_alive() for w in backend._pipeline_queue
     ), "cohort worker outlived stop()")
 
     # ---- judge: everything observed is printed, then any problem fails
@@ -579,7 +579,7 @@ async def _matchmaker_phase(args, name, rev, intervals, n_modeled, n_ws,
             collect_ms=round(d["collect_lag_s"] * 1e3),
             publish_ms=round(d.get("publish_lag_s", 0) * 1e3),
             slipped=d["slipped"],
-        ) for d in deliveries],
+        ) for d in deliveries if d["status"] == "ok"],
         memory_before=mem0, memory_after=mem1,
         threads=threads_before_stop,
     )

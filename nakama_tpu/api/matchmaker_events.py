@@ -10,6 +10,8 @@ every matched presence.
 
 from __future__ import annotations
 
+import uuid
+from time import perf_counter
 from typing import Any
 
 from ..logger import Logger
@@ -29,9 +31,22 @@ def make_matched_handler(
 ):
     log = logger.with_fields(subsystem="matchmaker.matched")
 
+    # Where a publish goes, summed over the matches of one batch; the
+    # matchmaker moves the sums onto the delivery call's ledger row and
+    # zeroes them (local.py `_publish`). Five stamps a match, none an
+    # entry: a match's bodies are all built before the first is routed.
+    stages = dict(
+        publish_matches=0, publish_envelopes=0,
+        publish_materialise_s=0.0, publish_hook_s=0.0,
+        publish_token_s=0.0, publish_envelope_s=0.0, publish_route_s=0.0,
+    )
+
     def on_matched(matched: list[list[MatchmakerEntry]]):
+        t_next = perf_counter()
         for entries in matched:
-            ticket_of = {e.presence.session_id: e.ticket for e in entries}
+            # Between two matches the time is the batch iterator's: a
+            # columnar batch makes a match's entry list only here.
+            t_entries = perf_counter()
             match_id = ""
             if runtime is not None:
                 hook = runtime.matchmaker_matched()
@@ -40,10 +55,9 @@ def make_matched_handler(
                         match_id = hook(entries) or ""
                     except Exception as e:
                         log.error("matchmaker matched hook error", error=str(e))
+            t_hooked = perf_counter()
 
             if not match_id:
-                import uuid as _uuid
-
                 user_list = ",".join(
                     sorted(
                         f"{e.presence.user_id}:{e.presence.username}"
@@ -52,7 +66,7 @@ def make_matched_handler(
                 )
                 # The token names a relayed-match rendezvous id every matched
                 # client can join (reference matchmaker.go:392-399).
-                rendezvous = f"{_uuid.uuid4()}.{node}"
+                rendezvous = f"{uuid.uuid4()}.{node}"
                 token, _ = session_token.generate(
                     encryption_key,
                     user_list,
@@ -64,7 +78,9 @@ def make_matched_handler(
                         "mid": rendezvous,
                     },
                 )
+            t_token = perf_counter()
 
+            ticket_of = {e.presence.session_id: e.ticket for e in entries}
             users = [
                 {
                     "presence": e.presence.as_dict(),
@@ -74,6 +90,7 @@ def make_matched_handler(
                 }
                 for e in entries
             ]
+            bodies = []
             for entry in entries:
                 body: dict = {
                     "ticket": ticket_of[entry.presence.session_id],
@@ -84,6 +101,9 @@ def make_matched_handler(
                     body["match_id"] = match_id
                 else:
                     body["token"] = token
+                bodies.append(body)
+            t_bodies = perf_counter()
+            for entry, body in zip(entries, bodies):
                 # Cluster: a forwarded ticket's presences carry their
                 # origin node — route the envelope there (the cluster
                 # router ships it over the bus; single-node presences
@@ -97,5 +117,14 @@ def make_matched_handler(
                     ],
                     {"matchmaker_matched": body},
                 )
+            stages["publish_matches"] += 1
+            stages["publish_envelopes"] += len(bodies)
+            stages["publish_materialise_s"] += t_entries - t_next
+            stages["publish_hook_s"] += t_hooked - t_entries
+            stages["publish_token_s"] += t_token - t_hooked
+            stages["publish_envelope_s"] += t_bodies - t_token
+            t_next = perf_counter()
+            stages["publish_route_s"] += t_next - t_bodies
 
+    on_matched.stages = stages
     return on_matched

@@ -14,6 +14,7 @@ import asyncio
 import base64
 import binascii
 import json
+import time
 from dataclasses import dataclass, field
 from typing import Any
 
@@ -76,18 +77,34 @@ class Pipeline:
         envelope (the socket has no traceparent header, so every
         envelope starts a fresh trace carrying session identity), then
         realtime-class admission + a per-envelope deadline
-        (overload.py), then dispatch."""
-        if not trace_api.TRACES.enabled:
-            return await self._process_admitted(session, envelope, None)
-        key = (
-            message_key(envelope) if isinstance(envelope, dict) else None
-        )
-        with trace_api.root_span(
-            f"ws.{key or 'envelope'}",
-            session_id=getattr(session, "id", ""),
-            user_id=getattr(session, "user_id", ""),
-        ) as root:
-            return await self._process_admitted(session, envelope, root)
+        (overload.py), then dispatch. A `matchmaker_add` is timed whole:
+        what of it was not `mm.add` is the interval record's
+        `add_pipeline_s` (tracing.AddStages)."""
+        t0 = time.perf_counter()
+        try:
+            if not trace_api.TRACES.enabled:
+                return await self._process_admitted(session, envelope, None)
+            key = (
+                message_key(envelope) if isinstance(envelope, dict) else None
+            )
+            with trace_api.root_span(
+                f"ws.{key or 'envelope'}",
+                session_id=getattr(session, "id", ""),
+                user_id=getattr(session, "user_id", ""),
+            ) as root:
+                return await self._process_admitted(session, envelope, root)
+        finally:
+            if isinstance(envelope, dict) and "matchmaker_add" in envelope:
+                stages = self._add_stages()
+                if stages is not None:
+                    stages.envelope_done(session, time.perf_counter() - t0)
+
+    def _add_stages(self):
+        """The matchmaker's add-stage sums, where its backend keeps a
+        `Tracing`."""
+        backend = getattr(self.c.matchmaker, "backend", None)
+        tracing = getattr(backend, "tracing", None)
+        return None if tracing is None else tracing.add_stages
 
     async def _process_admitted(self, session, envelope: dict, root) -> bool:
         """Realtime-class admission + a per-envelope deadline
@@ -271,6 +288,9 @@ class Pipeline:
             )
         except MatchmakerError as e:
             raise PipelineError(str(e) or type(e).__name__) from e
+        stages = self._add_stages()
+        if stages is not None:
+            stages.enveloped(session)
         out: dict = {"matchmaker_ticket": {"ticket": ticket}}
         if cid:
             out["cid"] = cid

@@ -882,4 +882,6 @@ def cluster_matched_handler(
                 held, reason=f"origin nodes down: {sorted(held_nodes)}"
             )
 
+    # The publish stage sums are the inner handler's (local.py `_publish`).
+    on_matched.stages = getattr(inner, "stages", None)
     return on_matched

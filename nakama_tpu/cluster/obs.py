@@ -418,9 +418,8 @@ class FleetTraceStore:
     def delivery_chain(self, trace_id: str,
                        offsets_s: dict[str, float] | None = None
                        ) -> list[str]:
-        """The stitched trace as a printable chain (profile_spans
-        --fleet): one line per span in adjusted time order, hops
-        annotated with their bus latency."""
+        """The stitched trace as a printable chain: one line per span
+        in adjusted time order, hops annotated with their bus latency."""
         tree = self.stitched(trace_id, offsets_s)
         if tree is None:
             return []

@@ -559,9 +559,8 @@ class DeviceTelemetry:
     # ------------------------------------------------------- console report
 
     def report_lines(self) -> list[str]:
-        """The shared plain-text device report (profile_interval /
-        profile_spans / profile_cprof all print this instead of three
-        drifting hand-rolled tables)."""
+        """The plain-text device report: kernel clocks, HBM owners and
+        transfer sites, one table for every script that prints them."""
         s = self.stats()
         lines = ["device telemetry:"]
         lines.append(

@@ -16,8 +16,8 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache; returns its directory.
 
-    Called first thing by `server.main`, `bench.py`, `profile_*.py` and
-    `chip_smoke.py`. Where `JAX_COMPILATION_CACHE_DIR` is set JAX reads
+    Called first thing by `server.main`, `bench.py`, `benchmark/run.py`
+    and `chip_smoke.py`. Where `JAX_COMPILATION_CACHE_DIR` is set JAX reads
     it itself and nothing is set in code. Otherwise the cache lives at
     the fixed `<checkout>/.jax_cache`: the path is part of the cache
     key, so a directory that moved between runs would never hit."""

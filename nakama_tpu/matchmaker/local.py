@@ -24,6 +24,7 @@ NewLocalBenchMatchmaker (server/matchmaker_test.go:1697).
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import time
 import uuid
 from typing import Callable, Protocol
@@ -633,6 +634,9 @@ class LocalMatchmaker:
         Reference Add: server/matchmaker.go:443-566."""
         if self._stopped:
             raise ErrNotAvailable("matchmaker stopped")
+        # Five stamps an add (tracing.AddStages): validation, parse and
+        # the ticket's making; store + staged device row; journal; trace.
+        t0 = time.perf_counter()
         # Deadline propagation (overload.py): a caller whose deadline
         # already passed gets DEADLINE_EXCEEDED before the ticket is
         # registered — registering it would be dead work the client has
@@ -716,9 +720,12 @@ class LocalMatchmaker:
             parsed_query=parsed,
             embedding=embedding,
         )
+        t_parsed = time.perf_counter()
         self._register(ticket)
+        t_registered = time.perf_counter()
         if self.journal is not None:
             self.journal.record_add(ticket)
+        t_journaled = time.perf_counter()
         sp = trace_api.current_span()
         if sp is not None:
             slot = self.store.slot_by_id(ticket_id)
@@ -735,6 +742,11 @@ class LocalMatchmaker:
                 self._hold_ticket_trace(ticket_id, sp, slot)
             self.logger.debug(
                 "matchmaker ticket added", ticket=ticket_id
+            )
+        tracing = getattr(self.backend, "tracing", None)
+        if tracing is not None:
+            tracing.add_stages.add(
+                t0, t_parsed, t_registered, t_journaled, time.perf_counter()
             )
         return ticket_id, created_at
 
@@ -787,8 +799,8 @@ class LocalMatchmaker:
         cohort_of = None
         if cohorts:
             cohort_of = np.full(cap, -1, dtype=np.int32)
-            for i, (_, slots_arr) in enumerate(cohorts):
-                cohort_of[slots_arr] = i
+            for i, cohort in enumerate(cohorts):
+                cohort_of[cohort.matched_slots] = i
         default_entry = None
         if tracing is not None and len(tracing.deliveries):
             default_entry = tracing.deliveries[-1]
@@ -810,7 +822,7 @@ class LocalMatchmaker:
                 del self._ticket_traces[tid]
                 entry = default_entry
                 if cohort_of is not None and cohort_of[slot] >= 0:
-                    entry = cohorts[cohort_of[slot]][0]
+                    entry = cohorts[cohort_of[slot]].entry
                 trace_api.emit_matched_spans((trace_id, span_id), entry)
             elif not active[slot] and (
                 inflight is None or not inflight[slot]
@@ -854,8 +866,7 @@ class LocalMatchmaker:
         collect = getattr(self.backend, "collect_ready", None)
         if collect is None:
             return None
-        tracing = getattr(self.backend, "tracing", None)
-        n_ledger = getattr(tracing, "deliveries_total", 0)
+        t0 = time.perf_counter()
         try:
             out = collect(
                 rev_precision=self.config.rev_precision,
@@ -873,48 +884,68 @@ class LocalMatchmaker:
             return None
         batch, matched_slots, reactivate = out
         objs = None
-        if len(matched_slots):
-            self.backend.on_remove_slots(matched_slots)
-            objs = self.store.remove_slots(matched_slots)
-            if batch.offsets is not None:
-                batch.bind_tickets(objs)
-        self.store.reactivate(reactivate)
+        with self._annotate("mm.remove"):
+            if len(matched_slots):
+                self.backend.on_remove_slots(matched_slots)
+                objs = self.store.remove_slots(matched_slots)
+                if batch.offsets is not None:
+                    batch.bind_tickets(objs)
+            self.store.reactivate(reactivate)
         if self.metrics is not None:
             self.metrics.mm_matched.inc(batch.entry_count if batch else 0)
             self._update_gauges()
-        published_ok = True
-        if len(batch) and self.on_matched is not None:
-            published_ok = self._publish(batch)
-            self._stamp_published(tracing, n_ledger)
-        self._journal_matched(matched_slots, objs, published_ok)
-        self._finish_ticket_traces(matched_slots, tracing)
+        head = self._deliver(
+            batch, matched_slots, objs, self.backend._accepted_cohorts
+        )
+        if head is not None:
+            head["delivery_held_s"] = time.perf_counter() - t0
         return batch
 
-    def _stamp_published(self, tracing, n_before: int):
-        """Close the per-cohort stage chain: stamp dispatch→published
-        lag on the ledger entries this collect/process call recorded
-        (the cohorts whose matches were just handed to `on_matched`).
-        Feeds the matchmaker_delivery_publish_lag histogram — the
-        end-to-end number the dispatched→ready→accepted→published
-        attribution hangs off."""
-        if tracing is None:
-            return
-        mark = getattr(tracing, "mark_published", None)
-        if mark is None:
-            return
-        # Monotonic-counter delta, NOT a deque-length delta: once the
-        # bounded ledger fills, its length stops moving and a length
-        # delta would stamp nothing forever.
-        n_new = max(0, tracing.deliveries_total - n_before)
-        lags = mark(time.perf_counter(), max_n=n_new)
-        if self.metrics is not None:
-            for lag in lags:
-                self.metrics.mm_delivery_publish_lag.observe(lag)
-        if self.slo is not None:
-            for lag in lags:
-                self.slo.observe("delivery_publish", lag * 1000)
+    def _annotate(self, name: str):
+        """A host span in a captured profile, where the backend runs on
+        JAX (keeps a `Tracing`); the host-only backends never import
+        it."""
+        if getattr(self.backend, "tracing", None) is None:
+            return contextlib.nullcontext()
+        return trace_api.annotate(name)
 
-    def _publish(self, batch: MatchBatch) -> bool:
+    def _deliver(self, batch, matched_slots, objs, cohorts):
+        """The tail of a process() / collect_pipelined() call: publish
+        the batch, close each shipped cohort's stage chain with its
+        dispatch→published lag, journal the outcome and resolve held
+        ticket traces. `cohorts` are the backend's records of the
+        pipelined cohorts the call accepted, oldest first; the call's
+        own stamps go, in place, on the oldest one's ledger row, which
+        is returned for the caller to add `delivery_held_s` as its last
+        act (None when the call shipped no such cohort)."""
+        head = cohorts[0].entry if cohorts else None
+        published_ok = True
+        t_publish = time.perf_counter()
+        if len(batch) and self.on_matched is not None:
+            with self._annotate("mm.publish"):
+                published_ok = self._publish(batch, head)
+            now = time.perf_counter()
+            for cohort in cohorts:
+                # Feeds the matchmaker_delivery_publish_lag histogram —
+                # the end-to-end number the dispatched→ready→accepted→
+                # published attribution hangs off.
+                lag = cohort.published(now)
+                if self.metrics is not None:
+                    self.metrics.mm_delivery_publish_lag.observe(lag)
+                if self.slo is not None:
+                    self.slo.observe("delivery_publish", lag * 1000)
+        with self._annotate("mm.journal_matched"):
+            self._journal_matched(matched_slots, objs, published_ok)
+        self._finish_ticket_traces(
+            matched_slots, getattr(self.backend, "tracing", None)
+        )
+        if head is not None:
+            # All between the newest accept stamp and the publish:
+            # batch finalisation, slot removal, reactivation, gauges.
+            head["deliver_remove_s"] = t_publish - cohorts[-1].t_accept
+        return head
+
+    def _publish(self, batch: MatchBatch, row: dict | None = None) -> bool:
         """Deliver a matched batch to `on_matched`, bounded by the fault
         plane's `delivery.publish` point. The tickets are already
         removed from the pool by the time delivery runs (reference
@@ -925,7 +956,9 @@ class LocalMatchmaker:
         `unpublished` matches so a restart re-pools the tickets; a
         handler raising PartialPublish (cluster: some cohorts' origin
         nodes down) returns the held tickets' id set so ONLY those
-        cohorts journal unpublished."""
+        cohorts journal unpublished. Where the handler keeps publish
+        stage sums (`stages`, api/matchmaker_events.py), they are moved
+        onto `row`, the ledger row of the delivery call."""
         try:
             if faults.fire("delivery.publish"):
                 # drop-mode chaos: delivery intentionally discarded.
@@ -936,7 +969,14 @@ class LocalMatchmaker:
                 if self.metrics is not None:
                     self.metrics.mm_delivery_failed.inc()
                 return False
-            self.on_matched(batch)
+            try:
+                self.on_matched(batch)
+            finally:
+                stages = getattr(self.on_matched, "stages", None)
+                if stages:
+                    if row is not None:
+                        row.update(stages)
+                    stages.update(dict.fromkeys(stages, 0))
             return True
         except PartialPublish as e:
             self.logger.warn(
@@ -1009,8 +1049,7 @@ class LocalMatchmaker:
         host-only object paths."""
         t0 = time.perf_counter()
         t_backend = t0  # re-stamped just before the backend call below
-        _tracing = getattr(self.backend, "tracing", None)
-        _n_ledger = getattr(_tracing, "deliveries_total", 0)
+        backend_failed = False
         store = self.store
         meta = store.meta
         active_slots = store.active_slots()
@@ -1033,7 +1072,6 @@ class LocalMatchmaker:
             )
             expired_slots = active_slots[last]
             t_backend = time.perf_counter()
-            backend_failed = False
             try:
                 batch, matched_slots, reactivate = (
                     self.backend.process_slots(
@@ -1061,19 +1099,21 @@ class LocalMatchmaker:
                 reactivate = expired_slots.astype(np.int32)
 
         t_rm = time.perf_counter()
-        store.deactivate(expired_slots)
-        t_rm1 = time.perf_counter()
-        if len(matched_slots):
-            self.backend.on_remove_slots(matched_slots)
-        t_rm2 = time.perf_counter()
-        objs = None
-        if len(matched_slots):
-            objs = store.remove_slots(matched_slots)
-            if batch.offsets is not None:
-                # Columnar batch: its slots ARE matched_slots in order —
-                # reuse the parked refs as the delivery snapshot.
-                batch.bind_tickets(objs)
-        store.reactivate(reactivate)
+        with self._annotate("mm.remove"):
+            store.deactivate(expired_slots)
+            t_rm1 = time.perf_counter()
+            if len(matched_slots):
+                self.backend.on_remove_slots(matched_slots)
+            t_rm2 = time.perf_counter()
+            objs = None
+            if len(matched_slots):
+                objs = store.remove_slots(matched_slots)
+                if batch.offsets is not None:
+                    # Columnar batch: its slots ARE matched_slots in
+                    # order — reuse the parked refs as the delivery
+                    # snapshot.
+                    batch.bind_tickets(objs)
+            store.reactivate(reactivate)
         t_cb = time.perf_counter()
 
         if self.metrics is not None:
@@ -1085,12 +1125,15 @@ class LocalMatchmaker:
                 "matchmaker_interval", (time.perf_counter() - t0) * 1000
             )
 
-        published_ok = True
-        if len(batch) and self.on_matched is not None:
-            published_ok = self._publish(batch)
-            self._stamp_published(_tracing, _n_ledger)
-        self._journal_matched(matched_slots, objs, published_ok)
-        self._finish_ticket_traces(matched_slots, _tracing)
+        # Override intervals never called process_slots, and a backend
+        # that RAISED out of it accepted nothing: the cohorts it lists
+        # are some earlier call's.
+        own_interval = self.override_fn is None and not backend_failed
+        head = self._deliver(
+            batch, matched_slots, objs,
+            getattr(self.backend, "_accepted_cohorts", ())
+            if own_interval else (),
+        )
         # Attribute the post-backend tail (slot removal, delivery
         # callback) on the interval's breadcrumb: the p99 work that
         # isn't inside process_slots must still be visible to the bench
@@ -1100,9 +1143,7 @@ class LocalMatchmaker:
         # that interval's attribution. Likewise a backend that RAISED
         # out of process_slots recorded no crumb for this interval.
         tracing = (
-            getattr(self.backend, "tracing", None)
-            if self.override_fn is None and not backend_failed
-            else None
+            getattr(self.backend, "tracing", None) if own_interval else None
         )
         if tracing is not None and tracing.breadcrumbs:
             import threading as _threading
@@ -1115,6 +1156,8 @@ class LocalMatchmaker:
                 pre_backend_s=t_backend - t0,
                 threads=_threading.active_count(),
             )
+        if head is not None:
+            head["delivery_held_s"] = time.perf_counter() - t0
         return batch
 
     def _process_override(self, active_slots: np.ndarray):

@@ -94,22 +94,115 @@ def _pow2_blocks(blocks: int) -> int:
     return 1 << max(0, blocks - 1).bit_length()
 
 
-def _work_ready(work: tuple) -> bool:
-    """Has this dispatched work's device compute + D2H + gap-side
-    assembly completed? The ready stamp (written before the completion
-    signal fires) is authoritative: a collector woken BY the signal
-    must see a ready head even though the worker thread is still
-    unwinding its last microseconds; thread liveness is only the
-    fallback for paths with no stamp."""
-    holder = work[0][1]
-    return "t_ready" in holder or not work[0][-1].is_alive()
+class Cohort:
+    """One dispatched cohort, from `_dispatch` to publish: the work the
+    worker thread and the accept step hand over, and the cohort record
+    of tracing.py — ids and `perf_counter` stamps at the stage
+    boundaries, from which `row()` makes the delivery-ledger row."""
 
+    __slots__ = (
+        # ids: `seq` is never reused (head_token identity); the interval
+        # record with this `seq` dispatched the cohort.
+        "seq", "interval_seq", "variant", "actives",
+        # the work: dispatched slots, the store generations at dispatch,
+        # the worker and what it leaves
+        "slots", "gen", "thread", "asm", "err",
+        # stamps, perf_counter seconds (wall twins for trace spans)
+        "t_dispatch", "t_dispatch_wall", "t_window_wall", "deadline",
+        "t_device_done", "t_fetched", "t_ready", "t_collect", "t_accept",
+        "t_publish",
+        # what came of it
+        "d2h_bytes", "matches", "envelopes", "matched_slots", "slipped",
+        "status", "error_stage", "probe", "trace", "entry",
+    )
 
-def _work_deadline(work: tuple) -> float | None:
-    """The cohort's delivery deadline (perf_counter seconds): dispatch
-    time + one interval. Delivery past this point means the cohort
-    slipped its own interval."""
-    return work[0][1].get("deadline")
+    def __init__(self, seq, variant, slots, interval_sec):
+        import time as _time
+
+        self.seq = seq
+        self.interval_seq = None
+        self.variant = variant
+        self.actives = len(slots)
+        self.slots = slots
+        self.gen = None
+        self.thread = None
+        self.asm = None
+        self.err = None
+        self.t_dispatch = _time.perf_counter()
+        # Wall-clock twin of t_dispatch: ledger consumers (bench slip
+        # gate, trace spans) attribute cohorts to dispatch windows
+        # without reconstructing it from lag arithmetic.
+        self.t_dispatch_wall = _time.time()
+        self.t_window_wall = None
+        # Delivery deadline: the cohort must reach players before its
+        # OWN interval ends. collect_ready preempts gap work for a
+        # cohort nearing this stamp (local.py deadline guard).
+        self.deadline = self.t_dispatch + max(1.0, float(interval_sec))
+        self.t_device_done = self.t_fetched = self.t_ready = None
+        self.t_collect = self.t_accept = self.t_publish = None
+        self.d2h_bytes = 0
+        self.matches = self.envelopes = 0
+        self.matched_slots = None
+        self.slipped = False
+        self.status = "ok"
+        self.error_stage = None
+        self.probe = False
+        self.trace = None  # (trace_id, span_id) while its trace is held
+        self.entry = None  # the stored ledger row, once recorded
+
+    def ready(self) -> bool:
+        """Has this cohort's device compute + D2H + gap-side assembly
+        completed? The ready stamp (written before the completion
+        signal fires) is authoritative: a collector woken BY the signal
+        must see a ready head even though the worker thread is still
+        unwinding its last microseconds; thread liveness is only the
+        fallback for paths with no stamp."""
+        return self.t_ready is not None or not self.thread.is_alive()
+
+    def mine(self, store_gen) -> np.ndarray:
+        """The dispatched slots whose in-flight claim is still THIS
+        cohort's: a slot freed, reused and re-dispatched by a later
+        cohort (gen changed) is not."""
+        return self.slots[self.gen[self.slots] == store_gen[self.slots]]
+
+    def lag(self, stamp) -> float | None:
+        return None if stamp is None else stamp - self.t_dispatch
+
+    def row(self) -> dict:
+        """The delivery-ledger row: every stage an unrounded lag since
+        dispatch (None where the cohort never got there), under the
+        names the console, chip_smoke.py and the benchmark read."""
+        row = dict(
+            seq=self.seq,
+            interval_seq=self.interval_seq,
+            status=self.status,
+            device_done_lag_s=self.lag(self.t_device_done),
+            fetch_lag_s=self.lag(self.t_fetched),
+            ready_lag_s=self.lag(self.t_ready),
+            collect_lag_s=self.lag(self.t_collect),
+            accept_lag_s=self.lag(self.t_accept),
+            slipped=self.slipped,
+            dispatched_ts=self.t_dispatch_wall,
+            _pc_dispatch=self.t_dispatch,
+            actives=self.actives,
+            d2h_bytes=self.d2h_bytes,
+            matches=self.matches,
+            envelopes=self.envelopes,
+        )
+        if self.error_stage is not None:
+            row["error_stage"] = self.error_stage
+        if self.trace is not None:
+            # The row names its cohort trace, so a ticket trace closed
+            # off this row can link to it.
+            row["trace_id"] = self.trace[0]
+        return row
+
+    def published(self, now: float) -> float:
+        """Stamp dispatch→published on the record and its stored row;
+        returns the lag."""
+        self.t_publish = now
+        self.entry["publish_lag_s"] = lag = now - self.t_dispatch
+        return lag
 
 
 class TpuBackend:
@@ -344,21 +437,23 @@ class TpuBackend:
         # wakes immediately instead of a gap poll discovering the result
         # seconds later. None = nobody listening (tests, sync mode).
         self._ready_cb = None
-        # Monotonic per-dispatch sequence: head_token identity. id() of
-        # the holder dict is NOT usable — CPython reuses a freed
-        # holder's address for the next cohort's, which would make a
-        # new head look already-guard-joined.
+        # Monotonic per-dispatch sequence (`Cohort.seq`): head_token
+        # identity. id() of the cohort is NOT usable — CPython reuses a
+        # freed object's address for the next cohort's, which would
+        # make a new head look already-guard-joined.
         self._dispatch_counter = 0
         # Kernel + full shapes of the dispatch being launched: copied
-        # onto the cohort's holder (→ the interval breadcrumb's
-        # `kernel`) and named by the ERROR a refused program logs.
+        # onto the cohort (→ the interval breadcrumb's `kernel`) and
+        # named by the ERROR a refused program logs.
         self._dispatching: dict = {}
-        # Cohorts accepted by the CURRENT process/collect call:
-        # (ledger entry, matched slot array) pairs, so the ticket-trace
-        # closer attributes each matched ticket to ITS cohort's stage
-        # chain when one call collects several cohorts. Transient —
-        # replaced every call, never retained past it.
-        self._accepted_cohorts: list[tuple[dict, np.ndarray]] = []
+        # Pipelined cohorts accepted by the CURRENT process/collect
+        # call, oldest first, each with its stored ledger row (`entry`)
+        # and matched slots: local.py stamps their publish and the
+        # delivery call on them, and the ticket-trace closer attributes
+        # each matched ticket to ITS cohort's stage chain when one call
+        # collects several. Transient — replaced every call, never
+        # retained past it.
+        self._accepted_cohorts: list[Cohort] = []
         # Device telemetry plane: the named jit entry points this
         # backend drives. Registration installs the process-wide
         # compile-watch listener (jax is imported by now), so every
@@ -750,23 +845,22 @@ class TpuBackend:
         abandoned = False
         while self._pipeline_queue:
             head = self._pipeline_queue[0]
-            dl = _work_deadline(head)
-            if dl is None or _work_ready(head) or now <= dl + grace:
+            dl = head.deadline
+            if head.ready() or now <= dl + grace:
                 break
             self._pipeline_queue.popleft()
             abandoned = True
-            _, w_slots, _, _, w_gen = head
-            mine = w_slots[w_gen[w_slots] == self.store.gen[w_slots]]
+            mine = head.mine(self.store.gen)
             self._in_flight_mask[mine] = False
             n = self._reclaim_inflight(mine, "wedged cohort abandoned")
-            if head[0][1].get("probe"):
+            if head.probe:
                 # The abandoned cohort WAS the half-open probe: book its
                 # wedge as the probe's failure, or the breaker waits
                 # half-open forever for an answer that can never come.
                 self.breaker.record_failure()
-            self._close_cohort_trace(
-                head[0][1], status="error",
-                message=f"wedged cohort abandoned {round(now - dl, 1)}s"
+            self._lose_cohort(
+                head, "abandoned",
+                f"wedged cohort abandoned {round(now - dl, 1)}s"
                 " past deadline",
             )
             self.logger.warn(
@@ -786,7 +880,7 @@ class TpuBackend:
         if self._pipeline_queue:
             covered = np.zeros(self.pool.capacity, dtype=bool)
             for w in self._pipeline_queue:
-                covered[w[1]] = True
+                covered[w.slots] = True
             orphan = self._in_flight_mask & ~covered
         else:
             orphan = self._in_flight_mask.copy()
@@ -838,10 +932,9 @@ class TpuBackend:
         else:
             host_sel = np.ones(len(active_slots), dtype=bool)
         n_host = int(host_sel.sum())
-        crumb: dict = {
-            "actives": len(active_slots),
-            "host_actives": n_host,
-        }
+        crumb = self.tracing.open_crumb(
+            actives=len(active_slots), host_actives=n_host, cohort_seq=None
+        )
         if self.breaker.state != CLOSED:
             crumb["backend_state"] = self.breaker.state
         span = self.tracing.span
@@ -929,9 +1022,9 @@ class TpuBackend:
                 "matchmaker.cohort", actives=int(len(device_slots))
             ) as troot:
                 try:
-                    with span(crumb, "flush_s"):
+                    with span(crumb, "flush_s", "mm.flush"):
                         self.pool.flush()
-                    with span(crumb, "dispatch_s"):
+                    with span(crumb, "dispatch_s", "mm.dispatch"):
                         pending = self._dispatch(
                             device_slots, device_last, rev_precision
                         )
@@ -950,32 +1043,26 @@ class TpuBackend:
                     self._note_backend_failure("dispatch", e, crumb)
                     react_parts.append(device_slots.astype(np.int32))
                 else:
-                    crumb["kernel"] = pending[1]["variant"]
-                    pending[1]["t_window_wall"] = t_window_wall
+                    crumb["kernel"] = pending.variant
+                    crumb["cohort_seq"] = pending.seq
+                    pending.interval_seq = crumb["seq"]
+                    pending.t_window_wall = t_window_wall
                     if probe_pending:
                         # Tag the half-open probe cohort: only ITS successful
                         # collection may close the breaker (_accept_work) — a
                         # pre-outage cohort draining late must not.
-                        pending[1]["probe"] = True
+                        pending.probe = True
                         probe_used = True
                     if troot is not None:
                         # Keep the cohort trace open for the stage spans
                         # the accept path appends (ready/collect/accept);
                         # released there, or by the reclaim path.
                         trace_api.TRACES.hold(troot.trace_id)
-                        pending[1]["trace"] = (
-                            troot.trace_id, troot.span_id,
-                        )
-                    gen_snap = (
+                        pending.trace = (troot.trace_id, troot.span_id)
+                    pending.gen = (
                         self.store.gen.copy() if pipelined else self.store.gen
                     )
-                    work = (
-                        pending,
-                        device_slots,
-                        device_last,
-                        len(device_slots),
-                        gen_snap,
-                    )
+                    work = pending
                     if pipelined:
                         # Queue it; collection below drains only completed
                         # results, so the dispatch computes + transfers while
@@ -992,7 +1079,7 @@ class TpuBackend:
             # interval can probe.
             self.breaker.release_probe()
 
-        ready_works: list[tuple] = []
+        ready_works: list[Cohort] = []
         if work is not None:
             ready_works.append(work)
         if pipelined:
@@ -1006,7 +1093,7 @@ class TpuBackend:
             # (bounded join_head in a worker thread, local.py) is the
             # delivery path for overdue heads.
             while collectable > 0 and (
-                _work_ready(self._pipeline_queue[0])
+                self._pipeline_queue[0].ready()
                 or len(self._pipeline_queue) > 2
             ):
                 ready_works.append(self._pipeline_queue.popleft())
@@ -1037,7 +1124,7 @@ class TpuBackend:
             # the authoritative arrays first (the oracle's "let them wait"
             # rule reads hit.intervals) — O(pool), paid only when exotic
             # host-only queries exist.
-            with span(crumb, "host_s"):
+            with span(crumb, "host_s", "mm.host"):
                 host_actives, _, pool_view = self.store.oracle_view(
                     host_slots
                 )
@@ -1072,7 +1159,7 @@ class TpuBackend:
             sel, flat_parts, size_parts, react_parts
         )
         crumb["matched_entries"] = batch.entry_count
-        self.tracing.record(crumb)
+        self.tracing.record(crumb, interval=True)
         return batch, matched_slots, reactivate
 
     # ----------------------------------------------- pipeline state surface
@@ -1089,9 +1176,7 @@ class TpuBackend:
     def head_ready(self) -> bool:
         """Is the head cohort's device pass + assembly complete (its
         collection would be free, no blocking join)?"""
-        return bool(self._pipeline_queue) and _work_ready(
-            self._pipeline_queue[0]
-        )
+        return bool(self._pipeline_queue) and self._pipeline_queue[0].ready()
 
     def head_token(self):
         """Opaque identity of the current head cohort (None when the
@@ -1102,7 +1187,7 @@ class TpuBackend:
         next cycle."""
         if not self._pipeline_queue:
             return None
-        return self._pipeline_queue[0][0][1].get("dispatch_seq")
+        return self._pipeline_queue[0].seq
 
     def reclaim_stale(self):
         """Public reclamation entry for the delivery stage: abandon
@@ -1119,7 +1204,7 @@ class TpuBackend:
         schedules its gap wakes around this."""
         if not self._pipeline_queue:
             return None
-        return _work_deadline(self._pipeline_queue[0])
+        return self._pipeline_queue[0].deadline
 
     def pipeline_depth(self) -> int:
         return len(self._pipeline_queue)
@@ -1134,13 +1219,11 @@ class TpuBackend:
         head merely in normal mid-gap flight (seconds old, deadline far)
         does NOT shed: that would starve maintenance most intervals and
         then dump the accumulated churn into one still-backlogged gap."""
-        if not self._pipeline_queue or _work_ready(self._pipeline_queue[0]):
+        if not self._pipeline_queue or self._pipeline_queue[0].ready():
             return False
         if len(self._pipeline_queue) > 1:
             return True
-        deadline = _work_deadline(self._pipeline_queue[0])
-        if deadline is None:
-            return False
+        deadline = self._pipeline_queue[0].deadline
         import time as _time
 
         guard = max(
@@ -1172,14 +1255,10 @@ class TpuBackend:
             head = self._pipeline_queue[0]
         except IndexError:
             return False
-        dl = _work_deadline(head)
-        if dl is not None:
-            guard = max(
-                0.1, float(self.config.pipeline_deadline_guard_sec)
-            )
-            until = min(until, dl + guard)
-        head[0][-1].join(max(0.0, until - _time.perf_counter()))
-        return _work_ready(head)
+        guard = max(0.1, float(self.config.pipeline_deadline_guard_sec))
+        until = min(until, head.deadline + guard)
+        head.thread.join(max(0.0, until - _time.perf_counter()))
+        return head.ready()
 
     def collect_ready(self, *, rev_precision: bool, block_until=None):
         """Drain completed pipelined cohorts OUTSIDE process(): the
@@ -1198,12 +1277,12 @@ class TpuBackend:
         self._accepted_cohorts = []
         if block_until is not None:
             self.join_head(block_until)
-        ready_works: list[tuple] = []
-        while self._pipeline_queue and _work_ready(self._pipeline_queue[0]):
+        ready_works: list[Cohort] = []
+        while self._pipeline_queue and self._pipeline_queue[0].ready():
             ready_works.append(self._pipeline_queue.popleft())
         if not ready_works:
             return None
-        crumb: dict = {"midgap_collect": True}
+        crumb = self.tracing.open_crumb(midgap_collect=True)
         sel = self._sel_mask
         sel[:] = False
         flat_parts: list[np.ndarray] = []
@@ -1220,26 +1299,20 @@ class TpuBackend:
         return out
 
     def _accept_work(
-        self, work, crumb, sel, flat_parts, size_parts, react_parts,
-        pipelined,
+        self, work: Cohort, crumb, sel, flat_parts, size_parts,
+        react_parts, pipelined,
     ):
+        import time as _time
+
         span = self.tracing.span
-        w_pending, w_slots, w_last, w_n, w_gen = work
-        # Cohort delivery attribution (VERDICT r4 #3): when each cohort
-        # became ready (device pass + gap assembly done) and when it was
-        # actually collected, both relative to its dispatch. A cohort
-        # whose collect_lag exceeds the interval missed every mid-gap
-        # collection point — log it loudly instead of letting the
-        # cadence metric average it away.
+        w_slots, w_gen = work.slots, work.gen
         if pipelined:
             # Release only slots whose in-flight claim is still THIS
             # cohort's: a slot freed, reused, and re-dispatched by a
             # later still-queued cohort (gen changed) keeps its bit or
             # the next interval triple-dispatches it.
-            self._in_flight_mask[
-                w_slots[w_gen[w_slots] == self.store.gen[w_slots]]
-            ] = False
-        with span(crumb, "collect_s"):
+            self._in_flight_mask[work.mine(self.store.gen)] = False
+        with span(crumb, "collect_s", "mm.collect"):
             # Fetch + exact-ordering + native assembly + host
             # validation all ran on the cohort's worker thread in the
             # interval gap (_bg_asm); a ready cohort hands back
@@ -1248,7 +1321,7 @@ class TpuBackend:
             # thread ran) is exactly the staleness the accept step
             # below already drops via gen/alive masks.
             try:
-                n_matches, offsets, flat, ok = self._collect(w_pending)
+                n_matches, offsets, flat, ok = self._collect(work)
             except Exception as e:
                 # Cohort lost (worker crash, device fetch error,
                 # injected fault): its in-flight claims were released
@@ -1258,86 +1331,47 @@ class TpuBackend:
                 # about it.
                 self._note_backend_failure(
                     "collect", e, crumb,
-                    probe=bool(w_pending[1].get("probe")),
-                    variant=w_pending[1].get("variant"),
+                    probe=work.probe, variant=work.variant,
                 )
-                mine = w_slots[w_gen[w_slots] == self.store.gen[w_slots]]
-                n = self._reclaim_inflight(mine, "cohort collect failed")
+                n = self._reclaim_inflight(
+                    work.mine(self.store.gen), "cohort collect failed"
+                )
                 crumb["collect_reclaimed"] = (
                     crumb.get("collect_reclaimed", 0) + n
                 )
-                self._close_cohort_trace(
-                    w_pending[1], status="error",
-                    message=f"collect failed: {e}",
+                work.t_collect = _time.perf_counter()
+                self._lose_cohort(
+                    work, "collect", f"collect failed: {e}",
+                    ledger=pipelined,
                 )
                 return
         # The cohort's full device→host round trip succeeded: reset the
         # breaker's failure streak; a half-open PROBE cohort closes it.
-        if self.breaker.state == CLOSED or w_pending[1].get("probe"):
+        if self.breaker.state == CLOSED or work.probe:
             self.breaker.record_success()
-        holder = w_pending[1]
-        t_disp = holder.get("t_dispatch")
-        ledger = None  # written AFTER the accept span (accept_lag_s)
-        if t_disp is not None:
-            # Cohort delivery attribution (VERDICT r4 #3), measured
-            # AFTER the join above so a not-yet-ready cohort popped by
-            # backpressure (or the non-pipelined path) charges its real
-            # blocking wait to collect_lag instead of under-reporting.
-            import time as _time
-
-            now = _time.perf_counter()
-            ready_lag = (holder.get("t_ready", now)) - t_disp
-            fetch_lag = (holder.get("t_fetched", now)) - t_disp
-            collect_lag = now - t_disp
-            deadline = holder.get("deadline")
-            slipped = (
-                pipelined and deadline is not None and now > deadline
+        # Cohort delivery attribution (VERDICT r4 #3): when each cohort
+        # became ready (device pass + gap assembly done) and when it was
+        # actually collected, measured AFTER the join above so a
+        # not-yet-ready cohort popped by backpressure (or the
+        # non-pipelined path) charges its real blocking wait to the
+        # collect stamp instead of under-reporting.
+        work.t_collect = now = _time.perf_counter()
+        work.slipped = bool(pipelined and now > work.deadline)
+        if work.slipped:
+            crumb["cohort_slipped"] = crumb.get("cohort_slipped", 0) + 1
+            # A cohort past its deadline missed every mid-gap collection
+            # point — log it loudly instead of letting the cadence
+            # metric average it away. Attribution in the message
+            # itself: a long fetch lag names the D2H transfer;
+            # ready≈fetch with a long collect names gap-poll gating.
+            self.logger.warn(
+                "cohort delivered past its interval deadline",
+                ready_lag_s=round(work.lag(work.t_ready), 2),
+                fetch_lag_s=round(work.lag(work.t_fetched), 2),
+                collect_lag_s=round(work.lag(now), 2),
+                interval_sec=self.config.interval_sec,
             )
-            crumb.setdefault("cohort_ready_lag_ms", []).append(
-                round(ready_lag * 1000, 1)
-            )
-            crumb.setdefault("cohort_fetch_lag_ms", []).append(
-                round(fetch_lag * 1000, 1)
-            )
-            crumb.setdefault("cohort_collect_lag_ms", []).append(
-                round(collect_lag * 1000, 1)
-            )
-            if slipped:
-                crumb["cohort_slipped"] = crumb.get("cohort_slipped", 0) + 1
-            # Per-cohort dispatch→delivered ledger: slips are read off
-            # the console/metrics, not inferred from bench WARN lines.
-            # Pipelined cohorts only — the synchronous fallback's
-            # blocking same-interval collects would otherwise pollute
-            # the delivery-lag histogram and evict real pipelined
-            # entries from the ledger window slip_count() reads.
-            # Recorded after the accept span below so the entry carries
-            # the full per-stage chain (dispatched→ready→fetched→
-            # collected→accepted; local.py stamps →published).
-            if pipelined:
-                ledger = dict(
-                    ready_lag_s=round(ready_lag, 3),
-                    fetch_lag_s=round(fetch_lag, 3),
-                    collect_lag_s=round(collect_lag, 3),
-                    slipped=bool(slipped),
-                    dispatched_ts=holder.get("t_dispatch_wall"),
-                    _pc_dispatch=t_disp,
-                )
-                if self.metrics is not None:
-                    self.metrics.mm_delivery_lag.observe(collect_lag)
-                    if slipped:
-                        self.metrics.mm_cohort_slipped.inc()
-            if slipped:
-                # Attribution in the message itself: a long fetch_lag
-                # names the D2H transfer; ready≈fetch with a long
-                # collect names gap-poll gating.
-                self.logger.warn(
-                    "cohort delivered past its interval deadline",
-                    ready_lag_s=round(ready_lag, 2),
-                    fetch_lag_s=round(fetch_lag, 2),
-                    collect_lag_s=round(collect_lag, 2),
-                    interval_sec=self.config.interval_sec,
-                )
-        with span(crumb, "accept_s"):
+        with span(crumb, "accept_s", "mm.accept"):
             total = int(offsets[n_matches])
             flat_t = flat[:total]
             sizes = (
@@ -1394,65 +1428,81 @@ class TpuBackend:
             sel[good_flat] = True
             flat_parts.append(good_flat)
             size_parts.append(sizes[good])
-        if ledger is not None:
-            import time as _time
+        work.matched_slots = good_flat
+        work.matches = int(good.sum())
+        work.envelopes = int(self.meta["count"][good_flat].sum())
+        work.t_accept = _time.perf_counter()
+        if pipelined:
+            # Per-cohort dispatch→delivered ledger: slips are read off
+            # the console/metrics, not inferred from bench WARN lines.
+            # Pipelined cohorts only — the synchronous fallback's
+            # blocking same-interval collects would otherwise pollute
+            # the delivery-lag histogram and evict real pipelined
+            # entries from the ledger window slip_count() reads.
+            if self.metrics is not None:
+                self.metrics.mm_delivery_lag.observe(work.lag(now))
+                if work.slipped:
+                    self.metrics.mm_cohort_slipped.inc()
+            self._record_cohort(work)
+            self._accepted_cohorts.append(work)
+        self._close_cohort_trace(work)
 
-            ledger["accept_lag_s"] = round(
-                _time.perf_counter() - t_disp, 3
-            )
-            # Device phases on the same record as the host stage chain:
-            # kernel events between the cohort's flush and now (shared-
-            # mesh neighbors — leaderboard flushes — land here too,
-            # which is the point: contention reads off one record).
-            t_w0 = holder.get("t_window_wall") or holder.get(
-                "t_dispatch_wall"
-            )
-            if t_w0 is not None:
-                ledger["device_timeline"] = DEVOBS.timeline_between(
-                    t_w0, _time.time()
-                )
-            tctx = holder.get("trace")
-            if tctx is not None:
-                # The ledger entry names its cohort trace, so a ticket
-                # trace closed off this entry can link to it.
-                ledger["trace_id"] = tctx[0]
-            entry = self.tracing.record_delivery(**ledger)
-            self._accepted_cohorts.append((entry, good_flat))
-        self._close_cohort_trace(holder)
+    def _record_cohort(self, work: Cohort) -> None:
+        """Store the cohort's ledger row, with the device phases on the
+        same record as the host stage chain: kernel events between the
+        cohort's flush and now (shared-mesh neighbors — leaderboard
+        flushes — land here too, which is the point: contention reads
+        off one record)."""
+        import time as _time
+
+        row = work.row()
+        row["device_timeline"] = DEVOBS.timeline_between(
+            work.t_window_wall or work.t_dispatch_wall, _time.time()
+        )
+        work.entry = self.tracing.record_delivery(**row)
+
+    def _lose_cohort(
+        self, work: Cohort, stage: str, message: str, ledger: bool = True
+    ) -> None:
+        """A cohort that will deliver nothing (its collect raised, or it
+        wedged and was abandoned): `status: error` with the stage on its
+        record, one whole ledger row that claims no stage it never
+        reached, and its trace closed as an error trace."""
+        work.status, work.error_stage = "error", stage
+        if ledger:
+            self._record_cohort(work)
+        self._close_cohort_trace(work, status="error", message=message)
 
     def _close_cohort_trace(
-        self, holder: dict, status: str = "ok", message: str = ""
+        self, work: Cohort, status: str = "ok", message: str = ""
     ) -> None:
-        """Append the cohort's stage spans (ready/fetched/collected,
-        from the holder's perf stamps) to its trace and release the
-        hold taken at dispatch. Pops the ctx so the reclaim path can
+        """Append the cohort's stage spans (fetched/ready, from the
+        record's perf stamps) to its trace and release the
+        hold taken at dispatch. Clears the ctx so the reclaim path can
         never double-release."""
-        tctx = holder.pop("trace", None)
+        tctx, work.trace = work.trace, None
         if tctx is None:
             return
         import time as _time
 
         trace_id, parent = tctx
-        t_disp_pc = holder.get("t_dispatch")
-        base = holder.get("t_dispatch_wall") or _time.time()
-        if t_disp_pc is not None:
-            for name, stamp in (
-                ("cohort.ready", holder.get("t_ready")),
-                ("cohort.fetched", holder.get("t_fetched")),
-            ):
-                if stamp is not None:
-                    trace_api.emit_span(
-                        trace_id, parent, name,
-                        start_ts=base,
-                        end_ts=base + (stamp - t_disp_pc),
-                    )
-            trace_api.emit_span(
-                trace_id, parent, "cohort.collected",
-                start_ts=base,
-                end_ts=base + (_time.perf_counter() - t_disp_pc),
-                status=status, message=message,
-                breaker=self.breaker.state,
-            )
+        base = work.t_dispatch_wall
+        for name, stamp in (
+            ("cohort.fetched", work.t_fetched),
+            ("cohort.ready", work.t_ready),
+        ):
+            if stamp is not None:
+                trace_api.emit_span(
+                    trace_id, parent, name,
+                    start_ts=base, end_ts=base + work.lag(stamp),
+                )
+        trace_api.emit_span(
+            trace_id, parent, "cohort.collected",
+            start_ts=base,
+            end_ts=base + work.lag(_time.perf_counter()),
+            status=status, message=message,
+            breaker=self.breaker.state,
+        )
         trace_api.TRACES.release(trace_id)
 
     def _finalize_batch(self, sel, flat_parts, size_parts, react_parts):
@@ -1497,7 +1547,7 @@ class TpuBackend:
             return max(0.0, deadline - _time.monotonic())
 
         for work in list(self._pipeline_queue):
-            work[0][-1].join(_left())
+            work.thread.join(_left())
         # Warm threads join WITHOUT the deadline: they are pure XLA
         # compiles (bounded, ~seconds) and a daemon compile thread left
         # alive at interpreter teardown aborts the whole process — a
@@ -1878,21 +1928,11 @@ class TpuBackend:
         np.asarray pays the full transfer."""
         import time as _time
 
-        t_disp = _time.perf_counter()
         self._dispatch_counter += 1
-        holder: dict = {
-            "dispatch_seq": self._dispatch_counter,
-            "variant": self._dispatching,
-            "t_dispatch": t_disp,
-            # Wall-clock twin of t_dispatch: ledger consumers (bench
-            # slip gate, profile spans) attribute cohorts to dispatch
-            # windows without reconstructing it from lag arithmetic.
-            "t_dispatch_wall": _time.time(),
-            # Delivery deadline: the cohort must reach players before its
-            # OWN interval ends. collect_ready preempts gap work for a
-            # cohort nearing this stamp (local.py deadline guard).
-            "deadline": t_disp + max(1.0, float(self.config.interval_sec)),
-        }
+        out = Cohort(
+            self._dispatch_counter, self._dispatching, slots,
+            self.config.interval_sec,
+        )
         n_rows = len(slots)
         # HBM ledger: the dispatch ring — candidate/partner tensors
         # alive on device between kernel launch and their D2H fetch
@@ -1902,47 +1942,49 @@ class TpuBackend:
             int(getattr(a, "nbytes", 0)) for a in dev_arrays
         )
         DEVOBS.mem_add("matchmaker.dispatch", dispatch_bytes)
+        annotate = trace_api.annotate
 
         def _fetch(arr):
-            # The blocking D2H read: compute + transfer tail lands on
-            # this clock (the async score call's clock only saw
-            # dispatch + compile time).
+            # The D2H read of a finished array: the `matchmaker.fetch`
+            # clock holds the copy alone, the record the wait before it.
             with DEVOBS.device_call("matchmaker.fetch"):
                 host = np.ascontiguousarray(np.asarray(arr))
             DEVOBS.transfer("cohort.fetch", "d2h", int(host.nbytes))
+            out.d2h_bytes += int(host.nbytes)
             return host
 
-        def _run(out=holder):
+        def _run():
             try:
                 # Chaos: stall delays this cohort's readiness (a slow
                 # D2H/assembly); raise surfaces at collect and walks the
                 # reclamation + breaker path.
                 faults.fire("device.collect")
-                if kind == "pairs":
-                    partner = _fetch(dev_arrays[0])[:n_rows]
-                    proposer = _fetch(dev_arrays[1])[:n_rows]
-                    out["t_fetched"] = _time.perf_counter()
-                    out["asm"] = self._assemble_pairs(
-                        slots, partner, proposer, rev
-                    )
-                    return
-                if kind == "big":
-                    # Already exactly ordered by (-score, created) on
-                    # device; a row slice of the contiguous fetch stays
-                    # C-contiguous.
-                    cand_np = _fetch(dev_arrays[0])[:n_rows]
-                    out["t_fetched"] = _time.perf_counter()
-                else:
-                    scores_np = _fetch(dev_arrays[0])[:n_rows]
-                    cand_np = _fetch(dev_arrays[1])[:n_rows]
-                    out["t_fetched"] = _time.perf_counter()
-                    cand_np = self._order_small(scores_np, cand_np)
-                out["asm"] = self._assemble(slots, last, cand_np, rev)
+                # The wait for the program, apart from the copy: on the
+                # host clock these two were one number that read as
+                # "D2H" while it was device compute.
+                with annotate("cohort.device"):
+                    jax.block_until_ready(dev_arrays)
+                out.t_device_done = _time.perf_counter()
+                with annotate("cohort.d2h"):
+                    fetched = [_fetch(a)[:n_rows] for a in dev_arrays]
+                out.t_fetched = _time.perf_counter()
+                with annotate("cohort.assemble"):
+                    if kind == "pairs":
+                        out.asm = self._assemble_pairs(slots, *fetched, rev)
+                    elif kind == "big":
+                        # Already exactly ordered by (-score, created)
+                        # on device; a row slice of the contiguous fetch
+                        # stays C-contiguous.
+                        out.asm = self._assemble(slots, last, *fetched, rev)
+                    else:
+                        out.asm = self._assemble(
+                            slots, last, self._order_small(*fetched), rev
+                        )
             except Exception as e:  # surfaced at collect
-                out["err"] = e
+                out.err = e
             finally:
                 DEVOBS.mem_add("matchmaker.dispatch", -dispatch_bytes)
-                out["t_ready"] = _time.perf_counter()
+                out.t_ready = _time.perf_counter()
                 # Completion signal LAST (after the ready stamp, so a
                 # woken collector always sees a finished cohort). A
                 # failing callback must never kill the worker before
@@ -1954,9 +1996,9 @@ class TpuBackend:
                     except Exception:
                         pass
 
-        thread = threading.Thread(target=_run, daemon=True)
-        thread.start()
-        return (kind, holder, thread)
+        out.thread = threading.Thread(target=_run, daemon=True)
+        out.thread.start()
+        return out
 
     def _assemble(self, slots, last, cand_np, rev):
         """Native greedy assembly + host validation of flagged matches.
@@ -2377,15 +2419,14 @@ class TpuBackend:
         self._warm_threads.append(t)
         t.start()
 
-    def _collect(self, pending):
+    def _collect(self, work: Cohort):
         """Pick up the worker thread's finished (n_matches, offsets, flat,
         ok) — free when the cohort was ready, a blocking join otherwise
         (non-pipelined mode, or the block-drain fallback)."""
-        _, holder, thread = pending
-        thread.join()
-        if "err" in holder:
-            raise holder["err"]
-        return holder["asm"]
+        work.thread.join()
+        if work.err is not None:
+            raise work.err
+        return work.asm
 
     # ----------------------------------------------------------- validation
 

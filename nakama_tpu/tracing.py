@@ -4,11 +4,35 @@ The reference ships none (SURVEY §5: OpenCensus remnants commented out,
 api.go:190) and the survey sets a higher bar for the TPU build. Three
 layers live here:
 
-1. **Breadcrumbs + ledgers** (`Tracing`): cheap per-interval timing
-   crumbs and bounded event ledgers (deliveries, db drains, breaker and
-   overload transitions) — the aggregate, always-on layer. Every ledger
-   is a `Ledger`: a bounded deque plus a monotonic `total` counter, so
-   "how many ever" questions never read a saturated deque length.
+1. **Breadcrumbs + ledgers** (`Tracing`): the aggregate, always-on
+   layer, every stamp on `time.perf_counter()`. Three records of the
+   matchmaker live here, tied by ids:
+
+   - the **interval record**: one breadcrumb per `process_slots` call
+     (`open_crumb` → `record`), with its `seq`, `_pc_start`/`_pc_end`,
+     the stage sums inside it (`flush_s` … `callback_s`), the `seq` of
+     the cohort it dispatched (`cohort_seq`) and the add stages of
+     every `mm.add` since the previous interval (`AddStages`: `adds`,
+     `add_parse_s`, `add_register_s`, …);
+   - the **cohort record**: one delivery-ledger row per pipelined
+     cohort, made by `matchmaker.tpu.Cohort.row()` from the stamps the
+     cohort carried from dispatch to accept (`device_done_lag_s`,
+     `fetch_lag_s`, `ready_lag_s`, `collect_lag_s`, `accept_lag_s`,
+     each an unrounded lag since `_pc_dispatch`), with its `seq` and
+     the `interval_seq` of the interval that dispatched it;
+   - the **delivery call**: what the `process()` / `collect_pipelined()`
+     call that shipped cohorts did after accept, stamped in place on
+     the row of the oldest cohort it shipped (`deliver_remove_s`,
+     the `publish_*_s` stages and counts of
+     `on_matched`, `delivery_held_s`), beside each row's
+     `publish_lag_s`.
+
+   The coarse sites also open a `jax.profiler.TraceAnnotation`
+   (`annotate`), so a captured profile shows them on the host lines
+   beside the device's. The other ledgers (db drains, breaker and
+   overload transitions) are bounded event lists. Every ledger is a
+   `Ledger`: a bounded deque plus a monotonic `total` counter, so "how
+   many ever" questions never read a saturated deque length.
 
 2. **Request-scoped distributed traces** (module API + `TraceStore`):
    Dapper-style spans carried in a contextvar alongside overload.py's
@@ -900,6 +924,80 @@ class SloRecorder:
 # ------------------------------------------------- aggregate Tracing obj
 
 
+_TRACE_ANNOTATION = None  # jax.profiler.TraceAnnotation, once first used
+
+
+def annotate(name: str):
+    """A host span named `name` in a captured `jax.profiler` trace, on
+    the thread that opens it. For the coarse sites of a backend that
+    runs on JAX only (a handful a tick and a cohort): with no capture
+    running it costs a flag read."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION(name)
+
+
+class AddStages:
+    """Where `mm.add` and its envelope spent their time since the last
+    interval record, summed: `Tracing.record` folds these into the
+    interval breadcrumb and starts them again from zero."""
+
+    __slots__ = (
+        "adds", "adds_enveloped", "pipeline_s", "parse_s", "register_s",
+        "journal_s", "trace_s", "last_s", "_in_envelope",
+    )
+
+    def __init__(self):
+        self.last_s = 0.0  # the newest add alone; never folded
+        self._in_envelope = {}  # id(session) -> its add's seconds
+        self._zero()
+
+    def _zero(self) -> None:
+        self.adds = self.adds_enveloped = 0
+        self.pipeline_s = self.parse_s = self.register_s = 0.0
+        self.journal_s = self.trace_s = 0.0
+
+    def take(self) -> dict:
+        """The sums as breadcrumb keys, and a fresh start."""
+        out = dict(
+            adds=self.adds,
+            adds_enveloped=self.adds_enveloped,
+            add_pipeline_s=self.pipeline_s,
+            add_parse_s=self.parse_s,
+            add_register_s=self.register_s,
+            add_journal_s=self.journal_s,
+            add_trace_s=self.trace_s,
+        )
+        self._zero()
+        return out
+
+    def add(self, t0, t_parsed, t_registered, t_journaled, t_end) -> None:
+        """One `mm.add` that returned a ticket, by its five stamps."""
+        self.adds += 1
+        self.parse_s += t_parsed - t0
+        self.register_s += t_registered - t_parsed
+        self.journal_s += t_journaled - t_registered
+        self.trace_s += t_end - t_journaled
+        self.last_s = t_end - t0
+
+    def enveloped(self, session) -> None:
+        """The `mm.add` that just returned came in `session`'s
+        `matchmaker_add` envelope: its time is not the pipeline's."""
+        self._in_envelope[id(session)] = self.last_s
+
+    def envelope_done(self, session, seconds: float) -> None:
+        """`Pipeline.process` took `seconds` over one `matchmaker_add`
+        envelope of `session`. Counted where its `mm.add` returned a
+        ticket, less that add; a refused envelope is no add."""
+        add_s = self._in_envelope.pop(id(session), None)
+        if add_s is not None:
+            self.adds_enveloped += 1
+            self.pipeline_s += seconds - add_s
+
+
 class Tracing:
     def __init__(self, config=None, logger=None):
         port = 0
@@ -910,6 +1008,8 @@ class Tracing:
         self.logger = logger
         self._profiler_started = False
         self.breadcrumbs = Ledger(capacity)
+        self._crumb_seq = 0
+        self.add_stages = AddStages()
         # Per-cohort pipelined delivery ledger (dispatch→delivered lag,
         # deadline slips): slips are observable here and via metrics,
         # not inferred from bench WARN lines.
@@ -962,8 +1062,8 @@ class Tracing:
 
     @contextlib.contextmanager
     def device_trace(self, out_dir: str):
-        """Capture one jax.profiler trace around a block (used by
-        profile_interval.py and the console's on-demand capture)."""
+        """Capture one jax.profiler trace around a block (the
+        console's on-demand capture)."""
         import jax
 
         jax.profiler.start_trace(out_dir)
@@ -974,18 +1074,36 @@ class Tracing:
 
     # ------------------------------------------------------- breadcrumbs
 
+    def open_crumb(self, **fields) -> dict:
+        """Start a breadcrumb: its `seq` (what a cohort's `interval_seq`
+        names) and `_pc_start`. `record` closes and stores it."""
+        self._crumb_seq += 1
+        return {
+            "seq": self._crumb_seq,
+            "_pc_start": time.perf_counter(),
+            **fields,
+        }
+
     @contextlib.contextmanager
-    def span(self, crumb: dict, key: str):
+    def span(self, crumb: dict, key: str, annotation: str):
         """Accumulating timing crumb (NOT a request-scoped trace span —
         that is the module-level `span()`): adds elapsed seconds under
-        `key` on the aggregate interval breadcrumb."""
+        `key` on the aggregate interval breadcrumb, and shows the block
+        as `annotation` in a captured profile."""
         t0 = time.perf_counter()
         try:
-            yield
+            with annotate(annotation):
+                yield
         finally:
             crumb[key] = crumb.get(key, 0.0) + time.perf_counter() - t0
 
-    def record(self, crumb: dict):
+    def record(self, crumb: dict, *, interval: bool = False):
+        """Close and store a breadcrumb. An `interval` crumb (one per
+        `process_slots` call) takes the add stages summed since the
+        last one."""
+        if interval:
+            crumb.update(self.add_stages.take())
+        crumb["_pc_end"] = time.perf_counter()
         self.breadcrumbs.append(crumb)
 
     def recent(self, n: int = 32) -> list[dict]:
@@ -994,48 +1112,25 @@ class Tracing:
     # -------------------------------------------------- cohort deliveries
 
     def record_delivery(self, **fields) -> dict:
-        """One pipelined cohort delivered: lag attribution + slip flag
-        (tpu.py accept path). Kept separate from interval breadcrumbs so
-        mid-gap deliveries don't dilute per-interval timing rows.
-        Returns the stored entry — later stage stamps (mark_published)
-        mutate it in place, so holders of the return value see them."""
+        """One pipelined cohort delivered or lost: the row its record
+        makes (`tpu.py Cohort.row`). Kept separate from interval
+        breadcrumbs so mid-gap deliveries don't dilute per-interval
+        timing rows. Returns the stored row: the publish lag and the
+        delivery call's stamps are written on it in place (local.py),
+        so holders of the return value see them."""
         self.deliveries.append(fields)
         return fields
 
     def recent_deliveries(self, n: int = 32) -> list[dict]:
         return self.deliveries.recent(n)
 
-    def mark_published(
-        self, pc_now: float, max_n: int | None = None
-    ) -> list[float]:
-        """Stamp dispatch→published lag on the newest ledger entries
-        that have none yet (the cohorts whose batch the caller just
-        handed to `on_matched`), closing each entry's stage chain:
-        ready_lag_s → fetch_lag_s → collect_lag_s → accept_lag_s →
-        publish_lag_s, all relative to dispatch. `max_n` bounds the
-        stamping to the entries one collect call recorded, so a cohort
-        that never published (empty batch, no callback) cannot absorb a
-        much-later publish stamp. Returns the lags stamped."""
-        out: list[float] = []
-        for entry in reversed(self.deliveries):
-            if "publish_lag_s" in entry:
-                break
-            if max_n is not None and len(out) >= max_n:
-                break
-            t_disp = entry.get("_pc_dispatch")
-            if t_disp is None:
-                continue
-            lag = pc_now - t_disp
-            entry["publish_lag_s"] = round(lag, 3)
-            out.append(lag)
-        return out
-
     def delivery_stage_stats(self) -> dict:
         """p50/p99 per delivery stage over the retained ledger — the
-        one-call attribution surface (profile_interval.py, console): a
-        delivery-gap regression names its stage here instead of hiding
-        inside a single end-to-end number."""
-        stages = (  # chain order: D2H fetch, then assembly completes
+        one-call attribution surface (console): a delivery-gap
+        regression names its stage here instead of hiding inside a
+        single end-to-end number."""
+        stages = (  # chain order, each a lag since dispatch
+            "device_done_lag_s",
             "fetch_lag_s",
             "ready_lag_s",
             "collect_lag_s",

@@ -78,13 +78,30 @@ def test_tracing_ledgers_all_answer_how_many_ever():
     assert len(t.deliveries) == 256
 
 
-def test_mark_published_still_uses_monotonic_counter():
+def test_interval_crumb_takes_the_add_stages_and_a_seq():
+    """`record(interval=True)` folds the add stages summed since the
+    last interval crumb and starts them again; every crumb has a seq
+    and both perf_counter stamps (mid-gap crumbs take no add stages)."""
     t = Tracing()
-    t.record_delivery(_pc_dispatch=1.0)
-    t.record_delivery(_pc_dispatch=2.0)
-    lags = t.mark_published(5.0, max_n=2)
-    assert [round(x, 1) for x in lags] == [3.0, 4.0]
-    assert t.mark_published(9.0, max_n=2) == []  # already stamped
+    t.add_stages.add(1.0, 1.25, 1.5, 1.75, 2.0)
+    session = object()
+    t.add_stages.enveloped(session)
+    t.add_stages.envelope_done(session, 1.5)
+    t.add_stages.envelope_done(object(), 9.0)  # refused: no add of its own
+    t.add_stages.add(3.0, 3.125, 3.25, 3.375, 3.5)
+    gap = t.open_crumb(midgap_collect=True)
+    t.record(gap)
+    assert "adds" not in gap
+    crumb = t.open_crumb(actives=7)
+    t.record(crumb, interval=True)
+    assert crumb["seq"] == gap["seq"] + 1
+    assert crumb["_pc_start"] <= crumb["_pc_end"]
+    assert (crumb["adds"], crumb["adds_enveloped"]) == (2, 1)
+    assert crumb["add_pipeline_s"] == 0.5  # 1.5 s envelope - 1.0 s add
+    assert crumb["add_parse_s"] == crumb["add_trace_s"] == 0.375
+    nxt = t.open_crumb(actives=0)
+    t.record(nxt, interval=True)
+    assert (nxt["adds"], nxt["add_parse_s"], nxt["add_pipeline_s"]) == (0, 0, 0)
 
 
 # -------------------------------------------------------- traceparent
