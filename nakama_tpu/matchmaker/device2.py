@@ -26,6 +26,14 @@ bench size) are gathered and re-checked *exactly* — full interval/term/
 forbidden compares, count-range, party/self/pool/validity, mutual (rev)
 when on, exact should-boost and embedding scores — then lexicographically
 sorted by (-score, created) on device. Stage-1 false positives die here.
+A candidate is gathered as rows only: its `num`, `str` and `emb` rows
+(and its query mirrors under rev) from the pool's row tables, and its six
+scalar columns (RECORD_KEYS) as one row of a record table built from the
+pool once per dispatch. On a v5e a gather costs by the index, not by the
+word: one word from a 1-D column 7.45 ns, a row of 8 to 64 words 1.8 ns
+(PR 24's and PR 25's traces). Six one-word gathers for each of 16.8
+million candidates were 0.735 s of a 1.14 s pass; the record row is
+0.030 s (PERF.md, PR 25).
 Stage 1's eligibility test is a superset filter, so it never rejects a
 true candidate; its per-block argmax can still drop one, when false
 positives of the same block outrank it (a required string term whose
@@ -42,7 +50,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -62,9 +69,10 @@ PACKED_NONE = -(2**31)  # plain int: pallas kernels must not capture arrays
 VMEM_LIMIT_BYTES = 16 << 20
 MIN_ROW_TILE = 128
 # Stage 2 re-ranks the active rows in stripes sized so one stripe's
-# candidate gather stays under this many bytes: its temporaries then do
-# not grow with the pool (un-striped, a 131072-row dispatch needs 9 GB
-# of HBM without mutual matching and 32 GB with it).
+# candidate gather (per kept winner: its record row and a row of every
+# table in _stage2_tables) stays under this many bytes: its temporaries
+# then do not grow with the pool (un-striped, a 131072-row dispatch
+# needs 9 GB of HBM without mutual matching and 32 GB with it).
 STAGE2_GATHER_BYTES = 256 << 20
 
 # Every pool field the row (query) side of the kernels reads.
@@ -669,9 +677,10 @@ def _stage2(
     temporaries bounded by the stripe, not by A_pad."""
     a_pad = active_slots.shape[0]
     keep = min(winners.shape[1], max(2 * k, 8))  # see _stage2_rows
-    words = sum(
-        int(np.prod(pool_n[key].shape[1:]))
-        for key in _stage2_columns(rev)
+    # Built once, before the stripes: the loop body only reads it.
+    record = _stage2_record(pool_n)
+    words = record.shape[1] + sum(
+        pool_n[key].shape[1] for key in _stage2_tables(rev)
     )
     stripe = a_pad
     while (
@@ -683,7 +692,7 @@ def _stage2(
     def rerank(args):
         rq, act, win = args
         return _stage2_rows(
-            pool_n, rq, act, win, k=k, keep=keep, rev=rev,
+            pool_n, record, rq, act, win, k=k, keep=keep, rev=rev,
             with_should=with_should, with_embedding=with_embedding,
             order_exact=order_exact,
         )
@@ -705,14 +714,18 @@ def _stage2(
     return out.reshape(a_pad, k)
 
 
-def _stage2_columns(rev: bool) -> list[str]:
-    """Pool columns the exact checks gather per candidate — the
-    candidate's VALUES and slot metadata always; its QUERY mirrors only
-    under rev (mutual)."""
-    needed = [
-        "num", "str", "emb", "min_count", "max_count", "party", "pool_id",
-        "flags", "created",
-    ]
+# A candidate's scalar columns, in the order its record row holds them
+# (no padding: rows of 6, 8 and 16 words gather in the same time).
+RECORD_KEYS = (
+    "min_count", "max_count", "party", "pool_id", "flags", "created",
+)
+
+
+def _stage2_tables(rev: bool) -> list[str]:
+    """Pool row tables ([n, w]) the exact checks gather per candidate
+    beside its record — the candidate's VALUES always; its QUERY mirrors
+    only under rev (mutual)."""
+    needed = ["num", "str", "emb"]
     if rev:
         needed += [
             "n_lo", "n_hi", "n_flo", "n_fhi", "s_req", "s_forb",
@@ -721,9 +734,27 @@ def _stage2_columns(rev: bool) -> list[str]:
     return needed
 
 
+def _stage2_record(pool_n):
+    """int32 [n, len(RECORD_KEYS)]: row j holds slot j's scalars."""
+    return jnp.stack([pool_n[key] for key in RECORD_KEYS], axis=1)
+
+
+def _stage2_gather(pool_n, record, cand, rev):
+    """Everything the exact checks read of the candidates `cand`
+    [R, B], by pool key → [R, B, ...]: one row gather per table, the
+    scalars as columns of the gathered record block."""
+    col = {key: pool_n[key][cand] for key in _stage2_tables(rev)}
+    # Fields come out of the gathered block [R, B, 6] along its second
+    # axis: a slice of the last one leaves six [R, B, 1] blocks, each
+    # tiled out to the size of the whole record block.
+    rec = jnp.swapaxes(record[cand], 1, 2)  # [R, 6, B]
+    col.update({key: rec[:, i] for i, key in enumerate(RECORD_KEYS)})
+    return col
+
+
 def _stage2_rows(
-    pool_n, rowq, active_slots, winners, *, k, keep, rev, with_should,
-    with_embedding, order_exact,
+    pool_n, record, rowq, active_slots, winners, *, k, keep, rev,
+    with_should, with_embedding, order_exact,
 ):
     """One stripe of `_stage2`: rows [R] against their winners [R, B],
     pre-trimmed to the `keep` best by stage-1 priority."""
@@ -740,9 +771,7 @@ def _stage2_rows(
     alive = winners != PACKED_NONE
 
     # Gather only what the exact checks read.
-    col = {
-        key: pool_n[key][cand] for key in _stage2_columns(rev)
-    }  # [A, B, ...]
+    col = _stage2_gather(pool_n, record, cand, rev)  # [A, B, ...]
 
     # Exact per-field predicate, reusing the small-kernel form: _accepts
     # wants fcol [Bc,...] vs qrow [Br,...]; vmap over rows gives

@@ -99,6 +99,15 @@ def test_big_kernel_full_pool_dispatch(one_chip, emb_rev):
         with_embedding=emb, interpret=False,
     ).compile()
     assert _fits(compiled, pallas=True).temp_size_in_bytes < 4e9
+    # Stage 2 gathers rows, never single words: a one-word gather from a
+    # 1-D pool column costs four times a 16-word row on the chip, and the
+    # re-rank loop issued six of them for every candidate.
+    one_word = [
+        line.strip()[:200] for line in compiled.as_text().splitlines()
+        if " gather(" in line and "while/body" in line
+        and "slice_sizes={1}" in line
+    ]
+    assert not one_word, one_word
 
 
 def test_big_kernel_sharded_mutual(topo):

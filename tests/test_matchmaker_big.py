@@ -211,9 +211,8 @@ def test_big_kernel_output_independent_of_chip_shapes(
         got = topk(pool, slots, grid_lo, grid_inv, bm=16, **kw)
     else:
         # 512 rows x 64 kept winners x 4 B x words -> 8 stripes of 64 rows
-        words = sum(
-            int(np.prod(pool[c].shape[1:]))
-            for c in device2._stage2_columns(rev)
+        words = len(device2.RECORD_KEYS) + sum(
+            pool[c].shape[1] for c in device2._stage2_tables(rev)
         )
         monkeypatch.setattr(
             device2, "STAGE2_GATHER_BYTES", 64 * 64 * 4 * words
@@ -222,6 +221,53 @@ def test_big_kernel_output_independent_of_chip_shapes(
         got = topk(pool, slots, grid_lo, grid_inv, bm=64, **kw)
         topk.clear_cache()
     assert np.array_equal(base, np.asarray(got))
+
+
+@pytest.mark.parametrize("order_exact", [True, False])
+@pytest.mark.parametrize("with_should", [False, True])
+@pytest.mark.parametrize("rev", [False, True])
+def test_stage2_record_round_trips(rev, with_should, order_exact, monkeypatch):
+    """A candidate's scalars travel as one record row: what stage 2
+    unpacks for a candidate block is, key by key and bit for bit, what a
+    gather of each pool column gives, and the kernel's output is what it
+    is with those column gathers (the parent's) in the record's place."""
+    from nakama_tpu.matchmaker import device2
+
+    pool, slots, grid_lo, grid_inv, kw = _kernel_inputs(rev)
+    kw.update(with_should=with_should, order_exact=order_exact)
+    n = kw["n_cols"]
+    rng = np.random.default_rng(8)
+    pool = {key: np.array(v[:n]) for key, v in pool.items()}
+    # Words a lossy packing would bend: negative values, the high bit.
+    odd = rng.choice(n, size=3 * 40, replace=False).reshape(3, 40)
+    pool["created"][odd[0]] = -1 - rng.integers(0, 2**31, 40)
+    pool["party"][odd[1]] = -1 - rng.integers(0, 2**31, 40)
+    pool["flags"][odd[2]] |= np.int32(-(2**31))
+
+    def by_column(pool_n, record, cand, rev):
+        keys = device2._stage2_tables(rev) + list(device2.RECORD_KEYS)
+        return {key: pool_n[key][cand] for key in keys}
+
+    cand = rng.integers(0, n, size=(96, 64)).astype(np.int32)
+    cand[0, :40], cand[1, :40], cand[2, :40] = odd
+    got = device2._stage2_gather(
+        pool, device2._stage2_record(pool), cand, rev
+    )
+    want = by_column(pool, None, cand, rev)
+    assert set(device2.RECORD_KEYS) < set(got) == set(want)
+    for key, block in want.items():
+        assert got[key].dtype == block.dtype, key
+        assert np.asarray(got[key]).tobytes() == block.tobytes(), key
+
+    topk = device2.topk_candidates_big
+    topk.clear_cache()
+    out = np.asarray(topk(pool, slots, grid_lo, grid_inv, bm=64, **kw))
+    monkeypatch.setattr(device2, "_stage2_gather", by_column)
+    topk.clear_cache()
+    ref = np.asarray(topk(pool, slots, grid_lo, grid_inv, bm=64, **kw))
+    topk.clear_cache()
+    assert (ref >= 0).sum() > 1000
+    assert np.array_equal(out, ref)
 
 
 @pytest.mark.parametrize(
