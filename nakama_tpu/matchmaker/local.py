@@ -419,6 +419,15 @@ class LocalMatchmaker:
                         self.metrics.mm_gap_shed.inc()
                 else:
                     shed_streak = 0
+                    # Delivered cohorts' candidate-list counters go on
+                    # their ledger rows here, before the drain: they
+                    # read the removed tickets' slots as matched.
+                    try:
+                        count = getattr(self.backend, "count_cohorts", None)
+                        if count is not None:
+                            count()
+                    except Exception as e:
+                        self.logger.error("cohort count error", error=str(e))
                     # Preemptible: stop the teardown pass early rather
                     # than queue a due cohort delivery behind it. The
                     # budget is floored at 200ms forward — when the head

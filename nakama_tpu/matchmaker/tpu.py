@@ -105,8 +105,9 @@ class Cohort:
         # record with this `seq` dispatched the cohort.
         "seq", "interval_seq", "variant", "actives",
         # the work: dispatched slots, the store generations at dispatch,
-        # the worker and what it leaves
-        "slots", "gen", "thread", "asm", "err",
+        # the worker and what it leaves (`cand`: the fetched candidate
+        # lists, kept until `list_counts` has read them)
+        "slots", "gen", "thread", "asm", "err", "cand", "pool",
         # stamps, perf_counter seconds (wall twins for trace spans)
         "t_dispatch", "t_dispatch_wall", "t_window_wall", "deadline",
         "t_device_done", "t_fetched", "t_ready", "t_collect", "t_accept",
@@ -128,6 +129,8 @@ class Cohort:
         self.thread = None
         self.asm = None
         self.err = None
+        self.cand = None
+        self.pool = 0  # tickets in the pool at dispatch
         self.t_dispatch = _time.perf_counter()
         # Wall-clock twin of t_dispatch: ledger consumers (bench slip
         # gate, trace spans) attribute cohorts to dispatch windows
@@ -203,6 +206,35 @@ class Cohort:
         self.t_publish = now
         self.entry["publish_lag_s"] = lag = now - self.t_dispatch
         return lag
+
+    def list_counts(self, count, max_count) -> dict:
+        """What the query filter left of this cohort's candidate lists
+        and what the assembler made of them, as row keys; `count` and
+        `max_count` are the store's per-slot columns, which a removed
+        ticket's slot keeps until the store drains. Lets go of the
+        lists: one read."""
+        n, offsets, flat, _ = self.asm
+        flat = flat[: offsets[n]]
+        # One spare cell: -1, "no candidate", lands there.
+        seen = np.zeros(len(count) + 1, dtype=bool)
+        seen[flat] = True
+        entries = np.concatenate(([0], np.cumsum(count[flat])))
+        sizes = entries[offsets[1 : n + 1]] - entries[offsets[:n]]
+        searcher = flat[offsets[1 : n + 1] - 1]  # last slot of its match
+        out = dict(
+            actives_unmatched=self.actives - int(seen[self.slots].sum()),
+            matches_below_max=int((sizes < max_count[searcher]).sum()),
+        )
+        cand, self.cand = self.cand, None
+        if cand is not None:
+            seen[:] = False
+            seen[cand.ravel()] = True
+            out.update(
+                candidates_valid=int(np.count_nonzero(cand >= 0)),
+                candidates_distinct=int(seen[:-1].sum()),
+                candidates_pool=self.pool,
+            )
+        return out
 
 
 class TpuBackend:
@@ -375,6 +407,10 @@ class TpuBackend:
         # re-dispatched meanwhile (mask cleared on collection and on slot
         # reuse by a new add).
         self._pipeline_queue: deque = deque()
+        # Recorded cohorts whose candidate lists nobody has counted yet
+        # (`count_cohorts`, the interval loop's idle gap). Bounded: where
+        # no loop sweeps, the oldest fall out uncounted.
+        self._uncounted: deque = deque(maxlen=8)
         self._in_flight_mask = np.zeros(cap, dtype=bool)
         # Row-bucket shapes already compiled (or prewarmed) this process.
         self._warmed_buckets: set[tuple] = set()
@@ -1460,6 +1496,26 @@ class TpuBackend:
             work.t_window_wall or work.t_dispatch_wall, _time.time()
         )
         work.entry = self.tracing.record_delivery(**row)
+        if work.asm is not None:
+            self._uncounted.append(work)
+
+    def count_cohorts(self) -> None:
+        """Idle-gap sweep (the interval loop calls it before the store
+        drains): put on each recorded cohort's ledger row what filtering
+        did to its candidate lists — `candidates_valid` (list cells that
+        hold a ticket after stage 2), `candidates_distinct` (tickets in
+        at least one list) of `candidates_pool` (tickets in the pool at
+        dispatch) — and what the assembler made of them:
+        `actives_unmatched` (searchers in no match), `matches_below_max`
+        (matches smaller than their searcher's max_count). O(actives x
+        k) numpy, which is why no stage between dispatch and publish
+        pays for it; a pairs cohort has no lists and gets the last two."""
+        meta = self.meta
+        while self._uncounted:
+            work = self._uncounted.popleft()
+            work.entry.update(
+                work.list_counts(meta["count"], meta["max_count"])
+            )
 
     def _lose_cohort(
         self, work: Cohort, stage: str, message: str, ledger: bool = True
@@ -1933,6 +1989,7 @@ class TpuBackend:
             self._dispatch_counter, self._dispatching, slots,
             self.config.interval_sec,
         )
+        out.pool = self.store.n_live
         n_rows = len(slots)
         # HBM ledger: the dispatch ring — candidate/partner tensors
         # alive on device between kernel launch and their D2H fetch
@@ -1975,11 +2032,11 @@ class TpuBackend:
                         # Already exactly ordered by (-score, created)
                         # on device; a row slice of the contiguous fetch
                         # stays C-contiguous.
-                        out.asm = self._assemble(slots, last, *fetched, rev)
+                        (out.cand,) = fetched
+                        out.asm = self._assemble(slots, last, out.cand, rev)
                     else:
-                        out.asm = self._assemble(
-                            slots, last, self._order_small(*fetched), rev
-                        )
+                        out.cand = self._order_small(*fetched)
+                        out.asm = self._assemble(slots, last, out.cand, rev)
             except Exception as e:  # surfaced at collect
                 out.err = e
             finally:

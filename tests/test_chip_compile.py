@@ -81,6 +81,24 @@ def _fits(compiled, pallas: bool):
     return mem
 
 
+def _compile_big(one_chip, rows, n_cols, emb, rev):
+    """The two-stage kernel for `rows` actives against `n_cols` pool
+    columns at shipped widths; its temporaries must stay far under the
+    chip."""
+    a = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+    grid = jax.ShapeDtypeStruct(
+        (CFG.numeric_fields,), jnp.float32, sharding=one_chip
+    )
+    compiled = device2.topk_candidates_big.lower(
+        _pool(one_chip), a, grid, grid,
+        fn=CFG.numeric_fields, fs=CFG.string_fields, n_cols=n_cols,
+        k=CFG.candidates_per_ticket, rev=rev, with_should=False,
+        with_embedding=emb, interpret=False,
+    ).compile()
+    assert _fits(compiled, pallas=True).temp_size_in_bytes < 4e9
+    return compiled
+
+
 @pytest.mark.parametrize("emb_rev", [(False, False), (True, True)])
 def test_big_kernel_full_pool_dispatch(one_chip, emb_rev):
     """A fresh 100k pool dispatches all actives in one pass: 131072 rows
@@ -88,17 +106,7 @@ def test_big_kernel_full_pool_dispatch(one_chip, emb_rev):
     over (stage-1 VMEM, stage-2 HBM) before the row tile was derived and
     stage 2 striped; temporaries must stay far under the chip."""
     emb, rev = emb_rev
-    a = jax.ShapeDtypeStruct((CAP,), jnp.int32, sharding=one_chip)
-    grid = jax.ShapeDtypeStruct(
-        (CFG.numeric_fields,), jnp.float32, sharding=one_chip
-    )
-    compiled = device2.topk_candidates_big.lower(
-        _pool(one_chip), a, grid, grid,
-        fn=CFG.numeric_fields, fs=CFG.string_fields, n_cols=CAP,
-        k=CFG.candidates_per_ticket, rev=rev, with_should=False,
-        with_embedding=emb, interpret=False,
-    ).compile()
-    assert _fits(compiled, pallas=True).temp_size_in_bytes < 4e9
+    compiled = _compile_big(one_chip, CAP, CAP, emb, rev)
     # Stage 2 gathers rows, never single words: a one-word gather from a
     # 1-D pool column costs four times a 16-word row on the chip, and the
     # re-rank loop issued six of them for every candidate.
@@ -108,6 +116,21 @@ def test_big_kernel_full_pool_dispatch(one_chip, emb_rev):
         and "slice_sizes={1}" in line
     ]
     assert not one_word, one_word
+
+
+@pytest.mark.parametrize("rows", [1 << 16, 2048])
+def test_big_kernel_squad_pool_dispatch(one_chip, rows):
+    """`squad50k.burst`'s two dispatches: 50,000 tickets without an
+    embedding pad to 65,536 rows against 65,536 columns (64 column
+    blocks, two winners a block), and the fill tick sends the leftovers,
+    one or two row blocks, against the same columns."""
+    n_cols = 1 << 16
+    _compile_big(one_chip, rows, n_cols, emb=False, rev=False)
+    assert device2.stage1_plan(
+        n=n_cols, n_local=n_cols, k=CFG.candidates_per_ticket, bm=1024,
+        bn=1024, fn=CFG.numeric_fields, fs=CFG.string_fields, de=8,
+        rev=False,
+    )[0] == 2
 
 
 def test_big_kernel_sharded_mutual(topo):

@@ -350,6 +350,7 @@ def produced():
     """The ledger rows and interval crumbs of one small pipelined run."""
     rig = Rig()
     asyncio.run(_cycle(rig, 2))
+    rig.backend.count_cohorts()  # the interval loop's idle-gap sweep
     rig.mm.process()
     return rig.tracing.recent_deliveries(8), rig.crumbs()
 
