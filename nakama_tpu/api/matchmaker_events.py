@@ -10,7 +10,10 @@ every matched presence.
 
 from __future__ import annotations
 
-import uuid
+import json
+import os
+import time
+from json.encoder import encode_basestring_ascii
 from time import perf_counter
 from typing import Any
 
@@ -20,6 +23,26 @@ from ..realtime import PresenceID
 from . import session_token
 
 MATCH_TOKEN_EXPIRY_SEC = 30
+
+# Byte 6 and byte 8 of sixteen random ones with the version (4) and the
+# variant (RFC 4122) set, for `bytes.translate` over a whole batch.
+_UUID_VERSION_4 = bytes((b & 0x0F) | 0x40 for b in range(256))
+_UUID_VARIANT_RFC = bytes((b & 0x3F) | 0x80 for b in range(256))
+
+
+def _uuid4_texts(n: int) -> list[str]:
+    """`n` version-4 UUIDs in their canonical text, from one read of the
+    OS's entropy: 122 random bits an id, as `uuid.uuid4` takes them,
+    without its object."""
+    raw = bytearray(os.urandom(16 * n))
+    raw[6::16] = raw[6::16].translate(_UUID_VERSION_4)
+    raw[8::16] = raw[8::16].translate(_UUID_VARIANT_RFC)
+    h = raw.hex()
+    return [
+        f"{h[i:i + 8]}-{h[i + 8:i + 12]}-{h[i + 12:i + 16]}-"
+        f"{h[i + 16:i + 20]}-{h[i + 20:i + 32]}"
+        for i in range(0, 32 * n, 32)
+    ]
 
 
 def make_matched_handler(
@@ -31,18 +54,40 @@ def make_matched_handler(
 ):
     log = logger.with_fields(subsystem="matchmaker.matched")
 
+    # The match token is `session_token.generate(key, user_list, "", 30,
+    # vars={"kind": "match_token", "node": node, "mid": f"{mid}.{node}"},
+    # token_id=tid)` byte for byte (tests/test_match_token.py): what of
+    # its payload no match changes is laid out here once, as `json.dumps`
+    # lays it out, and a match fills in its two ids, its users and its
+    # expiry.
+    key = encryption_key.encode()
+    node_json = json.dumps(node)
+    before_tid = '{"tid": "'
+    after_tid = '", "uid": '
+    after_uid = ', "usn": "", "exp": '
+    after_exp = (
+        ', "vrs": {"kind": "match_token", "node": ' + node_json
+        + ', "mid": "'
+    )
+    after_mid = "." + node_json[1:-1] + '"}}'
+
     # Where a publish goes, summed over the matches of one batch; the
     # matchmaker moves the sums onto the delivery call's ledger row and
     # zeroes them (local.py `_publish`). Five stamps a match, none an
     # entry: a match's bodies are all built before the first is routed.
     stages = dict(
-        publish_matches=0, publish_envelopes=0,
+        publish_matches=0, publish_envelopes=0, publish_tokens=0,
         publish_materialise_s=0.0, publish_hook_s=0.0,
         publish_token_s=0.0, publish_envelope_s=0.0, publish_route_s=0.0,
     )
 
     def on_matched(matched: list[list[MatchmakerEntry]]):
+        t_batch = perf_counter()
+        # Two ids a match, the rendezvous' and the token's own, from one
+        # read for the batch; the read is the token stage's.
+        ids = iter(_uuid4_texts(2 * len(matched)))
         t_next = perf_counter()
+        stages["publish_token_s"] += t_next - t_batch
         for entries in matched:
             # Between two matches the time is the batch iterator's: a
             # columnar batch makes a match's entry list only here.
@@ -60,24 +105,24 @@ def make_matched_handler(
             if not match_id:
                 user_list = ",".join(
                     sorted(
-                        f"{e.presence.user_id}:{e.presence.username}"
-                        for e in entries
+                        [
+                            f"{e.presence.user_id}:{e.presence.username}"
+                            for e in entries
+                        ]
                     )
                 )
                 # The token names a relayed-match rendezvous id every matched
                 # client can join (reference matchmaker.go:392-399).
-                rendezvous = f"{uuid.uuid4()}.{node}"
-                token, _ = session_token.generate(
-                    encryption_key,
-                    user_list,
-                    "",
-                    MATCH_TOKEN_EXPIRY_SEC,
-                    vars={
-                        "kind": "match_token",
-                        "node": node,
-                        "mid": rendezvous,
-                    },
+                token = session_token.sign(
+                    key,
+                    (
+                        f"{before_tid}{next(ids)}{after_tid}"
+                        f"{encode_basestring_ascii(user_list)}{after_uid}"
+                        f"{int(time.time() + MATCH_TOKEN_EXPIRY_SEC)}"
+                        f"{after_exp}{next(ids)}{after_mid}"
+                    ).encode(),
                 )
+                stages["publish_tokens"] += 1
             t_token = perf_counter()
 
             ticket_of = {e.presence.session_id: e.ticket for e in entries}

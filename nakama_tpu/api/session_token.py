@@ -9,7 +9,6 @@ invalidates live tokens.
 from __future__ import annotations
 
 import base64
-import hashlib
 import hmac
 import json
 import time
@@ -17,19 +16,29 @@ import uuid
 from dataclasses import dataclass, field
 
 
-def _b64(data: bytes) -> str:
-    return base64.urlsafe_b64encode(data).rstrip(b"=").decode()
+def _b64(data: bytes) -> bytes:
+    return base64.urlsafe_b64encode(data).rstrip(b"=")
 
 
 def _unb64(data: str) -> bytes:
     return base64.urlsafe_b64decode(data + "=" * (-len(data) % 4))
 
 
-_HEADER = _b64(json.dumps({"alg": "HS256", "typ": "JWT"}).encode())
+_HEADER_DOT = _b64(json.dumps({"alg": "HS256", "typ": "JWT"}).encode()) + b"."
 
 
 class TokenError(ValueError):
     pass
+
+
+def sign(key: bytes, payload: bytes) -> str:
+    """The token over a serialised payload: the one place the format
+    (header, unpadded urlsafe base64, HS256) is put together. `generate`
+    goes through it, and so does the matched handler, which serialises
+    its match token's payload itself (api/matchmaker_events.py)."""
+    signing_input = _HEADER_DOT + _b64(payload)
+    sig = hmac.digest(key, signing_input, "sha256")
+    return (signing_input + b"." + _b64(sig)).decode()
 
 
 @dataclass
@@ -63,11 +72,7 @@ def generate(
         "exp": int(claims.expires_at),
         "vrs": claims.vars,
     }
-    signing_input = _HEADER + "." + _b64(json.dumps(payload).encode())
-    sig = hmac.new(
-        key.encode(), signing_input.encode(), hashlib.sha256
-    ).digest()
-    return signing_input + "." + _b64(sig), claims
+    return sign(key.encode(), json.dumps(payload).encode()), claims
 
 
 def parse(key: str, token: str) -> SessionClaims:
@@ -76,9 +81,7 @@ def parse(key: str, token: str) -> SessionClaims:
     except ValueError as e:
         raise TokenError("malformed token") from e
     signing_input = header_b64 + "." + payload_b64
-    expected = hmac.new(
-        key.encode(), signing_input.encode(), hashlib.sha256
-    ).digest()
+    expected = hmac.digest(key.encode(), signing_input.encode(), "sha256")
     if not hmac.compare_digest(expected, _unb64(sig_b64)):
         raise TokenError("bad signature")
     try:

@@ -177,33 +177,37 @@ async def test_console_accounts_storage_and_ban():
         await server.stop(0)
 
 
-async def test_console_matchmaker_breadcrumbs():
-    """Device-backend breadcrumbs surface through the console (SURVEY §5
-    per-interval timing observability)."""
-    from nakama_tpu.matchmaker import LocalMatchmaker, MatchmakerPresence
+async def _tick_on_device_backend(tickets: int, pipelined: bool):
+    """A started server on the device backend (small exact kernel) after
+    one `process()` over `tickets` wildcard 1v1 tickets, and its console."""
+    from nakama_tpu.matchmaker import MatchmakerPresence
     from nakama_tpu.matchmaker.tpu import TpuBackend
 
     config = Config()
     config.socket.port = 0
     config.matchmaker.pool_capacity = 4096
     config.matchmaker.big_pool_threshold = 1 << 30  # small exact kernel
-    # Synchronous interval: the breadcrumb assertions below need one
-    # process() to dispatch AND deliver (the pipelined default delivers
-    # mid-gap, one interval later).
-    config.matchmaker.interval_pipelining = False
+    config.matchmaker.interval_pipelining = pipelined
     server = NakamaServer(config, quiet_logger())
     backend = TpuBackend(config.matchmaker, quiet_logger())
     server.matchmaker.backend = backend
     backend.attach(server.matchmaker.store)
     await server.start()
-    console = Console(server)
+    for i in range(tickets):
+        p = MatchmakerPresence(user_id=f"u{i}", session_id=f"s{i}")
+        server.matchmaker.add([p], p.session_id, "", "*", 2, 2, 1, {}, {})
+    server.matchmaker.process()
+    return server, Console(server)
+
+
+async def test_console_matchmaker_breadcrumbs():
+    """Device-backend breadcrumbs surface through the console (SURVEY §5
+    per-interval timing observability)."""
+    # Synchronous interval: the breadcrumb assertions below need one
+    # process() to dispatch AND deliver (the pipelined default delivers
+    # mid-gap, one interval later).
+    server, console = await _tick_on_device_backend(2, pipelined=False)
     try:
-        for i in range(2):
-            p = MatchmakerPresence(user_id=f"u{i}", session_id=f"s{i}")
-            server.matchmaker.add(
-                [p], p.session_id, "", "*", 2, 2, 1, {}, {}
-            )
-        server.matchmaker.process()
         await console.login()
         status, out = await console.call("GET", "/v2/console/matchmaker")
         assert status == 200
@@ -213,6 +217,28 @@ async def test_console_matchmaker_breadcrumbs():
         assert crumb["actives"] == 2
         assert crumb["matched_entries"] == 2
         assert "dispatch_s" in crumb and "collect_s" in crumb
+    finally:
+        await console.close()
+        await server.stop(0)
+
+
+async def test_console_matchmaker_delivery_row_counts_tokens():
+    """A pipelined cohort's delivery row reaches the console with the
+    publish callback's counts on it (README "Reading the records")."""
+    server, console = await _tick_on_device_backend(4, pipelined=True)
+    try:
+        await console.login()
+        for _ in range(400):
+            status, out = await console.call("GET", "/v2/console/matchmaker")
+            assert status == 200
+            rows = [r for r in out["deliveries"] if "publish_lag_s" in r]
+            if rows:
+                break
+            await asyncio.sleep(0.05)
+        (row,) = rows
+        assert row["matches"] == row["publish_matches"] == 2
+        assert row["publish_tokens"] == 2 and row["publish_envelopes"] == 4
+        assert row["publish_token_s"] > 0.0
     finally:
         await console.close()
         await server.stop(0)

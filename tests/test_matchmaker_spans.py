@@ -278,6 +278,7 @@ async def test_publish_stages_add_up_to_the_publish_lag():
     ]
     assert row["publish_envelopes"] == len(matched) == 64
     assert row["publish_matches"] == row["matches"] == 32
+    assert row["publish_tokens"] == 32  # no hook took a match
     assert row["envelopes"] == 64
     assert all(
         len(e["matchmaker_matched"]["users"]) == 2
@@ -293,6 +294,43 @@ async def test_publish_stages_add_up_to_the_publish_lag():
     assert row["delivery_held_s"] >= whole
     assert row["publish_hook_s"] < row["publish_token_s"]  # no runtime
     # the handler's sums were moved, not copied
+    assert set(rig.mm.on_matched.stages.values()) == {0}
+
+
+class _EveryOtherMatchIsTheHooks:
+    """A runtime whose matched hook names a match of its own for every
+    second match it is shown."""
+
+    def __init__(self):
+        self.shown = 0
+
+    def matchmaker_matched(self):
+        def hook(entries):
+            self.shown += 1
+            return "" if self.shown % 2 else f"auth-{self.shown}.n1"
+
+        return hook
+
+
+@pytest.mark.parametrize("via", ["collect", "process"])
+async def test_row_counts_tokens_as_matches_less_the_hooks(via):
+    rig = Rig()
+    rig.mm.on_matched = make_matched_handler(
+        quiet_logger(), rig.pipeline.c.router, "n1", "k" * 32,
+        runtime=_EveryOtherMatchIsTheHooks(),
+    )
+    sessions = await _cycle(rig, 5, via)
+    (row,) = rig.tracing.recent_deliveries(1)
+    bodies = [
+        e["matchmaker_matched"] for s in sessions for e in s.got
+        if "matchmaker_matched" in e
+    ]
+    assert row["publish_matches"] == row["matches"] == 5
+    assert row["publish_envelopes"] == len(bodies) == 10
+    assert row["publish_tokens"] == 3 == 5 - 2
+    assert sum("token" in b for b in bodies) == 2 * 3
+    assert sum("match_id" in b for b in bodies) == 2 * 2
+    assert len({b["token"] for b in bodies if "token" in b}) == 3
     assert set(rig.mm.on_matched.stages.values()) == {0}
 
 
