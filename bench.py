@@ -447,17 +447,15 @@ def measure_cadence_latency(rng, pool, cadence_sec, cycles):
         interval_end = t0 + cadence_sec
         maintenance_at = t0 + gap  # local.py's head-gap work point
         maintenance_done = False
-        guard = max(0.1, cfg.pipeline_deadline_guard_sec)
         watchdog = max(0.05, float(cfg.delivery_watchdog_sec))
-        guard_joined = None
         while time.perf_counter() < interval_end - 0.05:
             now = time.perf_counter()
             wait = min(interval_end - 0.02 - now, watchdog)
             if not maintenance_done:
                 wait = min(wait, max(0.0, maintenance_at - now))
-            dl = backend.next_deadline()
-            if dl is not None and dl - guard > now:
-                wait = min(wait, dl - guard - now)
+            guard_at = backend.guard_point()
+            if guard_at is not None and guard_at > now:
+                wait = min(wait, guard_at - now)
             if wait > 0:
                 # Event-driven: the cohort's worker thread sets the
                 # event the moment assembly finishes — delivery runs
@@ -467,19 +465,14 @@ def measure_cadence_latency(rng, pool, cadence_sec, cycles):
                 # gap work and a poll schedule.
                 ready_evt.wait(wait)
             ready_evt.clear()
-            dl = backend.next_deadline()
-            if dl is not None and time.perf_counter() >= dl - guard:
-                token = backend.head_token()
-                if not backend.head_ready() and token != guard_joined:
+            guard_at = backend.guard_point()
+            if guard_at is not None and time.perf_counter() >= guard_at:
+                if backend.claim_guard_join():
                     # Once per head (join_head itself refuses to block
                     # past deadline+guard); a head that failed its one
                     # guard join is wedged — the reclaim path's business.
-                    guard_joined = token
-                    backend.join_head(
-                        max(dl + guard, time.perf_counter() + 0.25)
-                    )
-                if time.perf_counter() > dl:
-                    backend.reclaim_stale()
+                    backend.join_head()
+                backend.reclaim_stale()
             mm.collect_pipelined()
             if (
                 not maintenance_done
@@ -488,23 +481,18 @@ def measure_cadence_latency(rng, pool, cadence_sec, cycles):
                 # The gap maintenance at its scheduled point — after
                 # any due delivery (delivery preempts maintenance).
                 maintenance_done = True
-                backlogged = getattr(backend, "pipeline_backlogged", None)
-                if (
-                    backlogged is not None
-                    and backlogged()
-                    and shed_streak < 2
-                ):
+                if backend.pipeline_backlogged() and shed_streak < 2:
                     shed_streak += 1  # shed: delivery preempts gap work
                 else:
                     shed_streak = 0
-                    dl = backend.next_deadline()
+                    guard_at = backend.guard_point()
                     # Floor the drain budget (as in local.py): a past
                     # deadline must not starve maintenance out of every
                     # forced gap.
                     mm.store.drain(
                         None
-                        if dl is None
-                        else max(time.perf_counter() + 0.2, dl - guard)
+                        if guard_at is None
+                        else max(time.perf_counter() + 0.2, guard_at)
                     )
                     gc.collect()
                     backend.pool.flush()

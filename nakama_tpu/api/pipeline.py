@@ -100,11 +100,10 @@ class Pipeline:
                     stages.envelope_done(session, time.perf_counter() - t0)
 
     def _add_stages(self):
-        """The matchmaker's add-stage sums, where its backend keeps a
-        `Tracing`."""
-        backend = getattr(self.c.matchmaker, "backend", None)
-        tracing = getattr(backend, "tracing", None)
-        return None if tracing is None else tracing.add_stages
+        """The matchmaker's add-stage sums, where there is a
+        matchmaker."""
+        mm = self.c.matchmaker
+        return None if mm is None else mm.tracing.add_stages
 
     async def _process_admitted(self, session, envelope: dict, root) -> bool:
         """Realtime-class admission + a per-envelope deadline

@@ -48,6 +48,7 @@ from ..matchmaker.local import (
     ErrQueryInvalid,
     ErrTooManyTickets,
     MatchmakerError,
+    ProcessBackend,
 )
 from ..matchmaker.query import QueryError, parse_query
 from ..matchmaker.types import MatchmakerPresence
@@ -92,7 +93,8 @@ class ClusterMatchmakerClient:
     on disagreement (e.g. a session racing tickets through two
     frontends)."""
 
-    backend = None  # console/server compat: no device backend here
+    # The seam's own bodies: no queue, no device, never attached.
+    backend = ProcessBackend()
 
     # Re-forward budget: a ticket bounced with `not_owner` (map churn)
     # re-routes at most this many times before the client drops it —
@@ -124,6 +126,10 @@ class ClusterMatchmakerClient:
         )
         self.owner = owner  # compat: the single-owner deployments' target
         self.on_matched = None  # owner publishes; kept for wiring compat
+        # The LocalMatchmaker surface the server and console read: no
+        # interval runs here, the record holds this node's envelope
+        # stages and ladder events.
+        self.tracing = trace_api.Tracing()
         self.override_fn = None
         self.slo = None
         self.journal = None

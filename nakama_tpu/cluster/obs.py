@@ -1141,7 +1141,7 @@ class FleetObsPlane:
     def _breaker_states(self) -> dict:
         s = self.server
         out = {}
-        breaker = getattr(s.matchmaker.backend, "breaker", None)
+        breaker = s.matchmaker.backend.breaker
         if breaker is not None:
             out["matchmaker_backend"] = breaker.state
         device = getattr(s.leaderboards, "device", None)

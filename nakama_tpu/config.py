@@ -126,21 +126,14 @@ class MatchmakerConfig:
     # Pools whose scanned column extent reaches this switch from the exact
     # blockwise top-K kernel to the two-stage MXU kernel (device2.py).
     big_pool_threshold: int = 32_768
-    emb_score_scale: float = 256.0  # stage-1 embedding-score quantisation
-    # Shard the pool's column axis over this many devices (0 = single
-    # device; -1 = all visible devices). Per-interval merge rides ICI
-    # collectives (SURVEY §2.8); capacity must split into col_block-sized
-    # shards. Operators set the `parallel` section instead — boot
-    # resolves it onto these three mesh_* knobs (apply_parallel), which
-    # stay the backend-level mechanism (and the test surface).
+    # Mesh-sharded matchmaking (parallel/mesh.py): shard the pool's
+    # column (candidate) axis over this many devices (0 = single
+    # device; -1 = all visible devices). Every device scores all active
+    # rows against its shard and the per-shard top-K lists merge, exact,
+    # over ICI collectives (SURVEY §2.8); capacity must split into
+    # col_block-sized shards, and the single-device path stays the
+    # fallback behind the mesh breaker. check() holds the bounds.
     mesh_devices: int = 0
-    # Mesh axis name the pool's column shards partition over.
-    mesh_axis: str = "pool"
-    # Per-shard top-K width gathered over ICI before the global merge
-    # (0 = candidates_per_ticket, the exact merge). Widths below K are
-    # an approximate bandwidth-saving mode; the merge stays exact while
-    # gather_k >= candidates_per_ticket.
-    mesh_gather_k: int = 0
     # Pipelined intervals — THE SHIPPED DEFAULT: process() dispatches the
     # current interval's device pass and collects completed earlier ones,
     # hiding device+transfer latency entirely (100k-pool Process p99 is
@@ -148,26 +141,14 @@ class MatchmakerConfig:
     # immutable so candidate eligibility cannot go stale; removed tickets
     # are filtered at collection. A matched cohort delivers the moment
     # its device pass + host assembly finish: the worker thread signals
-    # the event-driven delivery stage (delivery_event_driven below),
-    # and every cohort carries a delivery deadline of one interval_sec
+    # the event-driven delivery stage (local.py _delivery_loop), and
+    # every cohort carries a delivery deadline of one interval_sec
     # backed by a deadline-guard join and the reclaim path, so a cohort
     # is delivered before its own interval ends instead of slipping
     # behind gap work. Set False for the synchronous reference
     # semantics (same-interval delivery, device pass on the critical
     # path) — kept as the explicit fallback and correctness oracle.
     interval_pipelining: bool = True
-    # Device-side pair assignment: when the pool is large and every live
-    # ticket is a solo 1v1 (min==max==2, count 1, multiple 1|2),
-    # grouping runs as a propose-accept handshake ON DEVICE
-    # (device2.pair_partners) and only the partner vector crosses D2H —
-    # the full candidate matrix (~16MB at 100k) never transfers and the
-    # native greedy assembly never runs on the host. Synchronous
-    # intervals shed their latency floor this way; pipelined intervals
-    # shed the gap-side host assembly that contends with the server on
-    # small hosts (the cohort-slip tail). Matches stay exactly validated
-    # host-side; the matching is greedy-equivalent, not bit-identical to
-    # the sequential assembler's (oldest-first priority is preserved).
-    device_pairing: bool = True
     # Seconds before a pipelined cohort's delivery deadline at which the
     # delivery stage block-joins the cohort's assembly (yielding the
     # core to it, once per head). Bounds the worst-case delivery lag at
@@ -176,19 +157,12 @@ class MatchmakerConfig:
     # the guard at most one bounded join before the reclaim path
     # (inflight_reclaim_deadline_ms) takes it.
     pipeline_deadline_guard_sec: float = 2.0
-    # Event-driven delivery stage (local.py _delivery_loop): the worker
-    # thread that finishes a cohort's device pass + assembly signals
-    # the event loop directly (call_soon_threadsafe), so accept →
-    # finalize → publish run within milliseconds of readiness instead
-    # of at the next gap poll — the poll-quantized multi-second
-    # dispatch→matched tail at production cadence was exactly this
-    # wait. False disables the wakeup; delivery then paces on the
-    # watchdog below (poll-quantized fallback, the pre-event behavior).
-    delivery_event_driven: bool = True
     # Delivery-stage watchdog poll cadence (seconds): the timed drain
     # that runs even if a completion signal is lost or the backend has
-    # no signal to offer. With event-driven wakeups on, this bounds
-    # recovery from a lost signal — it is NOT the delivery latency.
+    # no signal to offer. The stage itself is event-driven — a cohort's
+    # worker thread wakes it the moment assembly finishes — so this
+    # bounds recovery from a lost signal; it is NOT the delivery
+    # latency.
     delivery_watchdog_sec: float = 1.0
     # Per-interval cap on host-only actives run through the CPU oracle
     # fallback (exotic queries the device kernel can't express). The
@@ -415,33 +389,6 @@ class DevObsConfig:
 
 
 @dataclass
-class ParallelConfig:
-    """Mesh-sharded matchmaking (parallel/mesh.py): the pool's column
-    (candidate) axis shards over a device mesh, every device scores all
-    active rows against its shard, and per-shard top-K merges over ICI
-    into the global candidate lists (SURVEY §2.8). Boot resolves this
-    section onto matchmaker.mesh_* (apply_parallel); the single-device
-    path stays the oracle/fallback behind the mesh breaker."""
-
-    enabled: bool = False
-    # Devices to shard over: -1 = all visible, otherwise an exact count
-    # (check() refuses more than the host exposes). Must divide
-    # matchmaker.pool_capacity into col_block-sized shards.
-    n_devices: int = -1
-    # Mesh axis name; the pool arrays' NamedSharding partitions on it.
-    axis: str = "pool"
-    # Per-shard top-K width gathered over ICI before the global merge
-    # (0 = candidates_per_ticket). Must be a power of two; widths below
-    # candidates_per_ticket trade merge exactness for gather bandwidth.
-    gather_k: int = 0
-    # Pools with capacity below this stay single-device even when
-    # enabled: the gather/merge overhead only pays for itself once the
-    # per-device O(N^2/D) saving beats the collective (boot logs the
-    # refusal instead of silently sharding a toy pool).
-    min_pool_for_mesh: int = 0
-
-
-@dataclass
 class RecoveryConfig:
     """Crash-recovery plane (recovery.py): the durable ticket journal
     (append-only, LSN-ordered, drained through the group-commit write
@@ -663,7 +610,6 @@ class Config:
     tracing: TracingConfig = field(default_factory=TracingConfig)
     recovery: RecoveryConfig = field(default_factory=RecoveryConfig)
     devobs: DevObsConfig = field(default_factory=DevObsConfig)
-    parallel: ParallelConfig = field(default_factory=ParallelConfig)
     cluster: ClusterConfig = field(default_factory=ClusterConfig)
     loadgen: LoadgenConfig = field(default_factory=LoadgenConfig)
 
@@ -884,62 +830,45 @@ class Config:
             warnings.append("tracing.slo_target should be in (0, 1)")
         if self.devobs.warmup_intervals < 0:
             raise ValueError("devobs.warmup_intervals must be >= 0")
-        pl = self.parallel
-        if pl.enabled:
-            if not re.fullmatch(r"[A-Za-z_][A-Za-z0-9_]*", pl.axis or ""):
+        mm = self.matchmaker
+        if mm.mesh_devices:
+            if mm.mesh_devices < -1:
                 raise ValueError(
-                    "parallel.axis must be a mesh-axis identifier"
-                    " ([A-Za-z_][A-Za-z0-9_]*)"
+                    "matchmaker.mesh_devices must be 0 (single device),"
+                    " -1 (all visible) or a positive device count"
                 )
-            if pl.n_devices == 0 or pl.n_devices < -1:
+            if not mm.interval_pipelining:
                 raise ValueError(
-                    "parallel.n_devices must be -1 (all visible) or a"
-                    " positive device count"
-                )
-            if pl.gather_k < 0 or (
-                pl.gather_k and pl.gather_k & (pl.gather_k - 1)
-            ):
-                raise ValueError(
-                    "parallel.gather_k must be 0 (= candidates_per_"
-                    "ticket) or a power of two — the gathered merge"
-                    " width is a compile shape, and non-pow2 widths"
-                    " churn it"
-                )
-            if pl.min_pool_for_mesh < 0:
-                raise ValueError("parallel.min_pool_for_mesh must be >= 0")
-            if not self.matchmaker.interval_pipelining:
-                raise ValueError(
-                    "parallel.enabled requires matchmaker.interval_"
+                    "matchmaker.mesh_devices requires matchmaker.interval_"
                     "pipelining: the mesh path's gather/merge rides the"
                     " pipelined gap — synchronous intervals would put"
                     " the ICI collective on the critical path"
                 )
-            if pl.n_devices > 0:
-                try:
-                    import jax as _jax
+            n_dev = mm.mesh_devices
+            try:
+                import jax as _jax
 
-                    visible = len(_jax.devices())
-                except Exception:
-                    visible = None
-                    warnings.append(
-                        "parallel.n_devices could not be validated"
-                        " against visible devices (jax unavailable)"
-                    )
-                if visible is not None and pl.n_devices > visible:
+                visible = len(_jax.devices())
+            except Exception:
+                visible = None
+                warnings.append(
+                    "matchmaker.mesh_devices could not be validated"
+                    " against visible devices (jax unavailable)"
+                )
+            if visible is not None:
+                if n_dev > visible:
                     raise ValueError(
-                        f"parallel.n_devices={pl.n_devices} but only"
+                        f"matchmaker.mesh_devices={n_dev} but only"
                         f" {visible} devices visible"
                     )
-            if (
-                pl.min_pool_for_mesh
-                and self.matchmaker.pool_capacity < pl.min_pool_for_mesh
-            ):
-                warnings.append(
-                    "parallel.enabled but matchmaker.pool_capacity"
-                    f" {self.matchmaker.pool_capacity} is below"
-                    f" parallel.min_pool_for_mesh"
-                    f" {pl.min_pool_for_mesh} — the matchmaker stays"
-                    " single-device"
+                n_dev = visible if n_dev < 0 else n_dev
+            if n_dev > 0 and mm.pool_capacity % n_dev:
+                # The backend checks the finer bound at boot, against
+                # its column blocks; this one needs no device.
+                raise ValueError(
+                    f"matchmaker.pool_capacity {mm.pool_capacity} must"
+                    f" split into equal column shards across {n_dev}"
+                    " mesh devices"
                 )
         lg = self.loadgen
         if lg.enabled:
@@ -1155,30 +1084,6 @@ def config_to_dict(cfg: Any, redact: bool = False) -> dict:
     return out
 
 
-def apply_parallel(cfg: "Config") -> str | None:
-    """Resolve the operator-facing `parallel` section onto the backend-
-    level matchmaker.mesh_* knobs (the seam TpuBackend actually reads).
-    Returns a human-readable note when the mesh is refused despite
-    parallel.enabled (boot logs it), else None. Idempotent; a config
-    with parallel.enabled=False leaves mesh_devices untouched so the
-    legacy knob keeps working for tests and labs."""
-    pl = cfg.parallel
-    mm = cfg.matchmaker
-    if not pl.enabled:
-        return None
-    mm.mesh_axis = pl.axis
-    mm.mesh_gather_k = pl.gather_k
-    if pl.min_pool_for_mesh and mm.pool_capacity < pl.min_pool_for_mesh:
-        mm.mesh_devices = 0
-        return (
-            f"pool_capacity {mm.pool_capacity} below parallel."
-            f"min_pool_for_mesh {pl.min_pool_for_mesh} — staying"
-            " single-device"
-        )
-    mm.mesh_devices = pl.n_devices
-    return None
-
-
 __all__ = [
     "Config",
     "LoggerConfig",
@@ -1198,9 +1103,7 @@ __all__ = [
     "TracingConfig",
     "RecoveryConfig",
     "DevObsConfig",
-    "ParallelConfig",
     "ClusterConfig",
-    "apply_parallel",
     "load_config",
     "parse_args",
     "config_to_dict",

@@ -347,15 +347,12 @@ class ConsoleServer:
         ov = getattr(s, "overload", None)
         if ov is None:
             return web.json_response({"enabled": False})
-        tracing = getattr(s, "_overload_tracing", None)
         return web.json_response(
             {
                 "enabled": True,
                 **ov.stats(),
                 "recent_transitions": (
-                    tracing.recent_overload_events()
-                    if tracing is not None
-                    else []
+                    s.matchmaker.tracing.recent_overload_events()
                 ),
             }
         )
@@ -762,34 +759,17 @@ class ConsoleServer:
         names its stage from this one endpoint."""
         self._auth(request)
         mm = self.server.matchmaker
-        tracing = getattr(mm.backend, "tracing", None)
+        tracing = mm.tracing
         n = int(request.query.get("n", 32))
         return web.json_response(
             {
                 "tickets": len(mm),
                 "active": len(mm.active),
                 "backend": type(mm.backend).__name__,
-                "intervals": (
-                    tracing.recent(n) if tracing is not None else []
-                ),
-                "deliveries": (
-                    tracing.recent_deliveries(n)
-                    if tracing is not None
-                    and hasattr(tracing, "recent_deliveries")
-                    else []
-                ),
-                "delivery_stages": (
-                    tracing.delivery_stage_stats()
-                    if tracing is not None
-                    and hasattr(tracing, "delivery_stage_stats")
-                    else {}
-                ),
-                "ledger_totals": (
-                    tracing.ledger_totals()
-                    if tracing is not None
-                    and hasattr(tracing, "ledger_totals")
-                    else {}
-                ),
+                "intervals": tracing.recent(n),
+                "deliveries": tracing.recent_deliveries(n),
+                "delivery_stages": tracing.delivery_stage_stats(),
+                "ledger_totals": tracing.ledger_totals(),
             }
         )
 
@@ -922,27 +902,26 @@ class ConsoleServer:
         from ..parallel.mesh import describe_mesh
 
         backend = self.server.matchmaker.backend
-        mesh = getattr(backend, "_mesh", None)
-        pool = getattr(backend, "pool", None)
         try:
             n = min(256, max(1, int(request.query.get("n", 64))))
         except (TypeError, ValueError):
             return _err(400, "n must be an integer")
-        describe = getattr(backend, "describe", None)
+        if backend.mesh is None:
+            mesh = describe_mesh()
+        else:
+            mesh = describe_mesh(
+                backend.mesh,
+                pool_capacity=backend.pool.capacity,
+                pool=backend.pool.device,
+                gather_bytes=backend.mesh_gather_bytes,
+            )
         return web.json_response(
             {
                 **DEVOBS.stats(),
                 # Where the matchmaker's kernels really run: platform,
                 # and whether Pallas is interpreting (None = host oracle).
-                "backend": describe() if describe is not None else None,
-                "mesh": describe_mesh(
-                    mesh,
-                    pool_capacity=getattr(pool, "capacity", 0),
-                    pool=getattr(pool, "device", None),
-                    gather_bytes=getattr(
-                        backend, "mesh_gather_bytes", 0
-                    ),
-                ),
+                "backend": backend.describe(),
+                "mesh": mesh,
                 "timeline": DEVOBS.recent_timeline(n),
             }
         )
@@ -969,13 +948,7 @@ class ConsoleServer:
         duration_ms = min(max(50, duration_ms), cap)
         if self._capture_busy:
             return _err(409, "a device capture is already running")
-        tracing = getattr(
-            self.server.matchmaker.backend, "tracing", None
-        )
-        if tracing is None or not hasattr(tracing, "device_trace"):
-            from ..tracing import Tracing
-
-            tracing = Tracing(logger=self.logger)
+        tracing = self.server.matchmaker.tracing
         out_dir = os.path.join(
             self.config.data_dir,
             "device_captures",

@@ -63,6 +63,7 @@ COL_BITS = 18  # column index bits in the packed winner word
 MAX_COLS = 1 << COL_BITS
 PRIO_MAX = 8191  # 13-bit priority
 JITTER_AMP = 256  # selection-jitter range (stays below 1 emb-score unit)
+EMB_SCORE_SCALE = 256.0  # stage-1 embedding-score quantisation
 PACKED_NONE = -(2**31)  # plain int: pallas kernels must not capture arrays
 # Mosaic's scoped-VMEM limit for one kernel on a v5e (the compiler's
 # default; a kernel over it is refused at compile time, not at run time).
@@ -211,7 +212,6 @@ def _stage1_kernel(
     out_w: int,
     with_embedding: bool,
     rev: bool,
-    emb_scale: float,
 ):
     s = jax.lax.dot_general(
         uq_ref[:],
@@ -246,7 +246,9 @@ def _stage1_kernel(
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        bump = jnp.clip(score * emb_scale, -4095.0, 4095.0).astype(jnp.int32)
+        bump = jnp.clip(
+            score * EMB_SCORE_SCALE, -4095.0, 4095.0
+        ).astype(jnp.int32)
         prio = jnp.clip(prio + bump, 0, PRIO_MAX)
 
     j = pl.program_id(1)
@@ -349,7 +351,6 @@ def _stage1_call(
     bn: int,
     with_embedding: bool,
     rev: bool,
-    emb_scale: float,
     interpret: bool,
     vma=None,
 ):
@@ -372,7 +373,6 @@ def _stage1_call(
         out_w=out_w,
         with_embedding=with_embedding,
         rev=rev,
-        emb_scale=emb_scale,
     )
     return pl.pallas_call(
         kernel,
@@ -406,7 +406,7 @@ def _stage1_call(
     jax.jit,
     static_argnames=(
         "fn", "fs", "n_cols", "k", "rev", "with_should", "with_embedding",
-        "bm", "bn", "interpret", "emb_scale", "order_exact",
+        "bm", "bn", "interpret", "order_exact",
     ),
 )
 def topk_candidates_big(
@@ -425,7 +425,6 @@ def topk_candidates_big(
     bm: int = 1024,
     bn: int = 1024,
     interpret: bool = False,
-    emb_scale: float = 256.0,
     order_exact: bool = True,
 ):
     """Two-stage top-k: returns slots i32 [A_pad, k] ordered by exact
@@ -479,7 +478,6 @@ def topk_candidates_big(
         bn=bn,
         with_embedding=with_embedding,
         rev=rev,
-        emb_scale=emb_scale,
         interpret=interpret,
     )
 
@@ -500,7 +498,7 @@ def topk_candidates_big(
     jax.jit,
     static_argnames=(
         "mesh", "axis", "fn", "fs", "k", "rev", "with_should",
-        "with_embedding", "bm", "bn", "interpret", "emb_scale",
+        "with_embedding", "bm", "bn", "interpret",
     ),
 )
 def topk_candidates_big_sharded(
@@ -520,7 +518,6 @@ def topk_candidates_big_sharded(
     bm: int = 1024,
     bn: int = 1024,
     interpret: bool = False,
-    emb_scale: float = 256.0,
 ):
     """Mesh-sharded two-stage top-k (VERDICT r2 #2): stage 1 runs the MXU
     pallas kernel per device over ITS column shard of the pool, the packed
@@ -619,7 +616,6 @@ def topk_candidates_big_sharded(
             bn=bn,
             with_embedding=with_embedding,
             rev=rev,
-            emb_scale=emb_scale,
             interpret=interpret,
             vma=frozenset({axis}),
         )

@@ -25,9 +25,10 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
+import threading
 import time
 import uuid
-from typing import Callable, Protocol
+from typing import Callable
 
 import numpy as np
 
@@ -36,6 +37,7 @@ from .. import tracing as trace_api
 from ..config import MatchmakerConfig
 from ..logger import Logger
 from ..metrics import Metrics
+from ..tracing import Tracing
 from .process import process_custom, process_default
 from .query import QueryError, parse_query
 from .store import SlotStore
@@ -91,9 +93,44 @@ OverrideFn = Callable[
 ]
 
 
-class ProcessBackend(Protocol):
-    def attach(self, store: SlotStore) -> None:
-        """Bind the shared slot store before any other call."""
+class ProcessBackend:
+    """The seam between the interval host (`LocalMatchmaker`, the server,
+    the console) and what forms the matches. Every backend method and
+    attribute they use is declared here, with the body it has for a
+    backend that keeps no cohort queue and no device state: nothing is
+    ever ready, due or backlogged, and it is idle at once. `CpuBackend`
+    adds the oracle's `process_slots`; `TpuBackend` (tpu.py) overrides
+    the lot."""
+
+    store: SlotStore | None = None
+    # The interval record (breadcrumbs, delivery ledger, add stages):
+    # the matchmaker's own, bound by attach().
+    tracing: Tracing | None = None
+    # The device path's circuit breaker (faults.py) and the jax Mesh the
+    # pool shards over, where there is one.
+    breaker = None
+    mesh = None
+    # The pipelined cohorts (tpu.py `Cohort`) the last process_slots /
+    # collect_ready call accepted, oldest first; replaced every call.
+    accepted_cohorts = ()
+
+    def attach(self, store: SlotStore, tracing: Tracing) -> None:
+        """Bind the shared slot store and the interval record before
+        any other call."""
+        self.store = store
+        self.tracing = tracing
+
+    def describe(self) -> dict | None:
+        """Where the kernels really run (console /v2/console/device);
+        None = on no device."""
+        return None
+
+    def annotate(self, name: str):
+        """A host span in a captured device profile, where the backend
+        runs on JAX; the host-only backends never import it."""
+        return contextlib.nullcontext()
+
+    # ------------------------------------------------ pool notifications
 
     def on_add(self, ticket: MatchmakerTicket, slot: int) -> None:
         """Called after the ticket is slot-registered; may raise to reject
@@ -102,6 +139,13 @@ class ProcessBackend(Protocol):
     def on_remove_slots(self, slots: np.ndarray) -> None:
         """Called when tickets leave the pool, BEFORE the store clears
         their slots."""
+
+    def in_flight(self, slot: int) -> bool:
+        """Is the slot claimed by a dispatched cohort that may still
+        match it?"""
+        return False
+
+    # ------------------------------------------------------ the interval
 
     def process_slots(
         self,
@@ -117,23 +161,80 @@ class ProcessBackend(Protocol):
         invalidated after they already went inactive — they get another
         active interval so churn can't strand them passively matchable
         forever."""
-        ...
+        raise NotImplementedError
+
+    # -------------------------------------------------- the cohort queue
+
+    def set_ready_callback(self, cb: Callable[[], None] | None) -> None:
+        """Register the cohort-completion signal, called from a worker
+        thread whenever a dispatched cohort finishes; None unregisters."""
+
+    def collect_ready(self, *, rev_precision: bool, block_until=None):
+        """Accept the queued cohorts that completed, outside
+        process_slots: (batch, matched_slots, reactivate_slots), or None
+        when nothing is ready."""
+        return None
+
+    def pipeline_depth(self) -> int:
+        """Dispatched cohorts not yet accepted."""
+        return 0
+
+    def pipeline_backlogged(self) -> bool:
+        """Does an unfinished cohort need the host now (the idle gap
+        sheds its deferrable work)?"""
+        return False
+
+    def next_deadline(self) -> float | None:
+        """Delivery deadline of the head cohort (perf_counter seconds);
+        None when nothing is queued."""
+        return None
+
+    def guard_point(self) -> float | None:
+        """When the head cohort is due its deadline guard: its delivery
+        deadline less the guard margin; None when nothing is queued."""
+        return None
+
+    def claim_guard_join(self) -> bool:
+        """Claim the head cohort's one guard join: True when it is
+        unfinished and was not claimed before (the caller then runs
+        `join_head` off the event loop)."""
+        return False
+
+    def join_head(self, until: float | None = None) -> bool:
+        """Block until the head cohort finishes, `until` passes or its
+        own interval is over; returns readiness."""
+        return False
+
+    def reclaim_stale(self) -> None:
+        """Abandon cohorts wedged past their deadline and free their
+        in-flight claims."""
+
+    def wait_idle(self, timeout: float | None = None) -> None:
+        """Block until no worker thread of the backend is running."""
+
+    # ------------------------------------------------------ idle-gap work
+
+    def count_cohorts(self) -> None:
+        """Put the candidate-list counters on the delivered cohorts'
+        ledger rows (before the store drains)."""
+
+    def flush(self) -> None:
+        """Push the ticket rows staged so far to the device."""
+
+    # ------------------------------------------------ snapshot / restore
+
+    def snapshot_state(self) -> dict | None:
+        """The backend's derived per-ticket state for a checkpoint; None
+        where there is none to keep."""
+        return None
+
+    def restore_state(self, snap: dict) -> None:
+        """Load `snapshot_state`'s section onto a fresh backend whose
+        store is already restored; raises where it does not fit."""
 
 
-class CpuBackend:
+class CpuBackend(ProcessBackend):
     """The oracle backend — exact reference semantics on host objects."""
-
-    def __init__(self):
-        self.store: SlotStore | None = None
-
-    def attach(self, store: SlotStore):
-        self.store = store
-
-    def on_add(self, ticket: MatchmakerTicket, slot: int) -> None:
-        pass
-
-    def on_remove_slots(self, slots: np.ndarray) -> None:
-        pass
 
     def process_slots(
         self, active_slots, last_interval, *, max_intervals, rev_precision
@@ -174,7 +275,7 @@ def _select_backend(config: MatchmakerConfig, logger, metrics):
     CPU-only host. A failing `jax.devices()` propagates: a host whose
     accelerator cannot be reached must not come up quietly on the
     oracle. (SURVEY §7.5: the swappable-backends seam.)"""
-    choice = getattr(config, "backend", "auto")
+    choice = config.backend
     if choice == "cpu":
         return CpuBackend()
     import jax
@@ -270,14 +371,19 @@ class LocalMatchmaker:
         node: str = "local",
         backend: ProcessBackend | None = None,
         on_matched: MatchedCallback | None = None,
+        tracing: Tracing | None = None,
     ):
         self.logger = logger.with_fields(subsystem="matchmaker")
         self.config = config
         self.metrics = metrics
         self.node = node
         self.store = SlotStore(config.pool_capacity, config.max_party_size)
+        # The interval record: `add`, `_publish` and `Pipeline.process`
+        # stamp on it here, the backend writes its crumbs and ledger
+        # rows on the same one.
+        self.tracing = tracing or Tracing()
         self.backend = backend or _select_backend(config, self.logger, metrics)
-        self.backend.attach(self.store)
+        self.backend.attach(self.store, self.tracing)
         self.on_matched = on_matched
         self.override_fn: OverrideFn | None = None
 
@@ -338,16 +444,12 @@ class LocalMatchmaker:
         if self._delivery_task is not None:
             self._delivery_task.cancel()
             self._delivery_task = None
-        set_cb = getattr(self.backend, "set_ready_callback", None)
-        if set_cb is not None:
-            # Unhook the wakeup before the loop closes: a cohort
-            # finishing during shutdown must not signal a dead loop.
-            set_cb(None)
-        wait_idle = getattr(self.backend, "wait_idle", None)
-        if wait_idle is not None:
-            # No device fetch thread may outlive the server (XLA aborts if
-            # a transfer is in flight at interpreter teardown).
-            wait_idle(timeout=5.0)
+        # Unhook the wakeup before the loop closes: a cohort finishing
+        # during shutdown must not signal a dead loop.
+        self.backend.set_ready_callback(None)
+        # No device fetch thread may outlive the server (XLA aborts if
+        # a transfer is in flight at interpreter teardown).
+        self.backend.wait_idle(timeout=5.0)
 
     def start(self):
         """Spawn the per-interval processing task (reference
@@ -406,14 +508,7 @@ class LocalMatchmaker:
                 # they queue the cohort's worker thread behind seconds of
                 # main-thread work. The streak cap keeps a permanently
                 # slow pipeline from starving heap maintenance forever.
-                backlogged = getattr(
-                    self.backend, "pipeline_backlogged", None
-                )
-                if (
-                    backlogged is not None
-                    and backlogged()
-                    and shed_streak < 2
-                ):
+                if self.backend.pipeline_backlogged() and shed_streak < 2:
                     shed_streak += 1
                     if self.metrics is not None:
                         self.metrics.mm_gap_shed.inc()
@@ -423,9 +518,7 @@ class LocalMatchmaker:
                     # their ledger rows here, before the drain: they
                     # read the removed tickets' slots as matched.
                     try:
-                        count = getattr(self.backend, "count_cohorts", None)
-                        if count is not None:
-                            count()
+                        self.backend.count_cohorts()
                     except Exception as e:
                         self.logger.error("cohort count error", error=str(e))
                     # Preemptible: stop the teardown pass early rather
@@ -436,15 +529,11 @@ class LocalMatchmaker:
                     # must still make progress, or the graveyard grows
                     # until the allocator pays the full teardown inline
                     # on the add path.
-                    deadline = self._next_cohort_deadline()
+                    guard_at = self.backend.guard_point()
                     self.store.drain(
                         None
-                        if deadline is None
-                        else max(
-                            time.perf_counter() + 0.2,
-                            deadline
-                            - self.config.pipeline_deadline_guard_sec,
-                        )
+                        if guard_at is None
+                        else max(time.perf_counter() + 0.2, guard_at)
                     )
                     gc.collect()
                     # Idle-gap flush: push ticket rows staged so far so
@@ -452,13 +541,7 @@ class LocalMatchmaker:
                     # arrive during the remaining sleep (eager 2048-row
                     # chunking already streams the bulk as adds come in).
                     try:
-                        flush = getattr(
-                            getattr(self.backend, "pool", None),
-                            "flush",
-                            None,
-                        )
-                        if flush is not None:
-                            flush()
+                        self.backend.flush()
                     except Exception as e:
                         self.logger.error("gap flush error", error=str(e))
                     if (
@@ -510,33 +593,27 @@ class LocalMatchmaker:
             # latency). Runs on the event loop, so accept/finalize/
             # publish serialize with process() — the in-flight mask and
             # sel-scratch invariants need no new locking.
-            guard = max(
-                0.1, float(self.config.pipeline_deadline_guard_sec)
-            )
-            watchdog = max(
-                0.05,
-                float(getattr(self.config, "delivery_watchdog_sec", 1.0)),
-            )
+            watchdog = max(0.05, float(self.config.delivery_watchdog_sec))
             wakeup = self._delivery_wakeup
-            guard_joined = None  # head token already guard-joined once
+            backend = self.backend
             while not self._stopped:
-                deadline = self._next_cohort_deadline()
+                guard_at = backend.guard_point()
                 now = time.perf_counter()
-                if deadline is None or deadline - guard <= now:
+                if guard_at is None or guard_at <= now:
                     # Nothing due (or the head is already at/past its
                     # guard point and was handled below): event or
                     # watchdog.
                     timeout = watchdog
                 else:
-                    timeout = min(watchdog, deadline - guard - now)
+                    timeout = min(watchdog, guard_at - now)
                 cause = "watchdog"
                 try:
                     await asyncio.wait_for(wakeup.wait(), timeout)
                     cause = "event"
                 except asyncio.TimeoutError:
                     if (
-                        deadline is not None
-                        and time.perf_counter() >= deadline - guard
+                        guard_at is not None
+                        and time.perf_counter() >= guard_at
                     ):
                         cause = "deadline"
                 wakeup.clear()
@@ -545,44 +622,22 @@ class LocalMatchmaker:
                 if self._paused:
                     continue
                 try:
-                    deadline = self._next_cohort_deadline()
-                    now = time.perf_counter()
-                    if deadline is not None and now >= deadline - guard:
-                        token = getattr(
-                            self.backend, "head_token", lambda: None
-                        )()
-                        ready = getattr(
-                            self.backend, "head_ready", lambda: True
-                        )()
-                        join = getattr(self.backend, "join_head", None)
-                        if (
-                            join is not None
-                            and not ready
-                            and token is not None
-                            and token != guard_joined
-                        ):
+                    guard_at = backend.guard_point()
+                    if (
+                        guard_at is not None
+                        and time.perf_counter() >= guard_at
+                    ):
+                        if backend.claim_guard_join():
                             # Bounded join in a worker thread (the event
                             # loop stays responsive; the cohort's
-                            # assembly gets the core) — ONCE per head:
-                            # join_head itself refuses to block past the
-                            # head's own interval, and a head that
-                            # failed its one guard join is wedged —
-                            # booked to the reclaim path below, never
-                            # re-joined into the next cycle.
-                            guard_joined = token
-                            await asyncio.to_thread(
-                                join,
-                                max(
-                                    deadline + guard,
-                                    time.perf_counter() + 0.25,
-                                ),
-                            )
-                        if time.perf_counter() > deadline:
-                            reclaim = getattr(
-                                self.backend, "reclaim_stale", None
-                            )
-                            if reclaim is not None:
-                                reclaim()
+                            # assembly gets the core), once per head:
+                            # the backend keeps that count and bounds
+                            # the join by the head's own interval. A
+                            # head that failed its one guard join is
+                            # wedged — the reclaim below takes it once
+                            # it is past its deadline.
+                            await asyncio.to_thread(backend.join_head)
+                        backend.reclaim_stale()
                     if self.metrics is not None:
                         self.metrics.mm_delivery_wakeups.labels(
                             cause=cause
@@ -595,23 +650,19 @@ class LocalMatchmaker:
 
         loop = asyncio.get_running_loop()
         self._delivery_wakeup = asyncio.Event()
-        set_cb = getattr(self.backend, "set_ready_callback", None)
-        if set_cb is not None and getattr(
-            self.config, "delivery_event_driven", True
-        ):
-            wakeup = self._delivery_wakeup
+        wakeup = self._delivery_wakeup
 
-            def _signal():
-                # Worker thread → event loop: the only thread-safe way
-                # to poke an asyncio.Event. A loop already closed
-                # (shutdown race) just drops the signal — stop()'s
-                # wait_idle covers the tail.
-                try:
-                    loop.call_soon_threadsafe(wakeup.set)
-                except RuntimeError:
-                    pass
+        def _signal():
+            # Worker thread → event loop: the only thread-safe way to
+            # poke an asyncio.Event. A loop already closed (shutdown
+            # race) just drops the signal — stop()'s wait_idle covers
+            # the tail.
+            try:
+                loop.call_soon_threadsafe(wakeup.set)
+            except RuntimeError:
+                pass
 
-            set_cb(_signal)
+        self.backend.set_ready_callback(_signal)
         self._task = loop.create_task(_loop())
         self._delivery_task = loop.create_task(_delivery_loop())
 
@@ -752,11 +803,9 @@ class LocalMatchmaker:
             self.logger.debug(
                 "matchmaker ticket added", ticket=ticket_id
             )
-        tracing = getattr(self.backend, "tracing", None)
-        if tracing is not None:
-            tracing.add_stages.add(
-                t0, t_parsed, t_registered, t_journaled, time.perf_counter()
-            )
+        self.tracing.add_stages.add(
+            t0, t_parsed, t_registered, t_journaled, time.perf_counter()
+        )
         return ticket_id, created_at
 
     def _hold_ticket_trace(self, ticket_id: str, sp, slot: int) -> None:
@@ -783,11 +832,11 @@ class LocalMatchmaker:
             return None
         return ctx[0], ctx[1]
 
-    def _finish_ticket_traces(self, matched_slots, tracing) -> None:
+    def _finish_ticket_traces(self, matched_slots, cohorts) -> None:
         """Resolve held ticket traces after an interval/collect pass:
         matched tickets get the cohort stage spans (attributed to THEIR
-        cohort's ledger entry via backend._accepted_cohorts) and their
-        hold released; tickets parked inactive with no cohort in flight
+        cohort's ledger entry; `cohorts` are those the call accepted)
+        and their hold released; tickets parked inactive with no cohort in flight
         (expired unmatched) release too — their trace completes with
         just the add, and a later PASSIVE match is not appended (the
         bounded store cannot hold traces for tickets that may linger
@@ -804,18 +853,16 @@ class LocalMatchmaker:
         # when one collect accepted SEVERAL cohorts, each matched slot
         # maps to ITS cohort's ledger entry — a ticket must not wear
         # another cohort's stage chain.
-        cohorts = list(getattr(self.backend, "_accepted_cohorts", ()))
         cohort_of = None
         if cohorts:
             cohort_of = np.full(cap, -1, dtype=np.int32)
             for i, cohort in enumerate(cohorts):
                 cohort_of[cohort.matched_slots] = i
         default_entry = None
-        if tracing is not None and len(tracing.deliveries):
-            default_entry = tracing.deliveries[-1]
+        if len(self.tracing.deliveries):
+            default_entry = self.tracing.deliveries[-1]
         ticket_at = self.store.ticket_at
         active = self.store.active
-        inflight = getattr(self.backend, "_in_flight_mask", None)
         for tid, (trace_id, span_id, slot) in list(
             self._ticket_traces.items()
         ):
@@ -833,9 +880,7 @@ class LocalMatchmaker:
                 if cohort_of is not None and cohort_of[slot] >= 0:
                     entry = cohorts[cohort_of[slot]].entry
                 trace_api.emit_matched_spans((trace_id, span_id), entry)
-            elif not active[slot] and (
-                inflight is None or not inflight[slot]
-            ):
+            elif not active[slot] and not self.backend.in_flight(slot):
                 # Deactivated (expired / min==max attempt spent) with
                 # no dispatched cohort that could still match it: the
                 # add→(not yet matched) trace finalizes now.
@@ -861,8 +906,7 @@ class LocalMatchmaker:
         """Earliest delivery deadline among the backend's queued cohorts
         (perf_counter seconds), or None: pipeline-less backends and an
         empty queue both report nothing due."""
-        nd = getattr(self.backend, "next_deadline", None)
-        return None if nd is None else nd()
+        return self.backend.next_deadline()
 
     def collect_pipelined(self, block_until=None) -> MatchBatch | None:
         """Deliver any pipelined cohorts whose device pass + gap assembly
@@ -872,12 +916,9 @@ class LocalMatchmaker:
         a blocking join of the head cohort for deadline-guard delivery.
         No-op (None) for backends without a pipeline or when nothing is
         ready."""
-        collect = getattr(self.backend, "collect_ready", None)
-        if collect is None:
-            return None
         t0 = time.perf_counter()
         try:
-            out = collect(
+            out = self.backend.collect_ready(
                 rev_precision=self.config.rev_precision,
                 block_until=block_until,
             )
@@ -893,7 +934,7 @@ class LocalMatchmaker:
             return None
         batch, matched_slots, reactivate = out
         objs = None
-        with self._annotate("mm.remove"):
+        with self.backend.annotate("mm.remove"):
             if len(matched_slots):
                 self.backend.on_remove_slots(matched_slots)
                 objs = self.store.remove_slots(matched_slots)
@@ -904,19 +945,11 @@ class LocalMatchmaker:
             self.metrics.mm_matched.inc(batch.entry_count if batch else 0)
             self._update_gauges()
         head = self._deliver(
-            batch, matched_slots, objs, self.backend._accepted_cohorts
+            batch, matched_slots, objs, self.backend.accepted_cohorts
         )
         if head is not None:
             head["delivery_held_s"] = time.perf_counter() - t0
         return batch
-
-    def _annotate(self, name: str):
-        """A host span in a captured profile, where the backend runs on
-        JAX (keeps a `Tracing`); the host-only backends never import
-        it."""
-        if getattr(self.backend, "tracing", None) is None:
-            return contextlib.nullcontext()
-        return trace_api.annotate(name)
 
     def _deliver(self, batch, matched_slots, objs, cohorts):
         """The tail of a process() / collect_pipelined() call: publish
@@ -931,7 +964,7 @@ class LocalMatchmaker:
         published_ok = True
         t_publish = time.perf_counter()
         if len(batch) and self.on_matched is not None:
-            with self._annotate("mm.publish"):
+            with self.backend.annotate("mm.publish"):
                 published_ok = self._publish(batch, head)
             now = time.perf_counter()
             for cohort in cohorts:
@@ -943,11 +976,9 @@ class LocalMatchmaker:
                     self.metrics.mm_delivery_publish_lag.observe(lag)
                 if self.slo is not None:
                     self.slo.observe("delivery_publish", lag * 1000)
-        with self._annotate("mm.journal_matched"):
+        with self.backend.annotate("mm.journal_matched"):
             self._journal_matched(matched_slots, objs, published_ok)
-        self._finish_ticket_traces(
-            matched_slots, getattr(self.backend, "tracing", None)
-        )
+        self._finish_ticket_traces(matched_slots, cohorts)
         if head is not None:
             # All between the newest accept stamp and the publish:
             # batch finalisation, slot removal, reactivation, gauges.
@@ -1108,7 +1139,7 @@ class LocalMatchmaker:
                 reactivate = expired_slots.astype(np.int32)
 
         t_rm = time.perf_counter()
-        with self._annotate("mm.remove"):
+        with self.backend.annotate("mm.remove"):
             store.deactivate(expired_slots)
             t_rm1 = time.perf_counter()
             if len(matched_slots):
@@ -1140,8 +1171,7 @@ class LocalMatchmaker:
         own_interval = self.override_fn is None and not backend_failed
         head = self._deliver(
             batch, matched_slots, objs,
-            getattr(self.backend, "_accepted_cohorts", ())
-            if own_interval else (),
+            self.backend.accepted_cohorts if own_interval else (),
         )
         # Attribute the post-backend tail (slot removal, delivery
         # callback) on the interval's breadcrumb: the p99 work that
@@ -1151,19 +1181,14 @@ class LocalMatchmaker:
         # crumb is some earlier interval's — updating it would corrupt
         # that interval's attribution. Likewise a backend that RAISED
         # out of process_slots recorded no crumb for this interval.
-        tracing = (
-            getattr(self.backend, "tracing", None) if own_interval else None
-        )
-        if tracing is not None and tracing.breadcrumbs:
-            import threading as _threading
-
-            tracing.breadcrumbs[-1].update(
+        if own_interval and self.tracing.breadcrumbs:
+            self.tracing.breadcrumbs[-1].update(
                 remove_s=t_cb - t_rm,
                 rm_backend_s=t_rm2 - t_rm1,
                 rm_store_s=t_cb - t_rm2,
                 callback_s=time.perf_counter() - t_cb,
                 pre_backend_s=t_backend - t0,
-                threads=_threading.active_count(),
+                threads=threading.active_count(),
             )
         if head is not None:
             head["delivery_held_s"] = time.perf_counter() - t0
@@ -1412,9 +1437,9 @@ class LocalMatchmaker:
             if alive.any()
             else 0
         )
-        backend_snap = getattr(self.backend, "snapshot_state", None)
+        backend_snap = self.backend.snapshot_state()
         if backend_snap is not None:
-            snap["backend"] = backend_snap()
+            snap["backend"] = backend_snap
         return snap
 
     def restore_state(self, snap: dict) -> None:
@@ -1427,11 +1452,10 @@ class LocalMatchmaker:
 
         self.store.restore(snap["store"])
         advance_created_seq(snap.get("max_created_seq", 0))
-        backend_restore = getattr(self.backend, "restore_state", None)
         backend_snap = snap.get("backend")
-        if backend_restore is not None and backend_snap is not None:
+        if backend_snap is not None:
             try:
-                backend_restore(backend_snap)
+                self.backend.restore_state(backend_snap)
             except Exception as e:
                 # Schema drift (config changed across the restart) or a
                 # torn backend section: the store is already populated,
@@ -1445,9 +1469,9 @@ class LocalMatchmaker:
                     error=str(e),
                 )
                 self._rederive_backend_rows()
-        elif getattr(self.backend, "snapshot_state", None) is not None:
-            # Snapshot written by a state-less backend (CPU oracle)
-            # restored onto a device backend: re-derive rows per ticket.
+        else:
+            # Snapshot written by a state-less backend (CPU oracle):
+            # whatever this backend derives, it derives per ticket.
             self._rederive_backend_rows()
         self._update_gauges()
 

@@ -68,9 +68,8 @@ def _run(mm, specs, intervals):
         info[tid] = (s["query"], 2, 2)
     for _ in range(intervals):
         mm.process()
-    wait = getattr(mm.backend, "wait_idle", None)
-    if wait:
-        wait(30)
+    if mm.backend.pipeline_depth():
+        mm.backend.wait_idle(30)
         mm.process()  # collect any pipelined tail
     mm.stop()
     return matched, info
@@ -192,20 +191,25 @@ def run_chip_selfcheck(log=print) -> dict:
         " exact oracle parity")
 
     # 2. Big (two-stage MXU) kernel + native assembler at the shipped
-    # default widths: exact validity + oracle coverage (device_pairing
-    # off pins the assembler path — the pure-1v1 pool would otherwise
-    # take the pairing handshake).
+    # default widths: exact validity + oracle coverage. One ticket that
+    # is no pair (a trio in a mode of its own: it never matches) pins
+    # the assembler path — a pure-1v1 pool takes the pairing handshake.
     rng = np.random.default_rng(11)
     specs = _specs(rng, 600)
     cpu_total = _validate(*_cpu_matches(specs), "oracle")
     cfg = MatchmakerConfig(
         pool_capacity=1024, max_intervals=2, big_pool_threshold=256,
-        interval_pipelining=True, device_pairing=False,
+        interval_pipelining=True,
     )
     mm = LocalMatchmaker(
         test_logger(), cfg, backend=TpuBackend(
             cfg, test_logger(), big_row_block=256, big_col_block=256,
         )
+    )
+    trio = MatchmakerPresence(user_id="trio", session_id="trio")
+    mm.add(
+        [trio], trio.session_id, "", "+properties.mode:trio", 3, 3, 1,
+        {"mode": "trio"}, {},
     )
     dev_total = _validate(*_run(mm, specs, 3), "big")
     assert dev_total >= cpu_total - 4, (dev_total, cpu_total)
@@ -218,7 +222,6 @@ def run_chip_selfcheck(log=print) -> dict:
         pool_capacity=1024, candidates_per_ticket=64, numeric_fields=8,
         string_fields=8, max_constraints=8, max_intervals=2,
         big_pool_threshold=256, interval_pipelining=False,
-        device_pairing=True,
     )
     mm = LocalMatchmaker(
         test_logger(), cfg, backend=TpuBackend(
@@ -238,7 +241,6 @@ def run_chip_selfcheck(log=print) -> dict:
         pool_capacity=1024, candidates_per_ticket=64, numeric_fields=8,
         string_fields=8, max_constraints=8, max_intervals=2,
         big_pool_threshold=256, interval_pipelining=True,
-        device_pairing=True,
     )
     mm = LocalMatchmaker(
         test_logger(), cfg, backend=TpuBackend(

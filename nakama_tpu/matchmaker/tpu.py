@@ -27,6 +27,7 @@ interval never loops over the whole pool in Python.
 from __future__ import annotations
 
 import threading
+import time
 from collections import deque
 
 import jax
@@ -39,6 +40,7 @@ from .. import faults, native
 from .. import tracing as trace_api
 from ..devobs import DEVOBS
 from ..faults import CLOSED, HALF_OPEN, STATE_CODE, CircuitBreaker, classify_exception
+from ..parallel.mesh import POOL_AXIS, make_mesh, mesh_merge_fn, mesh_score_fn
 from .compile import (
     FULL_HI,
     FULL_LO,
@@ -65,6 +67,7 @@ from .device import (
     topk_candidates,
 )
 from .device2 import MAX_COLS, topk_candidates_big
+from .local import ProcessBackend
 from .process import _mutual, process_default
 from .types import MatchBatch, MatchmakerTicket
 
@@ -101,8 +104,9 @@ class Cohort:
     boundaries, from which `row()` makes the delivery-ledger row."""
 
     __slots__ = (
-        # ids: `seq` is never reused (head_token identity); the interval
-        # record with this `seq` dispatched the cohort.
+        # ids: `seq` is never reused (the guard join's claim names the
+        # head by it); the interval record with this `seq` dispatched
+        # the cohort.
         "seq", "interval_seq", "variant", "actives",
         # the work: dispatched slots, the store generations at dispatch,
         # the worker and what it leaves (`cand`: the fetched candidate
@@ -118,8 +122,6 @@ class Cohort:
     )
 
     def __init__(self, seq, variant, slots, interval_sec):
-        import time as _time
-
         self.seq = seq
         self.interval_seq = None
         self.variant = variant
@@ -131,11 +133,11 @@ class Cohort:
         self.err = None
         self.cand = None
         self.pool = 0  # tickets in the pool at dispatch
-        self.t_dispatch = _time.perf_counter()
+        self.t_dispatch = time.perf_counter()
         # Wall-clock twin of t_dispatch: ledger consumers (bench slip
         # gate, trace spans) attribute cohorts to dispatch windows
         # without reconstructing it from lag arithmetic.
-        self.t_dispatch_wall = _time.time()
+        self.t_dispatch_wall = time.time()
         self.t_window_wall = None
         # Delivery deadline: the cohort must reach players before its
         # OWN interval ends. collect_ready preempts gap work for a
@@ -237,7 +239,7 @@ class Cohort:
         return out
 
 
-class TpuBackend:
+class TpuBackend(ProcessBackend):
     """ProcessBackend implementation running on the JAX default device."""
 
     def __init__(
@@ -249,16 +251,10 @@ class TpuBackend:
         col_block: int = 2048,
         big_row_block: int = 1024,
         big_col_block: int = 1024,
-        tracing=None,
     ):
         self.config = config
         self.logger = logger.with_fields(subsystem="matchmaker.tpu")
         self.metrics = metrics
-        if tracing is None:
-            from ..tracing import Tracing
-
-            tracing = Tracing()
-        self.tracing = tracing
         cap = config.pool_capacity
         self.fn = config.numeric_fields
         self.fs = config.string_fields
@@ -282,14 +278,10 @@ class TpuBackend:
 
         # Multi-device: shard the pool's slot axis over a mesh; dispatch
         # runs the blockwise kernel per shard and merges over ICI
-        # (SURVEY §2.8; parallel/mesh.py). Opt-in via config.mesh_devices.
-        self._mesh = None
-        # Operators drive these via the config `parallel` section, which
-        # boot resolves onto the matchmaker config (config.apply_parallel);
-        # getattr defaults keep direct-construction callers working.
-        self._mesh_axis = getattr(config, "mesh_axis", "pool") or "pool"
-        self._mesh_gather_k = getattr(config, "mesh_gather_k", 0)
-        mesh_n = getattr(config, "mesh_devices", 0)
+        # (SURVEY §2.8; parallel/mesh.py). Opt-in via config.mesh_devices
+        # (0 off, -1 every visible device, n).
+        self.mesh = None
+        mesh_n = config.mesh_devices
         if mesh_n:
             n_dev = len(jax.devices()) if mesh_n < 0 else mesh_n
             if len(jax.devices()) < n_dev:
@@ -311,17 +303,13 @@ class TpuBackend:
                     f"shards across {n_dev} devices for the sharded MXU "
                     "kernel (or raise big_pool_threshold above capacity)"
                 )
-            from ..parallel.mesh import make_mesh
-
-            self._mesh = make_mesh(n_dev, axis=self._mesh_axis)
+            self.mesh = make_mesh(n_dev)
 
         sharding = None
-        if self._mesh is not None:
+        if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
-            sharding = NamedSharding(
-                self._mesh, PartitionSpec(self._mesh_axis)
-            )
+            sharding = NamedSharding(self.mesh, PartitionSpec(POOL_AXIS))
         self.pool = PoolBuffer(
             cap, self.fn, self.fs, self.s, self.d,
             on_flush=self._observe_chunk,
@@ -343,10 +331,10 @@ class TpuBackend:
         )
         self.logger.info("matchmaker device backend", **self._where)
         self._gather_rows = None
-        if self._mesh is not None:
+        if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
-            replicated = NamedSharding(self._mesh, PartitionSpec())
+            replicated = NamedSharding(self.mesh, PartitionSpec())
             self._gather_rows = jax.jit(
                 lambda pool, safe: {
                     key: v[safe] for key, v in pool.items()
@@ -388,7 +376,7 @@ class TpuBackend:
         self._should_count = 0
         self._emb_mask = np.zeros(cap, dtype=bool)
         self._emb_count = 0
-        # Pure-pairs pool tracking (device_pairing gate): a ticket is
+        # Pure-pairs pool tracking (the `_use_pairs` gate): a ticket is
         # "pair-shaped" iff solo 1v1 (min==max==2, one presence,
         # count_multiple 1|2). The synchronous interval path can then run
         # grouping on device (device2.pair_partners).
@@ -444,10 +432,8 @@ class TpuBackend:
         # this breaker and intervals route every active through the
         # bounded host-oracle fallback until a half-open probe closes it.
         self.breaker = CircuitBreaker(
-            threshold=getattr(config, "breaker_threshold", 3),
-            cooldown_s=(
-                getattr(config, "breaker_cooldown_ms", 30_000) / 1000.0
-            ),
+            threshold=config.breaker_threshold,
+            cooldown_s=config.breaker_cooldown_ms / 1000.0,
             on_transition=self._on_breaker_transition,
         )
         # Mesh rung of the ladder: when the SHARDED dispatch fails, this
@@ -456,10 +442,8 @@ class TpuBackend:
         # the main breaker below it still guards device work as a whole,
         # so a dead device degrades mesh → single-device → host oracle.
         self.mesh_breaker = CircuitBreaker(
-            threshold=getattr(config, "breaker_threshold", 3),
-            cooldown_s=(
-                getattr(config, "breaker_cooldown_ms", 30_000) / 1000.0
-            ),
+            threshold=config.breaker_threshold,
+            cooldown_s=config.breaker_cooldown_ms / 1000.0,
             on_transition=self._on_mesh_breaker_transition,
         )
         # ICI gather accounting for the sharded merge (console + gauge).
@@ -473,11 +457,19 @@ class TpuBackend:
         # wakes immediately instead of a gap poll discovering the result
         # seconds later. None = nobody listening (tests, sync mode).
         self._ready_cb = None
-        # Monotonic per-dispatch sequence (`Cohort.seq`): head_token
+        # Monotonic per-dispatch sequence (`Cohort.seq`): the head's
         # identity. id() of the cohort is NOT usable — CPython reuses a
         # freed object's address for the next cohort's, which would
         # make a new head look already-guard-joined.
         self._dispatch_counter = 0
+        # The deadline guard (local.py delivery stage): the margin
+        # before a cohort's delivery deadline at which its assembly is
+        # block-joined, and the `seq` of the head that already had its
+        # one guard join — a head joined and found unfinished is
+        # wedged, the reclaim path's, never re-joined into the next
+        # cycle.
+        self._guard = max(0.1, float(config.pipeline_deadline_guard_sec))
+        self._guard_joined = 0
         # Kernel + full shapes of the dispatch being launched: copied
         # onto the cohort (→ the interval breadcrumb's `kernel`) and
         # named by the ERROR a refused program logs.
@@ -489,7 +481,7 @@ class TpuBackend:
         # each matched ticket to ITS cohort's stage chain when one call
         # collects several. Transient — replaced every call, never
         # retained past it.
-        self._accepted_cohorts: list[Cohort] = []
+        self.accepted_cohorts: list[Cohort] = []
         # Device telemetry plane: the named jit entry points this
         # backend drives. Registration installs the process-wide
         # compile-watch listener (jax is imported by now), so every
@@ -500,17 +492,17 @@ class TpuBackend:
             "matchmaker.assign",
             "matchmaker.fetch",
         ]
-        if self._mesh is not None:
+        if self.mesh is not None:
             # The sharded interval splits scoring into two named entry
             # points so compile-watch attributes per-shard scan vs
             # gather+merge separately.
             kernels += ["matchmaker.shard_score", "matchmaker.gather_merge"]
         for kernel in kernels:
             DEVOBS.register(kernel)
-        if self.metrics is not None and self._mesh is not None:
-            n_dev = self._mesh.shape[self._mesh_axis]
+        if self.metrics is not None and self.mesh is not None:
+            n_dev = self.mesh.shape[POOL_AXIS]
             self.metrics.mesh_devices.set(n_dev)
-            for d in self._mesh.devices.flat:
+            for d in self.mesh.devices.flat:
                 self.metrics.mesh_shard_slots.labels(
                     device=str(d.id)
                 ).set(cap // n_dev)
@@ -542,12 +534,16 @@ class TpuBackend:
             out.append(f"prewarm_failures={self.prewarm_failures}")
         return out
 
-    def attach(self, store):
-        """Bind the LocalMatchmaker's SlotStore: one slot space shared by
-        host metadata, reverse maps, and device rows."""
-        self.store = store
+    def attach(self, store, tracing):
+        """Bind the LocalMatchmaker's SlotStore — one slot space shared
+        by host metadata, reverse maps, and device rows — and its
+        interval record."""
+        super().attach(store, tracing)
         self.meta = store.meta
         self.pool.store = store
+
+    def annotate(self, name: str):
+        return trace_api.annotate(name)
 
     # -------------------------------------------------- pool notifications
 
@@ -727,6 +723,9 @@ class TpuBackend:
         self._nonpair_mask[slots] = False
         self._in_flight_mask[slots] = False
 
+    def in_flight(self, slot: int) -> bool:
+        return bool(self._in_flight_mask[slot])
+
     # ------------------------------------------------- degradation ladder
 
     def _on_breaker_transition(self, old: str, new: str, reason: str):
@@ -871,13 +870,8 @@ class TpuBackend:
         cohort (the belt-and-braces orphan case no known code path
         produces). Either way no ticket is ever stranded un-matchable
         behind a claim nobody will release."""
-        grace = (
-            getattr(self.config, "inflight_reclaim_deadline_ms", 60_000)
-            / 1000.0
-        )
-        import time as _time
-
-        now = _time.perf_counter()
+        grace = self.config.inflight_reclaim_deadline_ms / 1000.0
+        now = time.perf_counter()
         abandoned = False
         while self._pipeline_queue:
             head = self._pipeline_queue[0]
@@ -942,9 +936,7 @@ class TpuBackend:
         No step here is O(entries) Python — that per-entry host
         bookkeeping measured ~1.5s/interval at ~100k matched entries in
         round 2 and was the north-star latency floor."""
-        meta = self.meta
         pipelined = self.config.interval_pipelining
-        self._accepted_cohorts = []
         # Device telemetry: one warmup tick per interval — after
         # config.devobs.warmup_intervals of these, a hot-path compile
         # is an unexpected recompile (WARN + counter + span event).
@@ -1019,18 +1011,16 @@ class TpuBackend:
             device_slots = device_slots[ff]
             device_last = device_last[ff]
 
-        sel = self._sel_mask
-        sel[:] = False
-        flat_parts: list[np.ndarray] = []
-        size_parts: list[np.ndarray] = []
-        # Slots whose assembled match was dropped after they may already
-        # have gone inactive (pipelined collection lags dispatch by one
-        # interval): give them another active interval. Budget-deferred
-        # host-only slots likewise — the caller's expiry pass deactivates
-        # min==max actives after ONE processing attempt, and a deferred
-        # slot hasn't had its attempt yet. Failed dispatch/collect slots
-        # ride the same channel (degradation ladder: no ticket strands).
-        react_parts: list[np.ndarray] = []
+        # react_parts: slots whose assembled match was dropped after
+        # they may already have gone inactive (pipelined collection lags
+        # dispatch by one interval) get another active interval.
+        # Budget-deferred host-only slots likewise — the caller's expiry
+        # pass deactivates min==max actives after ONE processing
+        # attempt, and a deferred slot hasn't had its attempt yet.
+        # Failed dispatch/collect slots ride the same channel
+        # (degradation ladder: no ticket strands).
+        parts = self._open_batch()
+        sel, flat_parts, size_parts, react_parts = parts
         if deferred_slots is not None and len(deferred_slots):
             react_parts.append(deferred_slots.astype(np.int32))
 
@@ -1044,12 +1034,10 @@ class TpuBackend:
                 device_slots, device_last
             )
             pending = None
-            import time as _time
-
             # Device-timeline window opens BEFORE the flush: the
             # cohort's ledger entry slices the kernel-event timeline
             # from here, so its scatter phase reads off the record too.
-            t_window_wall = _time.time()
+            t_window_wall = time.time()
             # Each dispatched cohort gets its own trace: root span over
             # flush+dispatch, held open until accept/abandon closes it
             # with the stage spans. A dispatch failure makes it an
@@ -1185,18 +1173,28 @@ class TpuBackend:
                     )
                     sel[slots_m] = True
 
-        for work in ready_works:
-            self._accept_work(
-                work, crumb, sel, flat_parts, size_parts, react_parts,
-                pipelined,
-            )
-
-        batch, matched_slots, reactivate = self._finalize_batch(
-            sel, flat_parts, size_parts, react_parts
+        return self._accept(
+            ready_works, crumb, parts, pipelined, interval=True
         )
-        crumb["matched_entries"] = batch.entry_count
-        self.tracing.record(crumb, interval=True)
-        return batch, matched_slots, reactivate
+
+    def _open_batch(self):
+        """Start one call's accept scaffolding: the claimed-slot scratch
+        cleared, no cohort accepted yet, and the parts a batch is made
+        of — (sel, flat_parts, size_parts, react_parts)."""
+        self.accepted_cohorts = []
+        self._sel_mask[:] = False
+        return self._sel_mask, [], [], []
+
+    def _accept(self, works, crumb, parts, pipelined, interval=False):
+        """Accept these cohorts, oldest first, into the batch `parts`
+        holds so far, and close `crumb` over it: (batch, matched_slots,
+        reactivate_slots)."""
+        for work in works:
+            self._accept_work(work, crumb, *parts, pipelined)
+        out = self._finalize_batch(*parts)
+        crumb["matched_entries"] = out[0].entry_count
+        self.tracing.record(crumb, interval=interval)
+        return out
 
     # ----------------------------------------------- pipeline state surface
 
@@ -1214,25 +1212,33 @@ class TpuBackend:
         collection would be free, no blocking join)?"""
         return bool(self._pipeline_queue) and self._pipeline_queue[0].ready()
 
-    def head_token(self):
-        """Opaque identity of the current head cohort (None when the
-        queue is empty): its monotonic dispatch sequence number, never
-        reused. The delivery stage guard-joins each head at most once —
-        a token it already joined and found unfinished is a wedged
-        head, booked to the reclaim path instead of re-joined into the
-        next cycle."""
+    def guard_point(self) -> float | None:
+        deadline = self.next_deadline()
+        return None if deadline is None else deadline - self._guard
+
+    def claim_guard_join(self) -> bool:
+        """The delivery stage guard-joins each head at most once: a head
+        it already joined and found unfinished is wedged, booked to the
+        reclaim path instead of re-joined into the next cycle."""
         if not self._pipeline_queue:
-            return None
-        return self._pipeline_queue[0].seq
+            return False
+        head = self._pipeline_queue[0]
+        if head.ready() or head.seq == self._guard_joined:
+            return False
+        self._guard_joined = head.seq
+        return True
 
     def reclaim_stale(self):
-        """Public reclamation entry for the delivery stage: abandon
-        cohorts wedged `inflight_reclaim_deadline_ms` past their
-        delivery deadline and clear orphaned in-flight claims BETWEEN
-        process() calls. Without this the backstop sweep only runs once
-        per interval, so a wedged head discovered mid-gap would hold
-        the queue until the next dispatch."""
-        self._reclaim_stale()
+        """Public reclamation entry for the delivery stage, for a head
+        past its delivery deadline: abandon cohorts wedged
+        `inflight_reclaim_deadline_ms` past theirs and clear orphaned
+        in-flight claims BETWEEN process() calls. Without this the
+        backstop sweep only runs once per interval, so a wedged head
+        discovered mid-gap would hold the queue until the next
+        dispatch."""
+        deadline = self.next_deadline()
+        if deadline is not None and time.perf_counter() > deadline:
+            self._reclaim_stale()
 
     def next_deadline(self) -> float | None:
         """Earliest delivery deadline among queued cohorts (perf_counter
@@ -1260,14 +1266,9 @@ class TpuBackend:
         if len(self._pipeline_queue) > 1:
             return True
         deadline = self._pipeline_queue[0].deadline
-        import time as _time
+        return time.perf_counter() >= deadline - 2.0 * self._guard
 
-        guard = max(
-            0.1, float(self.config.pipeline_deadline_guard_sec)
-        )
-        return _time.perf_counter() >= deadline - 2.0 * guard
-
-    def join_head(self, until: float) -> bool:
+    def join_head(self, until: float | None = None) -> bool:
         """Block (yielding the GIL — and with it the core — to the
         cohort's worker thread) until the head cohort's assembly
         finishes or `until` (perf_counter seconds) passes. Returns
@@ -1281,8 +1282,6 @@ class TpuBackend:
         the next cycle. A head still unfinished past that point belongs
         to the reclaim path (`inflight_reclaim_deadline_ms` →
         reclaim_stale abandons it and frees its slots)."""
-        import time as _time
-
         try:
             # Runs in a worker thread (delivery stage's asyncio.to_thread)
             # while the event loop may pop the queue from process_slots:
@@ -1291,9 +1290,9 @@ class TpuBackend:
             head = self._pipeline_queue[0]
         except IndexError:
             return False
-        guard = max(0.1, float(self.config.pipeline_deadline_guard_sec))
-        until = min(until, head.deadline + guard)
-        head.thread.join(max(0.0, until - _time.perf_counter()))
+        bound = head.deadline + self._guard
+        until = bound if until is None else min(until, bound)
+        head.thread.join(max(0.0, until - time.perf_counter()))
         return head.ready()
 
     def collect_ready(self, *, rev_precision: bool, block_until=None):
@@ -1310,7 +1309,6 @@ class TpuBackend:
         ready."""
         if not self._pipeline_queue:
             return None
-        self._accepted_cohorts = []
         if block_until is not None:
             self.join_head(block_until)
         ready_works: list[Cohort] = []
@@ -1319,27 +1317,14 @@ class TpuBackend:
         if not ready_works:
             return None
         crumb = self.tracing.open_crumb(midgap_collect=True)
-        sel = self._sel_mask
-        sel[:] = False
-        flat_parts: list[np.ndarray] = []
-        size_parts: list[np.ndarray] = []
-        react_parts: list[np.ndarray] = []
-        for work in ready_works:
-            self._accept_work(
-                work, crumb, sel, flat_parts, size_parts, react_parts,
-                pipelined=True,
-            )
-        out = self._finalize_batch(sel, flat_parts, size_parts, react_parts)
-        crumb["matched_entries"] = out[0].entry_count
-        self.tracing.record(crumb)
-        return out
+        return self._accept(
+            ready_works, crumb, self._open_batch(), pipelined=True
+        )
 
     def _accept_work(
         self, work: Cohort, crumb, sel, flat_parts, size_parts,
         react_parts, pipelined,
     ):
-        import time as _time
-
         span = self.tracing.span
         w_slots, w_gen = work.slots, work.gen
         if pipelined:
@@ -1375,7 +1360,7 @@ class TpuBackend:
                 crumb["collect_reclaimed"] = (
                     crumb.get("collect_reclaimed", 0) + n
                 )
-                work.t_collect = _time.perf_counter()
+                work.t_collect = time.perf_counter()
                 self._lose_cohort(
                     work, "collect", f"collect failed: {e}",
                     ledger=pipelined,
@@ -1391,7 +1376,7 @@ class TpuBackend:
         # not-yet-ready cohort popped by backpressure (or the
         # non-pipelined path) charges its real blocking wait to the
         # collect stamp instead of under-reporting.
-        work.t_collect = now = _time.perf_counter()
+        work.t_collect = now = time.perf_counter()
         work.slipped = bool(pipelined and now > work.deadline)
         if work.slipped:
             crumb["cohort_slipped"] = crumb.get("cohort_slipped", 0) + 1
@@ -1467,7 +1452,7 @@ class TpuBackend:
         work.matched_slots = good_flat
         work.matches = int(good.sum())
         work.envelopes = int(self.meta["count"][good_flat].sum())
-        work.t_accept = _time.perf_counter()
+        work.t_accept = time.perf_counter()
         if pipelined:
             # Per-cohort dispatch→delivered ledger: slips are read off
             # the console/metrics, not inferred from bench WARN lines.
@@ -1480,7 +1465,7 @@ class TpuBackend:
                 if work.slipped:
                     self.metrics.mm_cohort_slipped.inc()
             self._record_cohort(work)
-            self._accepted_cohorts.append(work)
+            self.accepted_cohorts.append(work)
         self._close_cohort_trace(work)
 
     def _record_cohort(self, work: Cohort) -> None:
@@ -1489,11 +1474,9 @@ class TpuBackend:
         cohort's flush and now (shared-mesh neighbors — leaderboard
         flushes — land here too, which is the point: contention reads
         off one record)."""
-        import time as _time
-
         row = work.row()
         row["device_timeline"] = DEVOBS.timeline_between(
-            work.t_window_wall or work.t_dispatch_wall, _time.time()
+            work.t_window_wall or work.t_dispatch_wall, time.time()
         )
         work.entry = self.tracing.record_delivery(**row)
         if work.asm is not None:
@@ -1539,8 +1522,6 @@ class TpuBackend:
         tctx, work.trace = work.trace, None
         if tctx is None:
             return
-        import time as _time
-
         trace_id, parent = tctx
         base = work.t_dispatch_wall
         for name, stamp in (
@@ -1555,7 +1536,7 @@ class TpuBackend:
         trace_api.emit_span(
             trace_id, parent, "cohort.collected",
             start_ts=base,
-            end_ts=base + work.lag(_time.perf_counter()),
+            end_ts=base + work.lag(time.perf_counter()),
             status=status, message=message,
             breaker=self.breaker.state,
         )
@@ -1584,6 +1565,9 @@ class TpuBackend:
             reactivate = np.zeros(0, dtype=np.int32)
         return batch, matched_slots, reactivate
 
+    def flush(self):
+        self.pool.flush()
+
     def wait_idle(self, timeout: float | None = None):
         """Block until every dispatched cohort's compute + D2H + gap-side
         assembly completed (the results stay queued for the next process()
@@ -1591,16 +1575,14 @@ class TpuBackend:
         production interval gap, and at shutdown so no worker thread
         outlives the runtime (incl. prewarm compiles: XLA aborts the
         process if a compile thread dies at teardown)."""
-        import time as _time
-
         deadline = (
-            None if timeout is None else _time.monotonic() + timeout
+            None if timeout is None else time.monotonic() + timeout
         )
 
         def _left():
             if deadline is None:
                 return None
-            return max(0.0, deadline - _time.monotonic())
+            return max(0.0, deadline - time.monotonic())
 
         for work in list(self._pipeline_queue):
             work.thread.join(_left())
@@ -1799,7 +1781,7 @@ class TpuBackend:
         hw = self.pool.high_water
         with_should = self._should_count > 0
         with_embedding = self._emb_count > 0
-        if self._mesh is not None and self.mesh_breaker.allow():
+        if self.mesh is not None and self.mesh_breaker.allow():
             try:
                 # chaos: raise/stall the dispatch (mesh rung first)
                 faults.fire("device.dispatch")
@@ -1866,7 +1848,6 @@ class TpuBackend:
                     bm=bm,
                     bn=bn,
                     interpret=self._interpret,
-                    emb_scale=self.config.emb_score_scale,
                     # The handshake needs eligible candidates, not the
                     # exact (-score, created) order: skip stage 2's
                     # second sort.
@@ -1930,17 +1911,15 @@ class TpuBackend:
         )
 
     def _use_pairs(self) -> bool:
-        """Device-side 1v1 grouping is eligible when configured and the
-        whole pool is pure 1v1 — one predicate for the single-chip and
-        mesh dispatch paths. Synchronous intervals shed the candidate
-        matrix D2H (their latency floor); pipelined intervals shed the
+        """Device-side 1v1 grouping is taken when the whole pool is pure
+        1v1 — one predicate, on what the backend observes, for the
+        single-chip and mesh dispatch paths. Synchronous intervals shed
+        the candidate matrix D2H (their latency floor); pipelined intervals shed the
         gap-side host work (16MB fetch + native assembly) that contends
         with the server on small hosts — the cohort-slip tail. Staleness
         semantics are identical either way: pairs flow through the same
         gen/alive/sel accept masks as assembler matches."""
-        return (
-            self.config.device_pairing and self._nonpair_count == 0
-        )
+        return self._nonpair_count == 0
 
     def _pairs_dispatch(self, cand_dev, slots, a_pad, last, rev):
         """Propose-accept handshake over (exact-ranked or merged)
@@ -1982,8 +1961,6 @@ class TpuBackend:
         copy_to_host_async alone proved unreliable here — issued before
         the computation commits, some plugins drop it and the collect-side
         np.asarray pays the full transfer."""
-        import time as _time
-
         self._dispatch_counter += 1
         out = Cohort(
             self._dispatch_counter, self._dispatching, slots,
@@ -2021,10 +1998,10 @@ class TpuBackend:
                 # "D2H" while it was device compute.
                 with annotate("cohort.device"):
                     jax.block_until_ready(dev_arrays)
-                out.t_device_done = _time.perf_counter()
+                out.t_device_done = time.perf_counter()
                 with annotate("cohort.d2h"):
                     fetched = [_fetch(a)[:n_rows] for a in dev_arrays]
-                out.t_fetched = _time.perf_counter()
+                out.t_fetched = time.perf_counter()
                 with annotate("cohort.assemble"):
                     if kind == "pairs":
                         out.asm = self._assemble_pairs(slots, *fetched, rev)
@@ -2041,7 +2018,7 @@ class TpuBackend:
                 out.err = e
             finally:
                 DEVOBS.mem_add("matchmaker.dispatch", -dispatch_bytes)
-                out.t_ready = _time.perf_counter()
+                out.t_ready = time.perf_counter()
                 # Completion signal LAST (after the ready stamp, so a
                 # woken collector always sees a finished cohort). A
                 # failing callback must never kill the worker before
@@ -2188,10 +2165,8 @@ class TpuBackend:
         collection/assembly are common."""
         import jax.numpy as jnp
 
-        from ..parallel.mesh import gather_width, mesh_merge_fn, mesh_score_fn
-
-        axis = self._mesh_axis
-        n_dev = self._mesh.shape[axis]
+        axis = POOL_AXIS
+        n_dev = self.mesh.shape[axis]
         if self.pool.high_water >= self.config.big_pool_threshold:
             from .device2 import stage1_plan, topk_candidates_big_sharded
 
@@ -2220,7 +2195,7 @@ class TpuBackend:
                     pad_to(slots, a_pad, -1),
                     grid_lo,
                     grid_inv,
-                    mesh=self._mesh,
+                    mesh=self.mesh,
                     axis=axis,
                     fn=self.fn,
                     fs=self.fs,
@@ -2231,7 +2206,6 @@ class TpuBackend:
                     bm=bm,
                     bn=bn,
                     interpret=self._interpret,
-                    emb_scale=self.config.emb_score_scale,
                 )
             if self._use_pairs():
                 # Works on the ICI-merged candidate lists exactly as on
@@ -2247,28 +2221,28 @@ class TpuBackend:
         rows = dict(self._gather_rows(self.pool.device, safe))
         rows["_valid"] = jnp.asarray((pad_slots >= 0).astype(np.int32))
         rows["_slot"] = jnp.asarray(pad_slots.astype(np.int32))
+        # Every shard hands its full top-k to the merge: exact.
         k = min(self.k, self.pool.capacity)
-        w = gather_width(k, n_dev, self._mesh_gather_k)
         self._dispatching = self._variant(
             f"mesh_score+mesh_merge/{n_dev}", a_pad, self.pool.capacity,
             br, self.col_block, rev, with_should, with_embedding,
         )
         self._prewarm_mesh_bucket(
-            a_pad, w, rev, with_should, with_embedding,
+            a_pad, k, rev, with_should, with_embedding,
             {rk: (rv.shape, rv.dtype) for rk, rv in rows.items()},
         )
         score = mesh_score_fn(
-            self._mesh, axis, w, br, self.col_block, rev,
+            self.mesh, axis, k, br, self.col_block, rev,
             with_should, with_embedding, self.pool.capacity,
         )
         with DEVOBS.device_call("matchmaker.shard_score"):
             s_all, i_all = score(
                 self.pool.device, rows, jnp.int32(self._created_base)
             )
-        self._account_gather(n_dev * a_pad * w * 8)
+        self._account_gather(n_dev * a_pad * k * 8)
         faults.fire("mesh.gather")  # chaos: fail the ICI merge
         with DEVOBS.device_call("matchmaker.gather_merge"):
-            scores, cand = mesh_merge_fn(n_dev, w, k)(s_all, i_all)
+            scores, cand = mesh_merge_fn(n_dev, k)(s_all, i_all)
         return self._bg_asm("small", (scores, cand), slots, last, rev)
 
     def _account_gather(self, nbytes: int):
@@ -2280,7 +2254,7 @@ class TpuBackend:
             self.metrics.mesh_gather_bytes.set(nbytes)
 
     def _prewarm_mesh_bucket(
-        self, a_pad, w, rev, with_should, with_embedding, row_shapes
+        self, a_pad, k_top, rev, with_should, with_embedding, row_shapes
     ):
         """Mesh twin of _prewarm_row_bucket: whenever a row bucket is
         dispatched on the sharded path, compile every smaller bucket
@@ -2290,12 +2264,12 @@ class TpuBackend:
         NamedSharding — jit keys on shardings as well as shapes, so an
         unsharded clone would warm a different cache entry than the
         live dispatch hits."""
-        key0 = ("mesh", a_pad, w, rev, with_should, with_embedding)
+        key0 = ("mesh", a_pad, k_top, rev, with_should, with_embedding)
         self._warmed_buckets.add(key0)
         sizes = []
         half = a_pad // 2
         while half >= self.row_block:
-            key = ("mesh", half, w, rev, with_should, with_embedding)
+            key = ("mesh", half, k_top, rev, with_should, with_embedding)
             if key not in self._warmed_buckets:
                 self._warmed_buckets.add(key)
                 sizes.append(half)
@@ -2306,15 +2280,12 @@ class TpuBackend:
             k: (v.shape, v.dtype) for k, v in self.pool.device.items()
         }
         sharding = self.pool.sharding
-        mesh, axis = self._mesh, self._mesh_axis
+        mesh, axis = self.mesh, POOL_AXIS
         n_dev = mesh.shape[axis]
-        k_top = min(self.k, self.pool.capacity)
 
         def _warm():
             import jax
             import jax.numpy as jnp
-
-            from ..parallel.mesh import mesh_merge_fn, mesh_score_fn
 
             try:
                 with DEVOBS.device_call(
@@ -2325,10 +2296,10 @@ class TpuBackend:
                         for k, (shp, dt) in pool_shapes.items()
                     }
                 score = mesh_score_fn(
-                    mesh, axis, w, self.row_block, self.col_block, rev,
+                    mesh, axis, k_top, self.row_block, self.col_block, rev,
                     with_should, with_embedding, self.pool.capacity,
                 )
-                merge = mesh_merge_fn(n_dev, w, k_top)
+                merge = mesh_merge_fn(n_dev, k_top)
                 for size in sizes:
                     # Fully-masked pass: zero _valid rows score nothing,
                     # but the compile against this row bucket is real.
@@ -2348,7 +2319,7 @@ class TpuBackend:
             except Exception as e:  # best-effort: never break dispatch
                 for size in sizes:
                     self._warmed_buckets.discard(
-                        ("mesh", size, w, rev, with_should, with_embedding)
+                        ("mesh", size, k_top, rev, with_should, with_embedding)
                     )
                 self._note_prewarm_failure(
                     e, self._variant(
@@ -2442,7 +2413,6 @@ class TpuBackend:
                             bm=bm,
                             bn=bn,
                             interpret=self._interpret,
-                            emb_scale=self.config.emb_score_scale,
                             order_exact=order_exact,
                         )
                     if not order_exact:

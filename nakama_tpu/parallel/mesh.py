@@ -25,8 +25,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..matchmaker.device import FLAG_VALID, NEG_INF, scan_columns
 
+# The one mesh axis: the pool's column shards partition over it.
+POOL_AXIS = "pool"
 
-def make_mesh(n_devices: int | None = None, axis: str = "pool") -> Mesh:
+
+def make_mesh(n_devices: int | None = None, axis: str = POOL_AXIS) -> Mesh:
     devices = jax.devices()
     if n_devices is not None:
         devices = devices[:n_devices]
@@ -101,7 +104,7 @@ def describe_mesh(
     return out
 
 
-def shard_pool(pool: dict, mesh: Mesh, axis: str = "pool") -> dict:
+def shard_pool(pool: dict, mesh: Mesh, axis: str = POOL_AXIS) -> dict:
     """Place pool arrays sharded along their slot axis."""
     sharding = NamedSharding(mesh, P(axis))
     return {k: jax.device_put(v, sharding) for k, v in pool.items()}
@@ -195,17 +198,18 @@ def mesh_score_fn(
 
 
 @functools.lru_cache(maxsize=None)
-def mesh_merge_fn(n_dev: int, gather_w: int, k: int):
+def mesh_merge_fn(n_dev: int, k: int):
     """Build (once per width tuple) the jitted gather+merge entry point:
-    the per-shard [D, A_pad, w] partials concatenate along the shard
+    the per-shard [D, A_pad, k] partials concatenate along the shard
     axis — GSPMD inserts the all_gather over ICI right here, the merge
     IS the cross-shard candidate exchange — and one lax.top_k keeps the
-    global best k per row. Gathered bytes per call: D*A_pad*w*8."""
+    global best k per row: every shard hands over its full top-k, so
+    the merge is exact. Gathered bytes per call: D*A_pad*k*8."""
 
     def merge(s_all, i_all):
         a_pad = s_all.shape[1]
-        s_cat = jnp.moveaxis(s_all, 0, 1).reshape(a_pad, n_dev * gather_w)
-        i_cat = jnp.moveaxis(i_all, 0, 1).reshape(a_pad, n_dev * gather_w)
+        s_cat = jnp.moveaxis(s_all, 0, 1).reshape(a_pad, n_dev * k)
+        i_cat = jnp.moveaxis(i_all, 0, 1).reshape(a_pad, n_dev * k)
         best_s, sel = jax.lax.top_k(s_cat, k)
         best_i = jnp.take_along_axis(i_cat, sel, axis=1)
         best_i = jnp.where(best_s > NEG_INF, best_i, -1)
@@ -225,34 +229,19 @@ def sharded_topk_rows(
     rev: bool,
     with_should: bool,
     with_embedding: bool,
-    axis: str = "pool",
-    gather_k: int = 0,
+    axis: str = POOL_AXIS,
     created_base=0,
 ):
     """Per-device blockwise top-K over the local column shard, then a
     global merge via all_gather over ICI. Returns (scores [A_pad, k],
     global slot ids [A_pad, k]).
 
-    `gather_k` bounds the per-shard width gathered over ICI (0 = k, the
-    exact merge; smaller widths are an approximate bandwidth trade,
-    never below ceil(k / n_devices) so the merged pool can still fill k
-    rows). One-call convenience over the cached mesh_score_fn /
-    mesh_merge_fn pair the production dispatch drives separately (so
-    the two phases carry their own compile-watch attribution)."""
-    n_dev = mesh.shape[axis]
+    One-call convenience over the cached mesh_score_fn / mesh_merge_fn
+    pair the production dispatch drives separately (so the two phases
+    carry their own compile-watch attribution)."""
     n_total = pool_sharded["num"].shape[0]
-    w = gather_width(k, n_dev, gather_k)
     score = mesh_score_fn(
-        mesh, axis, w, br, bc, rev, with_should, with_embedding, n_total
+        mesh, axis, k, br, bc, rev, with_should, with_embedding, n_total
     )
     s_all, i_all = score(pool_sharded, rows, jnp.int32(created_base))
-    return mesh_merge_fn(n_dev, w, k)(s_all, i_all)
-
-
-def gather_width(k: int, n_dev: int, gather_k: int = 0) -> int:
-    """Effective per-shard top-K width gathered before the merge:
-    gather_k when set (floored so n_dev shards can still fill k global
-    rows), else the exact width k."""
-    if not gather_k:
-        return k
-    return max(gather_k, -(-k // n_dev))
+    return mesh_merge_fn(mesh.shape[axis], k)(s_all, i_all)

@@ -1026,8 +1026,4 @@ class RecoveryPlane:
             # already joined — join them too, or interpreter teardown
             # aborts the process mid-XLA-compile ("terminate called
             # without an active exception").
-            wait_idle = getattr(
-                self.matchmaker.backend, "wait_idle", None
-            )
-            if wait_idle is not None:
-                wait_idle(timeout=10.0)
+            self.matchmaker.backend.wait_idle(timeout=10.0)

@@ -51,13 +51,6 @@ class NakamaServer:
 
         set_node_name(node)
 
-        # Resolve the operator-facing `parallel` section onto the
-        # matchmaker config BEFORE the backend is constructed: the mesh
-        # shape is a pool-allocation decision, not a runtime toggle.
-        from .config import apply_parallel
-
-        self._parallel_note = apply_parallel(config)
-
         # Persistence (reference DbConnect, main.go:129-133): constructed
         # here, connected in start(). `database=None` builds the embedded
         # engine from config.
@@ -216,12 +209,11 @@ class NakamaServer:
         # reader-pool high-water mark become scrapeable, and drain spans
         # (record_db_drain) land in the same Tracing ledger operators
         # already read interval breadcrumbs from — the matchmaker
-        # backend owns that instance, hence binding after it exists.
+        # owns that instance, hence binding after it exists.
         # (An injected engine gets the same binding: per-server.)
         if hasattr(self.db, "bind_observability"):
             self.db.bind_observability(
-                metrics=self.metrics,
-                tracing=getattr(self.matchmaker.backend, "tracing", None),
+                metrics=self.metrics, tracing=self.matchmaker.tracing
             )
         # Crash-recovery plane (recovery.py): attaches the durable
         # ticket journal + idle-gap checkpointer to the matchmaker;
@@ -269,7 +261,7 @@ class NakamaServer:
         # no deadlines — the pre-overload behavior).
         from . import overload as overload_mod
         from . import tracing as tracing_mod
-        from .tracing import SloRecorder, Tracing
+        from .tracing import SloRecorder
 
         # Request-scoped tracing + SLO plane (tracing.py): configure
         # the process-wide trace store from config (tail sampling,
@@ -325,9 +317,6 @@ class NakamaServer:
         self.matchmaker.slo = self.slo
 
         self.overload = None
-        self._overload_tracing = getattr(
-            self.matchmaker.backend, "tracing", None
-        ) or Tracing(logger=log)
         if config.overload.enabled:
             oc = config.overload
             admission = overload_mod.AdmissionController(
@@ -353,7 +342,7 @@ class NakamaServer:
                 recover_samples=oc.ladder_recover_samples,
                 logger=log.with_fields(subsystem="overload"),
                 metrics=self.metrics,
-                tracing=self._overload_tracing,
+                tracing=self.matchmaker.tracing,
             )
         self.runtime = None
         self.matchmaker.on_matched = self._wrap_matched(
@@ -685,13 +674,11 @@ class NakamaServer:
                         oc.shed_queue_depth_shed,
                     ),
                 )
-            if getattr(self.matchmaker.backend, "breaker", None) is not None:
+            if self.matchmaker.backend.breaker is not None:
                 self.overload.register_signal(
                     "backend_breaker",
                     overload_mod.breaker_signal(
-                        lambda: getattr(
-                            self.matchmaker.backend, "breaker", None
-                        )
+                        lambda: self.matchmaker.backend.breaker
                     ),
                 )
             self.overload.register_signal(
@@ -770,38 +757,23 @@ class NakamaServer:
                 timeline_depth=dv.timeline_depth,
                 capture_max_ms=dv.capture_max_ms,
             )
-        pl = self.config.parallel
-        if pl.enabled:
+        mesh = self.matchmaker.backend.mesh
+        if mesh is not None:
             # The mesh posture in one line (boot-log convention): an
             # operator asking "is the pool sharded, over how many
-            # devices, at what merge width" reads it here — including
-            # the small-pool refusal, which otherwise looks identical
-            # to a silently-ignored config.
-            backend = getattr(self.matchmaker, "backend", None)
-            mesh = getattr(backend, "_mesh", None)
+            # devices" reads it here.
             self.logger.info(
                 "mesh-sharded matchmaking enabled",
-                devices=(
-                    mesh.shape[pl.axis] if mesh is not None else 0
-                ),
-                axis=pl.axis,
-                gather_k=pl.gather_k or None,
-                min_pool_for_mesh=pl.min_pool_for_mesh or None,
-                note=self._parallel_note,
+                devices=mesh.size,
+                configured=self.config.matchmaker.mesh_devices,
             )
         mm_cfg = self.config.matchmaker
         if mm_cfg.interval_pipelining:
-            # The delivery posture in one line: operators diagnosing a
-            # dispatch→matched tail need to know whether cohorts ship on
-            # completion events or on the watchdog poll cadence.
+            # The delivery posture in one line: cohorts ship on their
+            # completion events; the watchdog bounds a lost signal.
             self.logger.info(
                 "matchmaker delivery stage started",
-                event_driven=bool(
-                    getattr(mm_cfg, "delivery_event_driven", True)
-                ),
-                watchdog_sec=float(
-                    getattr(mm_cfg, "delivery_watchdog_sec", 1.0)
-                ),
+                watchdog_sec=float(mm_cfg.delivery_watchdog_sec),
                 deadline_guard_sec=float(
                     mm_cfg.pipeline_deadline_guard_sec
                 ),
@@ -886,8 +858,8 @@ class NakamaServer:
         # empties or the deadline passes — a SIGTERM must not strand a
         # formed match that one more second would have shipped. (The
         # journal's unpublished-match records cover whatever remains.)
-        depth = getattr(self.matchmaker.backend, "pipeline_depth", None)
-        if depth is not None and grace:
+        depth = self.matchmaker.backend.pipeline_depth
+        if grace:
             while depth() and loop.time() < deadline:
                 await asyncio.sleep(0.05)
         self.matchmaker.stop()

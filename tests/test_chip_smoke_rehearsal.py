@@ -213,11 +213,14 @@ def test_refused_program_is_logged_with_kernel_and_shapes():
     import logging
 
     from nakama_tpu.logger import Logger
+    from nakama_tpu.matchmaker import LocalMatchmaker
     from nakama_tpu.matchmaker.tpu import TpuBackend
 
     buf = io.StringIO()
     log = Logger(level=logging.INFO, fmt="json", streams=[buf])
-    backend = TpuBackend(_cfg("tpu"), log)
+    cfg = _cfg("tpu")
+    backend = TpuBackend(cfg, log)
+    LocalMatchmaker(log, cfg, backend=backend)  # binds store and record
     assert '"pallas_interpret": true' in buf.getvalue()  # said at INFO
     backend._dispatching = backend._variant(
         "topk_candidates_big", 131072, 131072, 1024, 1024, True, False, True

@@ -259,90 +259,57 @@ def test_cluster_reshard_config_check():
     assert cfg.cluster.reshard.handover_timeout_ms == 4000
 
 
-def test_parallel_defaults_off():
+@pytest.mark.parametrize(
+    "mesh_devices, mutate, error",
+    [
+        # 0 = single device, -1 = every visible device, n = a count the
+        # host exposes that splits the capacity (conftest provisions 8
+        # CPU devices).
+        (0, None, None),
+        (-1, None, None),
+        (4, None, None),
+        (8, None, None),
+        (-2, None, "mesh_devices must be"),
+        # More devices than the host exposes is a boot-time error, not
+        # a first-dispatch surprise.
+        (8192, None, "devices visible"),
+        # The capacity must split into equal column shards.
+        (3, None, "equal column shards"),
+        (-1, lambda mm: setattr(mm, "pool_capacity", 1001),
+         "equal column shards"),
+        # The mesh path rides the pipelined gap: refuse sync intervals.
+        (4, lambda mm: setattr(mm, "interval_pipelining", False),
+         "interval_pipelining"),
+        # Off, nothing of the mesh is checked.
+        (0, lambda mm: setattr(mm, "interval_pipelining", False), None),
+    ],
+)
+def test_mesh_devices_bounds(mesh_devices, mutate, error):
     cfg = Config()
-    assert cfg.parallel.enabled is False
-    assert cfg.parallel.n_devices == -1
-    assert cfg.parallel.axis == "pool"
-    assert cfg.parallel.gather_k == 0
-    assert cfg.parallel.min_pool_for_mesh == 0
-    # Off means the legacy backend knob is untouched.
-    from nakama_tpu.config import apply_parallel
-
-    assert apply_parallel(cfg) is None
-    assert cfg.matchmaker.mesh_devices == 0
-
-
-def test_parallel_check_bounds():
-    def base():
-        cfg = Config()
-        cfg.parallel.enabled = True
-        return cfg
-
-    base().check()  # defaults are valid when enabled
-    cfg = base()
-    cfg.parallel.axis = "8bad axis"
-    with pytest.raises(ValueError, match="axis"):
+    cfg.matchmaker.mesh_devices = mesh_devices
+    if mutate is not None:
+        mutate(cfg.matchmaker)
+    if error is None:
         cfg.check()
-    cfg = base()
-    cfg.parallel.n_devices = 0
-    with pytest.raises(ValueError, match="n_devices"):
-        cfg.check()
-    cfg = base()
-    cfg.parallel.n_devices = -2
-    with pytest.raises(ValueError, match="n_devices"):
-        cfg.check()
-    for bad in (3, 6, -1):
-        cfg = base()
-        cfg.parallel.gather_k = bad
-        with pytest.raises(ValueError, match="gather_k"):
+    else:
+        with pytest.raises(ValueError, match=error):
             cfg.check()
-    for good in (0, 1, 2, 64):
-        cfg = base()
-        cfg.parallel.gather_k = good
-        cfg.check()
-    cfg = base()
-    cfg.parallel.min_pool_for_mesh = -1
-    with pytest.raises(ValueError, match="min_pool_for_mesh"):
-        cfg.check()
-    # The mesh path rides the pipelined gap: refuse sync intervals.
-    cfg = base()
-    cfg.matchmaker.interval_pipelining = False
-    with pytest.raises(ValueError, match="interval_pipelining"):
-        cfg.check()
-    # More devices than the host exposes is a boot-time error, not a
-    # first-dispatch surprise (conftest provisions 8 CPU devices).
-    cfg = base()
-    cfg.parallel.n_devices = 8192
-    with pytest.raises(ValueError, match="devices visible"):
-        cfg.check()
-    # Small pool + floor: warned, not fatal (boot stays single-device).
-    cfg = base()
-    cfg.parallel.min_pool_for_mesh = cfg.matchmaker.pool_capacity * 2
-    warnings = cfg.check()
-    assert any("single-device" in w for w in warnings)
 
 
-def test_apply_parallel_resolution():
-    from nakama_tpu.config import apply_parallel
-
-    cfg = Config()
-    cfg.parallel.enabled = True
-    cfg.parallel.n_devices = 4
-    cfg.parallel.axis = "shard"
-    cfg.parallel.gather_k = 16
-    assert apply_parallel(cfg) is None
-    assert cfg.matchmaker.mesh_devices == 4
-    assert cfg.matchmaker.mesh_axis == "shard"
-    assert cfg.matchmaker.mesh_gather_k == 16
-    # The occupancy floor refuses the mesh with a loggable note.
-    cfg = Config()
-    cfg.parallel.enabled = True
-    cfg.parallel.n_devices = 4
-    cfg.parallel.min_pool_for_mesh = cfg.matchmaker.pool_capacity * 2
-    note = apply_parallel(cfg)
-    assert note and "single-device" in note
-    assert cfg.matchmaker.mesh_devices == 0
+def test_deleted_matchmaker_options_are_refused():
+    """The served path's options that nothing set are gone for good: a
+    config file or flag that still names one fails at load, it is not
+    silently ignored."""
+    for key in (
+        "delivery_event_driven", "device_pairing", "mesh_gather_k",
+        "mesh_axis", "emb_score_scale",
+    ):
+        assert not hasattr(Config().matchmaker, key)
+        with pytest.raises(ValueError, match="unknown config flag"):
+            parse_args([f"--matchmaker.{key}=1"])
+    assert not hasattr(Config(), "parallel")
+    with pytest.raises(ValueError, match="unknown config flag"):
+        parse_args(["--parallel.enabled=true"])
 
 
 def test_parse_args_config_flag(tmp_path):

@@ -191,7 +191,7 @@ async def _tick_on_device_backend(tickets: int, pipelined: bool):
     server = NakamaServer(config, quiet_logger())
     backend = TpuBackend(config.matchmaker, quiet_logger())
     server.matchmaker.backend = backend
-    backend.attach(server.matchmaker.store)
+    backend.attach(server.matchmaker.store, server.matchmaker.tracing)
     await server.start()
     for i in range(tickets):
         p = MatchmakerPresence(user_id=f"u{i}", session_id=f"s{i}")

@@ -300,7 +300,7 @@ def test_mesh_dispatch_fault_degrades_to_single_device_same_interval():
     point fires twice in that interval (mesh rung, then single-device
     rung), the tickets still match, and nothing strands."""
     mm, backend, got = make_mm(pool_capacity=512, mesh_devices=8)
-    assert backend._mesh is not None
+    assert backend.mesh is not None
     add(mm, mn=2, mx=2)
     add(mm, mn=2, mx=2)
     fired_before = faults.PLANE.fired.get("device.dispatch", 0)
@@ -327,7 +327,13 @@ def test_mesh_gather_fault_opens_mesh_breaker_and_heals_to_parity():
     mm, backend, got = make_mm(
         pool_capacity=512, mesh_devices=8, breaker_cooldown_ms=200
     )
-    assert backend._mesh is not None
+    assert backend.mesh is not None
+    # The mesh breaker reads a clock this test moves: however long a
+    # loaded host takes over an interval, the cooldown neither runs out
+    # while the fault is armed (a probe then would fail and double it)
+    # nor is still running when the probe is due.
+    clock = [0.0]
+    backend.mesh_breaker._clock = lambda: clock[0]
     faults.arm("mesh.gather", "raise")
     # Each faulted dispatch still matches on the fallback, so feed the
     # pool fresh tickets per interval to keep the mesh rung dispatching.
@@ -350,7 +356,13 @@ def test_mesh_gather_fault_opens_mesh_breaker_and_heals_to_parity():
     mm.process()
     assert faults.PLANE.fired.get("mesh.gather") == fired_before
     faults.disarm()
-    time.sleep(0.25)  # past breaker_cooldown_ms
+    clock[0] += 0.19  # still inside breaker_cooldown_ms: no probe yet
+    for _ in range(4):
+        add(mm)
+    mm.process()
+    assert backend.mesh_breaker.state == "open"
+    settle(mm, backend)
+    clock[0] += 0.02  # past it
     for _ in range(4):
         add(mm)
     mm.process()  # half-open probe takes the mesh path and succeeds
@@ -1734,6 +1746,21 @@ async def _reshard_rig():
     return buses, dirs, mms, leases, migs, tids, plan
 
 
+async def _reshard_aborted(migs) -> int:
+    """Wait, bounded, until the source has aborted AND the target has
+    seen its abort frame (the frame crosses the bus after the source
+    counts its abort: asserting on the target at once is a race).
+    Returns the most tickets the target held staged meanwhile."""
+    staged = 0
+    for _ in range(1500):
+        await asyncio.sleep(0.02)
+        for st in migs["o2"]._staging.values():
+            staged = max(staged, len(st["tickets"]))
+        if migs["o1"].aborts and not migs["o2"]._staging:
+            break
+    return staged
+
+
 async def _reshard_rig_down(buses, mms):
     for mm in mms.values():
         mm.stop()
@@ -1751,10 +1778,7 @@ async def test_reshard_migrate_drop_seq_gap_refuses_handover_aborts():
     try:
         faults.arm("reshard.migrate", "drop", probability=1.0)
         migs["o1"].on_begin("o1", {"plan": plan})
-        for _ in range(200):
-            await asyncio.sleep(0.02)
-            if migs["o1"].aborts:
-                break
+        await _reshard_aborted(migs)
         assert faults.PLANE.fired.get("reshard.migrate", 0) > 0
         assert migs["o1"].aborts == 1 and migs["o1"].completed == 0
         assert migs["o2"].refused_handovers == 1
@@ -1790,10 +1814,9 @@ async def test_reshard_handover_drop_staged_never_live_clean_abort():
         assert moving  # the leg must exercise a real parked slice
         faults.arm("reshard.handover", "drop", probability=1.0)
         migs["o1"].on_begin("o1", {"plan": plan})
-        for _ in range(200):
-            await asyncio.sleep(0.02)
-            if migs["o1"].aborts:
-                break
+        # The staging really was complete on the target before the
+        # abort discarded it.
+        assert await _reshard_aborted(migs) == len(moving)
         assert faults.PLANE.fired.get("reshard.handover", 0) == 1
         assert migs["o1"].aborts == 1 and migs["o1"].completed == 0
         assert migs["o2"].migrated_in == 0  # staged, never blessed

@@ -168,10 +168,17 @@ async def test_tournament_lifecycle_sweep_parity():
         )
         sched = LeaderboardScheduler(quiet_logger(), lb, t, runtime)
         now = time.time()
+        # Nothing here races the wall clock: the hourly reset lies half
+        # an hour from now whatever the time of day, so no bucket
+        # boundary falls inside the test, and the tournament ends well
+        # after its last write — the scheduler below is fired at a time
+        # of its own, past that end.
+        minute = (time.gmtime(now).tm_min + 30) % 60
+        end_time = now + 60.0
         await t.create(
             "cup", duration=3600, sort_order=sort_order,
-            reset_schedule="0 * * * *", start_time=now - 7200,
-            end_time=now + 0.2, operator="best",
+            reset_schedule=f"{minute} * * * *", start_time=now - 7200,
+            end_time=end_time, operator="best",
         )
         n = rng.randrange(15, 60)
         for i in range(n):
@@ -190,7 +197,8 @@ async def test_tournament_lifecycle_sweep_parity():
         )
         assert engine.sweeps >= 1  # it really was the device path
         # Scheduler end fire: the hook payload carries the final sweep.
-        await sched._fire(now + 1.0)
+        assert t.is_active(lb.get("cup"))  # every write was in time
+        await sched._fire(end_time + 0.8)
         ends = [b for kind, b in fired if kind == "end"]
         assert ends and ends[0]["standings"] == host_standings
         # Expiry rollover: writes after the bucket boundary land in a

@@ -83,13 +83,13 @@ class Rig:
         )
         log = quiet_logger()
         self.backend = TpuBackend(cfg, log, row_block=8, col_block=64)
-        self.tracing = self.backend.tracing
         self.sessions = LocalSessionRegistry(log)
         router = LocalMessageRouter(log, self.sessions, tracker=None)
         self.mm = LocalMatchmaker(
             log, cfg, backend=self.backend,
             on_matched=make_matched_handler(log, router, "n1", "k" * 32),
         )
+        self.tracing = self.mm.tracing
         self.mm.journal = _Journal()
         self.pipeline = Pipeline(log, Components(
             config=None, tracker=None, router=router, status_registry=None,
@@ -199,7 +199,7 @@ async def test_publish_stamp_survives_a_saturated_ledger():
     await _cycle(rig, 1)
     assert len(rig.tracing.deliveries) == cap
     *older, row = rig.tracing.recent_deliveries(cap)
-    assert row is rig.backend._accepted_cohorts[0].entry
+    assert row is rig.backend.accepted_cohorts[0].entry
     assert row["publish_lag_s"] >= row["accept_lag_s"] > 0.0
     assert row["delivery_held_s"] > 0.0
     assert not any("publish_lag_s" in r for r in older)

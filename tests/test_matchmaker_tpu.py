@@ -409,11 +409,11 @@ def test_host_only_budget_defers_overflow():
 
 
 def _pairing_mm(**kw):
-    """Synchronous big-path pool where device_pairing engages."""
+    """Synchronous big-path pool: pure 1v1, it takes the device
+    pairing handshake."""
     defaults = dict(
         big_pool_threshold=64,
         interval_pipelining=False,
-        device_pairing=True,
         candidates_per_ticket=128,  # complete lists: full pairing exists
         max_intervals=2,
     )
@@ -482,7 +482,11 @@ def test_device_pairing_respects_incompatible_tickets():
     assert total >= 64
 
 
-def test_device_pairing_disabled_for_nonpair_pools():
+@pytest.mark.parametrize("nonpair", [0, 1])
+def test_pairs_are_chosen_by_pool_shape_alone(nonpair):
+    """No option selects the grouping: a pure-1v1 big pool takes
+    `pair_partners`, and one ticket that is no pair (min 3) sends the
+    same pool to the native assembler."""
     mm, got = _pairing_mm()
     calls = []
     import nakama_tpu.matchmaker.device2 as d2
@@ -492,12 +496,20 @@ def test_device_pairing_disabled_for_nonpair_pools():
     try:
         for i in range(70):
             add(mm, "properties.mode:x", strs={"mode": "x"})
-        # One non-pair ticket (min 3) flips the pool off the pairing path.
-        add(mm, "properties.mode:x", mn=3, mx=3, strs={"mode": "x"})
+        for i in range(nonpair):
+            add(mm, "properties.mode:x", mn=3, mx=3, strs={"mode": "x"})
         mm.process()
     finally:
         d2.pair_partners = orig
-    assert not calls
+    (crumb,) = mm.tracing.recent(1)
+    if nonpair:
+        assert not calls
+        assert crumb["kernel"]["kernel"] == "topk_candidates_big"
+    else:
+        assert calls
+        assert crumb["kernel"]["kernel"] == (
+            "topk_candidates_big+pair_partners"
+        )
     assert sum(len(es) for b in got for es in b) >= 68
 
 

@@ -229,7 +229,7 @@ def test_full_process_on_mesh_matches_single_device():
 
     mm_single, matched_single = build(0)
     mm_mesh, matched_mesh = build(8)
-    assert mm_mesh.backend._mesh is not None
+    assert mm_mesh.backend.mesh is not None
     for _ in range(2):
         mm_single.process()
         mm_mesh.process()
@@ -267,10 +267,6 @@ def test_full_process_on_mesh_big_kernel_matches_single_device():
             max_constraints=8,
             mesh_devices=mesh_devices,
             big_pool_threshold=64,  # force the MXU path at test scale
-            # Exact assembler parity is what this test proves; the
-            # device-pairing fast path (sync pure-1v1 pools) is covered
-            # by its own tests in test_matchmaker_tpu.py.
-            device_pairing=False,
         )
         backend = TpuBackend(
             cfg, quiet_logger(), row_block=16, col_block=64,
@@ -292,11 +288,20 @@ def test_full_process_on_mesh_big_kernel_matches_single_device():
                 f" +properties.rank:<={r + 20}",
                 2, 2, 1, {"mode": f"m{m}"}, {"rank": float(r)},
             )
+        # Exact assembler parity is what this test proves: one ticket
+        # that is no pair (a trio in a mode of its own, it never
+        # matches) keeps the pool off the device-pairing handshake a
+        # pure-1v1 pool takes (its own tests: test_matchmaker_tpu.py).
+        p = MatchmakerPresence(user_id="trio", session_id="trio")
+        mm.add(
+            [p], p.session_id, "", "+properties.mode:trio", 3, 3, 1,
+            {"mode": "trio"}, {},
+        )
         return mm, matched
 
     mm_single, matched_single = build(0)
     mm_mesh, matched_mesh = build(8)
-    assert mm_mesh.backend._mesh is not None
+    assert mm_mesh.backend.mesh is not None
     # Prove the big path actually dispatched (not a silent small-path
     # fallback): capture the pending tag.
     tags = []
@@ -315,6 +320,7 @@ def test_full_process_on_mesh_big_kernel_matches_single_device():
     assert any(
         t.startswith("topk_candidates_big_sharded/") for t in tags
     ), "mesh path did not take the sharded MXU kernel"
+    assert not any("pair_partners" in t for t in tags)
 
     def pairs(matched):
         return sorted(
@@ -376,7 +382,7 @@ def test_mesh_parity_cross_shard_pairs_1_2_8_way():
     def cohorts(mesh_devices):
         mm, matched = _build_paired_mm(mesh_devices)
         if mesh_devices:
-            assert mm.backend._mesh is not None
+            assert mm.backend.mesh is not None
         for _ in range(2):
             mm.process()
         return sorted(
@@ -553,7 +559,6 @@ def test_device_pairing_runs_on_mesh():
         mesh_devices=8,
         big_pool_threshold=16,
         interval_pipelining=False,
-        device_pairing=True,
     )
     backend = TpuBackend(
         cfg, quiet_logger(), row_block=16, col_block=128,

@@ -277,7 +277,7 @@ def test_sharded_pool_snapshot_restore_roundtrip():
         return mm, backend
 
     mm, backend = build()
-    assert backend._mesh is not None
+    assert backend.mesh is not None
     tids = [_add(mm, i) for i in range(6)]
     mm.remove([tids[2]])
     snap = mm.snapshot_state()
