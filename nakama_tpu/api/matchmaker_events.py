@@ -6,6 +6,15 @@ MatchmakerMatched hook — a returned match id sends users to an authoritative
 match; otherwise mint a short-lived match token (30s JWT naming every user)
 for relayed-match rendezvous — then route a `matchmaker_matched` envelope to
 every matched presence.
+
+A match's fan-out is one pass over its entries and one router call
+(`send_envelopes`). Per entry it makes a presence dict, a `users` element, a
+`self` dict, a body, an envelope and a `(node, session_id, envelope)` triple,
+and no more: the presence dict stands under both `users[i]["presence"]` and
+the entry's `self["presence"]`, the `users` list and the token are one object
+in all of a match's bodies, and the property dicts are the entries' own. So
+nothing may mutate an envelope after it is built: a session that changed what
+it was sent would change what the match's other sessions are yet to encode.
 """
 
 from __future__ import annotations
@@ -19,7 +28,6 @@ from typing import Any
 
 from ..logger import Logger
 from ..matchmaker.types import MatchmakerEntry
-from ..realtime import PresenceID
 from . import session_token
 
 MATCH_TOKEN_EXPIRY_SEC = 30
@@ -74,9 +82,13 @@ def make_matched_handler(
     # Where a publish goes, summed over the matches of one batch; the
     # matchmaker moves the sums onto the delivery call's ledger row and
     # zeroes them (local.py `_publish`). Five stamps a match, none an
-    # entry: a match's bodies are all built before the first is routed.
+    # entry: a match's bodies are all built before the first is routed,
+    # and a match is routed before the next is built, so no session waits
+    # for a later match's bodies. `publish_route_calls` counts the router
+    # calls: one a match.
     stages = dict(
         publish_matches=0, publish_envelopes=0, publish_tokens=0,
+        publish_route_calls=0,
         publish_materialise_s=0.0, publish_hook_s=0.0,
         publish_token_s=0.0, publish_envelope_s=0.0, publish_route_s=0.0,
     )
@@ -102,7 +114,9 @@ def make_matched_handler(
                         log.error("matchmaker matched hook error", error=str(e))
             t_hooked = perf_counter()
 
-            if not match_id:
+            if match_id:
+                outcome_key, outcome = "match_id", match_id
+            else:
                 user_list = ",".join(
                     sorted(
                         [
@@ -113,7 +127,7 @@ def make_matched_handler(
                 )
                 # The token names a relayed-match rendezvous id every matched
                 # client can join (reference matchmaker.go:392-399).
-                token = session_token.sign(
+                outcome_key, outcome = "token", session_token.sign(
                     key,
                     (
                         f"{before_tid}{next(ids)}{after_tid}"
@@ -125,45 +139,43 @@ def make_matched_handler(
                 stages["publish_tokens"] += 1
             t_token = perf_counter()
 
-            ticket_of = {e.presence.session_id: e.ticket for e in entries}
-            users = [
-                {
-                    "presence": e.presence.as_dict(),
+            # One pass: an entry's presence dict is made once and stands
+            # under both `users[i]` and the entry's own `self`; `users` is
+            # one list for the whole match.
+            users = []
+            recipients = []
+            for e in entries:
+                p = e.presence
+                presence = {
+                    "user_id": p.user_id,
+                    "session_id": p.session_id,
+                    "username": p.username,
+                }
+                users.append({
+                    "presence": presence,
                     "party_id": e.party_id,
                     "string_properties": e.string_properties,
                     "numeric_properties": e.numeric_properties,
-                }
-                for e in entries
-            ]
-            bodies = []
-            for entry in entries:
-                body: dict = {
-                    "ticket": ticket_of[entry.presence.session_id],
+                })
+                body = {
+                    "ticket": e.ticket,
                     "users": users,
-                    "self": {"presence": entry.presence.as_dict()},
+                    "self": {"presence": presence},
+                    outcome_key: outcome,
                 }
-                if match_id:
-                    body["match_id"] = match_id
-                else:
-                    body["token"] = token
-                bodies.append(body)
-            t_bodies = perf_counter()
-            for entry, body in zip(entries, bodies):
                 # Cluster: a forwarded ticket's presences carry their
-                # origin node — route the envelope there (the cluster
-                # router ships it over the bus; single-node presences
-                # carry no node and stay local).
-                router.send_to_presence_ids(
-                    [
-                        PresenceID(
-                            entry.presence.node or node,
-                            entry.presence.session_id,
-                        )
-                    ],
-                    {"matchmaker_matched": body},
+                # origin node — the cluster router ships the envelope
+                # there over the bus; single-node presences carry no
+                # node and stay local.
+                recipients.append(
+                    (p.node or node, p.session_id,
+                     {"matchmaker_matched": body})
                 )
+            t_bodies = perf_counter()
+            router.send_envelopes(recipients)
+            stages["publish_route_calls"] += 1
             stages["publish_matches"] += 1
-            stages["publish_envelopes"] += len(bodies)
+            stages["publish_envelopes"] += len(recipients)
             stages["publish_materialise_s"] += t_entries - t_next
             stages["publish_hook_s"] += t_hooked - t_entries
             stages["publish_token_s"] += t_token - t_hooked

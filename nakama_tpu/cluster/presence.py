@@ -292,7 +292,8 @@ class ClusterTracker(LocalTracker):
 class ClusterMessageRouter(LocalMessageRouter):
     """LocalMessageRouter + cross-node routing by PresenceID.node:
     local presences deliver to local sessions, remote ones ship one
-    `route` frame per owning node carrying the envelope. Presence
+    `route` frame per owning node carrying the envelope (`send_envelopes`:
+    one per remote recipient, each envelope being its own). Presence
     events stay node-local (each node emits them to its own sessions
     from its replicated tracker view)."""
 
@@ -314,20 +315,40 @@ class ClusterMessageRouter(LocalMessageRouter):
             elif not self._presence_local_only:
                 remote.setdefault(pid.node, []).append(pid.session_id)
         super().send_to_presence_ids(local, envelope)
-        if not remote or self.bus is None:
+        if self.bus is None:
             return
         for node, sids in remote.items():
-            try:
-                ok = self.bus.send(
-                    node, "route", {"sids": sids, "env": envelope}
-                )
-            except Exception as e:
-                self.logger.warn(
-                    "cross-node route failed", node=node, error=str(e)
-                )
-                ok = False
-            if not ok and self.metrics:
-                self.metrics.outgoing_dropped.inc(len(sids))
+            self._ship(node, sids, envelope)
+
+    def send_envelopes(self, recipients):
+        """Local recipients go to their sessions; a remote one ships its
+        own `route` frame to its node (each envelope differs, so a frame
+        a remote recipient)."""
+        me = self.node
+        get = self.sessions.get
+        dropped = 0
+        for node, session_id, envelope in recipients:
+            if node == me or not node:
+                session = get(session_id)
+                if session is not None and not session.send(envelope):
+                    dropped += 1
+            elif self.bus is not None:
+                self._ship(node, [session_id], envelope)
+        if dropped and self.metrics:
+            self.metrics.outgoing_dropped.inc(dropped)
+
+    def _ship(self, node: str, sids: list[str], envelope: dict):
+        """One `route` frame to `node`; a frame that did not leave counts
+        its sessions as dropped."""
+        try:
+            ok = self.bus.send(node, "route", {"sids": sids, "env": envelope})
+        except Exception as e:
+            self.logger.warn(
+                "cross-node route failed", node=node, error=str(e)
+            )
+            ok = False
+        if not ok and self.metrics:
+            self.metrics.outgoing_dropped.inc(len(sids))
 
     def route_presence_event(self, event):
         # Each node emits presence events to its OWN sessions from its

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
+import gc
 import threading
 import time
 import uuid
@@ -302,6 +303,12 @@ def _select_backend(config: MatchmakerConfig, logger, metrics):
     return TpuBackend(config, logger, metrics)
 
 
+def _young_collections() -> int:
+    """Collections of generations 0 and 1 this process has run so far."""
+    gen0, gen1, _ = gc.get_stats()
+    return gen0["collections"] + gen1["collections"]
+
+
 class _TicketsView:
     """Mapping-compat view of live tickets (tests/console); not used on
     the interval path."""
@@ -461,8 +468,6 @@ class LocalMatchmaker:
         fallbacks cover lost signals and wedged heads)."""
 
         async def _loop():
-            import gc
-
             # The gap pass below owns full collections; an AUTOMATIC
             # gen2 pass over this server's steady heap (~100k ticket
             # objects plus runtime state) measures 100-650ms and lands
@@ -482,8 +487,6 @@ class LocalMatchmaker:
                 gc.set_threshold(g0, g1, g2_saved)
 
         async def _loop_body():
-            import gc
-
             shed_streak = 0
             while not self._stopped:
                 t0 = time.perf_counter()
@@ -998,7 +1001,9 @@ class LocalMatchmaker:
         nodes down) returns the held tickets' id set so ONLY those
         cohorts journal unpublished. Where the handler keeps publish
         stage sums (`stages`, api/matchmaker_events.py), they are moved
-        onto `row`, the ledger row of the delivery call."""
+        onto `row`, the ledger row of the delivery call, beside
+        `publish_gc_collections`: the young-generation collections that
+        ran while the handler did."""
         try:
             if faults.fire("delivery.publish"):
                 # drop-mode chaos: delivery intentionally discarded.
@@ -1009,14 +1014,31 @@ class LocalMatchmaker:
                 if self.metrics is not None:
                     self.metrics.mm_delivery_failed.inc()
                 return False
+            # No automatic collection inside the call: what the handler
+            # builds survives it (the sessions hold the envelopes), so a
+            # young-generation pass in there only walks survivors, some
+            # 730 times a full-pool cohort. The first collection after
+            # the call walks them once, after the last envelope has
+            # left; the interval's gap pass still owns full collections.
+            gc_was_enabled = gc.isenabled()
+            gc.disable()
+            collections = _young_collections()
             try:
                 self.on_matched(batch)
             finally:
+                if row is not None:
+                    row["publish_gc_collections"] = (
+                        _young_collections() - collections
+                    )
                 stages = getattr(self.on_matched, "stages", None)
                 if stages:
                     if row is not None:
                         row.update(stages)
                     stages.update(dict.fromkeys(stages, 0))
+                # Last, so that the pass it sets off starts after the
+                # caller's publish stamp and is not read as publishing.
+                if gc_was_enabled:
+                    gc.enable()
             return True
         except PartialPublish as e:
             self.logger.warn(

@@ -3,7 +3,10 @@
 Parity with the reference MessageRouter (reference
 server/message_router.go:33-110): send to explicit presence IDs or to every
 presence on a stream, honoring hidden presences for presence events, with a
-deferred-send queue the match loop flushes per tick.
+deferred-send queue the match loop flushes per tick. Beside the reference's
+`SendToPresenceIDs` (one envelope to many presences) stands `send_envelopes`:
+one call for a fan-out whose recipients each get an envelope of their own,
+which is what a formed match is (api/matchmaker_events.py).
 """
 
 from __future__ import annotations
@@ -57,6 +60,20 @@ class LocalMessageRouter:
             if not session.send(envelope):
                 if self.metrics:
                     self.metrics.outgoing_dropped.inc()
+
+    def send_envelopes(self, recipients: list[tuple[str, str, dict]]):
+        """Each recipient its own envelope, in order: `(node, session_id,
+        envelope)` a recipient. `node` is the cluster router's; here every
+        session is local, and an unknown id is skipped as
+        `send_to_presence_ids` skips it."""
+        get = self.sessions.get
+        dropped = 0
+        for _node, session_id, envelope in recipients:
+            session = get(session_id)
+            if session is not None and not session.send(envelope):
+                dropped += 1
+        if dropped and self.metrics:
+            self.metrics.outgoing_dropped.inc(dropped)
 
     def send_to_stream(self, stream: Stream, envelope: dict):
         self.send_to_presence_ids(

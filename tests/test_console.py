@@ -238,6 +238,8 @@ async def test_console_matchmaker_delivery_row_counts_tokens():
         (row,) = rows
         assert row["matches"] == row["publish_matches"] == 2
         assert row["publish_tokens"] == 2 and row["publish_envelopes"] == 4
+        assert row["publish_route_calls"] == 2  # one a match
+        assert row["publish_gc_collections"] == 0
         assert row["publish_token_s"] > 0.0
     finally:
         await console.close()

@@ -49,14 +49,14 @@ SIZES = (2, 4, 10)
 
 
 class _Router:
-    """Keeps what the handler routes: (presence id, body) in order."""
+    """Keeps what the handler routes: (session id, body) in order."""
 
     def __init__(self):
         self.sent = []
 
-    def send_to_presence_ids(self, ids, envelope):
-        (pid,) = ids
-        self.sent.append((pid, envelope["matchmaker_matched"]))
+    def send_envelopes(self, recipients):
+        for _node, session_id, envelope in recipients:
+            self.sent.append((session_id, envelope["matchmaker_matched"]))
 
 
 def _match(k, size, name="n"):

@@ -24,6 +24,8 @@ from nakama_tpu.api.pipeline import Components, Pipeline
 from nakama_tpu.config import MatchmakerConfig
 from nakama_tpu.logger import test_logger as quiet_logger
 from nakama_tpu.matchmaker import LocalMatchmaker, MatchmakerPresence
+from nakama_tpu.matchmaker.local import PartialPublish
+from nakama_tpu.matchmaker.types import MatchmakerEntry
 from nakama_tpu.matchmaker.tpu import TpuBackend
 from nakama_tpu.realtime.message_router import LocalMessageRouter
 from nakama_tpu.realtime.session_registry import LocalSessionRegistry
@@ -332,6 +334,141 @@ async def test_row_counts_tokens_as_matches_less_the_hooks(via):
     assert sum("match_id" in b for b in bodies) == 2 * 2
     assert len({b["token"] for b in bodies if "token" in b}) == 3
     assert set(rig.mm.on_matched.stages.values()) == {0}
+
+
+def _thousand_matches(rig):
+    """1,000 matches of two, each entry's session in the rig's registry."""
+    matches = []
+    for _ in range(1000):
+        pair = []
+        for _ in range(2):
+            s = rig.session()
+            pair.append(MatchmakerEntry(
+                ticket=f"t-{s.id}",
+                presence=MatchmakerPresence(s.user_id, s.id, s.username),
+            ))
+        matches.append(pair)
+    return matches
+
+
+def test_row_counts_one_router_call_a_match_and_no_collection_in_the_call():
+    """The fan-out's two counters on the delivery call's row: a router
+    call a match, and no young-generation collection while the handler
+    ran, which without the pause a batch of this size sets off by the
+    dozen (14,000 tracked objects against a threshold of 700)."""
+    rig = Rig()
+    matches = _thousand_matches(rig)
+    assert gc.isenabled() and gc.get_threshold()[0] <= 2000
+    row = {}
+    assert rig.mm._publish(matches, row) is True
+    assert gc.isenabled()
+    assert row["publish_route_calls"] == row["publish_matches"] == 1000
+    assert row["publish_envelopes"] == 2000
+    assert row["publish_gc_collections"] == 0
+    assert all(
+        len(rig.sessions.get(e.presence.session_id).got) == 1
+        for pair in matches for e in pair
+    )
+    assert set(rig.mm.on_matched.stages.values()) == {0}
+
+
+class _StampedCohort:
+    """What `_deliver` needs of a cohort's record, keeping the stamp."""
+
+    def __init__(self):
+        self.entry = {}
+        self.t_accept = time.perf_counter()
+        self.stamp = None
+
+    def published(self, now):
+        self.stamp = now
+        return 0.0
+
+
+def test_the_deferred_collection_starts_after_the_publish_stamp():
+    """The call's survivors are walked once, by the first collection
+    after the handler: it must not land between the last envelope and
+    the cohort's publish stamp, or `publish_lag_s` would read a
+    collector pass as publishing."""
+    rig = Rig()
+    matches = _thousand_matches(rig)
+    cohort = _StampedCohort()
+    started = []
+
+    def note(phase, info):
+        if phase == "start":
+            started.append(time.perf_counter())
+
+    gc.collect()
+    gc.callbacks.append(note)
+    try:
+        rig.mm._deliver(matches, None, None, [cohort])
+    finally:
+        gc.callbacks.remove(note)
+    assert cohort.entry["publish_gc_collections"] == 0
+    assert started, "14,000 survivors and no collection after the call"
+    assert cohort.stamp is not None and min(started) >= cohort.stamp
+
+
+@pytest.mark.parametrize("via", ["collect", "process"])
+async def test_cohort_row_carries_the_fan_outs_counters(via):
+    rig = Rig()
+    await _cycle(rig, 5, via)
+    (row,) = rig.tracing.recent_deliveries(1)
+    assert row["publish_route_calls"] == row["publish_matches"] == 5
+    assert row["publish_gc_collections"] == 0
+
+
+def _raises(batch):
+    raise RuntimeError("handler broke")
+
+
+def _holds(batch):
+    raise PartialPublish({"t-held"})
+
+
+@pytest.mark.parametrize("enabled_before", [True, False])
+@pytest.mark.parametrize("handler, returns", [
+    (lambda batch: None, True),
+    (_raises, False),
+    (_holds, frozenset({"t-held"})),
+], ids=["clean", "raises", "partial"])
+def test_publish_leaves_the_collector_as_it_found_it(
+    handler, returns, enabled_before
+):
+    """Paused while the handler runs, and afterwards what it was before:
+    enabled again after a clean call, a raising handler and a partial
+    publish; never enabled for a caller that had it disabled."""
+    rig = Rig()
+    inside = []
+
+    def on_matched(batch):
+        inside.append(gc.isenabled())
+        return handler(batch)
+
+    rig.mm.on_matched = on_matched
+    row = {}
+    was = gc.isenabled()
+    try:
+        (gc.enable if enabled_before else gc.disable)()
+        assert rig.mm._publish([["a match"]], row) == returns
+        assert gc.isenabled() is enabled_before
+    finally:
+        (gc.enable if was else gc.disable)()
+    assert inside == [False]
+    assert row["publish_gc_collections"] == 0
+
+
+def test_dropped_publish_does_not_touch_the_collector():
+    rig = Rig()
+    rig.mm.on_matched = _raises  # never reached
+    faults.arm("delivery.publish", "drop", count=1)
+    try:
+        row = {}
+        assert rig.mm._publish([["a match"]], row) is False
+    finally:
+        faults.disarm()
+    assert gc.isenabled() and row == {}
 
 
 # ------------------------------------------------------------ a lost cohort
