@@ -876,8 +876,13 @@ def pair_partners(
     updates batch into ONE fused scatter per round.
 
     Returns (partner i32 [A] — formed-pair target slot on the PROPOSER
-    row, -1 elsewhere (each pair reports exactly once), proposer bool [A]
-    == partner >= 0, kept for call-site clarity).
+    row, -1 elsewhere (each pair reports exactly once); formed i32
+    [1, rounds] — pairs each round formed, the scan's by-product; listed
+    i32 [1] — cells of `cand` that hold a ticket). The two counters are
+    one row each, so that a caller that cuts what it fetches to its rows
+    (tpu._bg_asm) keeps them whole; they cross D2H with the partner
+    vector, end on the cohort's ledger row (tpu.Cohort.list_counts) and
+    change nothing of the rounds.
     """
     a = cand.shape[0]
     i32 = jnp.int32
@@ -946,13 +951,14 @@ def pair_partners(
             ]
         )
         avail_slot = avail_slot.at[taken].set(False, mode="drop")
-        return (avail_slot, partner), None
+        return (avail_slot, partner), jnp.sum(form, dtype=i32)
 
     init = (
         jnp.ones((cap,), dtype=bool),
         jnp.full((a,), -1, i32),
     )
-    (_, partner), _ = jax.lax.scan(
+    (_, partner), formed = jax.lax.scan(
         round_fn, init, jnp.arange(rounds, dtype=i32)
     )
-    return partner, partner >= 0
+    listed = jnp.count_nonzero(cand >= 0).astype(i32)
+    return partner, formed[None], listed[None]

@@ -110,8 +110,9 @@ class Cohort:
         "seq", "interval_seq", "variant", "actives",
         # the work: dispatched slots, the store generations at dispatch,
         # the worker and what it leaves (`cand`: the fetched candidate
-        # lists, kept until `list_counts` has read them)
-        "slots", "gen", "thread", "asm", "err", "cand", "pool",
+        # lists, or `pairs`: the device pairing's own counts, either
+        # kept until `list_counts` has read it)
+        "slots", "gen", "thread", "asm", "err", "cand", "pairs", "pool",
         # stamps, perf_counter seconds (wall twins for trace spans)
         "t_dispatch", "t_dispatch_wall", "t_window_wall", "deadline",
         "t_device_done", "t_fetched", "t_ready", "t_collect", "t_accept",
@@ -132,6 +133,7 @@ class Cohort:
         self.asm = None
         self.err = None
         self.cand = None
+        self.pairs = None
         self.pool = 0  # tickets in the pool at dispatch
         self.t_dispatch = time.perf_counter()
         # Wall-clock twin of t_dispatch: ledger consumers (bench slip
@@ -234,6 +236,21 @@ class Cohort:
             out.update(
                 candidates_valid=int(np.count_nonzero(cand >= 0)),
                 candidates_distinct=int(seen[:-1].sum()),
+                candidates_pool=self.pool,
+            )
+        pairs, self.pairs = self.pairs, None
+        if pairs is not None:
+            # The lists stayed on the device: what `pair_partners`
+            # counted there, and what the host's exact re-check left.
+            formed, listed = pairs
+            total = int(formed.sum())
+            out.update(
+                pairs_formed=total,
+                pairs_rejected=total - n,
+                pair_rounds_formed=formed.tolist(),
+                pairs_formed_last_round=int(formed[-1]),
+                pair_rounds=len(formed),
+                candidates_valid=int(listed),
                 candidates_pool=self.pool,
             )
         return out
@@ -1492,7 +1509,13 @@ class TpuBackend(ProcessBackend):
         `actives_unmatched` (searchers in no match), `matches_below_max`
         (matches smaller than their searcher's max_count). O(actives x
         k) numpy, which is why no stage between dispatch and publish
-        pays for it; a pairs cohort has no lists and gets the last two."""
+        pays for it. A pairs cohort's lists never leave the device: it
+        gets the last two, `candidates_valid` and `candidates_pool` as
+        `pair_partners` counted them there (no `candidates_distinct`),
+        and the pairing's own: `pairs_formed` on the device,
+        `pair_rounds_formed` round by round (`pair_rounds` of them,
+        `pairs_formed_last_round` the last), `pairs_rejected` by the
+        host's exact re-check or for sharing a session."""
         meta = self.meta
         while self._uncounted:
             work = self._uncounted.popleft()
@@ -1923,21 +1946,20 @@ class TpuBackend(ProcessBackend):
 
     def _pairs_dispatch(self, cand_dev, slots, a_pad, last, rev):
         """Propose-accept handshake over (exact-ranked or merged)
-        candidate lists; only the partner vector crosses D2H — the
-        candidate matrix (~16MB at 100k) stays on device."""
+        candidate lists; only the partner vector and the handshake's
+        two counters (a row each) cross D2H — the candidate matrix
+        (~16MB at 100k) stays on device."""
         import jax.numpy as jnp
 
         from .device2 import pair_partners
 
         with DEVOBS.device_call("matchmaker.assign"):
-            partner_dev, prop_dev = pair_partners(
+            paired = pair_partners(
                 cand_dev,
                 jnp.asarray(pad_to(slots, a_pad, -1)),
                 cap=self.pool.capacity,
             )
-        return self._bg_asm(
-            "pairs", (partner_dev, prop_dev), slots, last, rev
-        )
+        return self._bg_asm("pairs", paired, slots, last, rev)
 
     def _grid_params(self):
         """Bucket-grid (lo, 1/width) per numeric field for the big kernel."""
@@ -2004,7 +2026,9 @@ class TpuBackend(ProcessBackend):
                 out.t_fetched = time.perf_counter()
                 with annotate("cohort.assemble"):
                     if kind == "pairs":
-                        out.asm = self._assemble_pairs(slots, *fetched, rev)
+                        partner, formed, listed = fetched
+                        out.pairs = (formed[0], listed[0])
+                        out.asm = self._assemble_pairs(slots, partner, rev)
                     elif kind == "big":
                         # Already exactly ordered by (-score, created)
                         # on device; a row slice of the contiguous fetch
@@ -2061,7 +2085,7 @@ class TpuBackend(ProcessBackend):
         ok = self._validate_flagged(n_matches, offsets, flat, needs_host, rev)
         return n_matches, offsets, flat, ok
 
-    def _assemble_pairs(self, slots, partner, proposer, rev):
+    def _assemble_pairs(self, slots, partner, rev):
         """Host tail of the device-pairing path: exact (f64) validation of
         the device-formed pairs, vectorized over all pairs at once, then
         the shared (n_matches, offsets, flat, ok) shape. Mirrors the
@@ -2071,7 +2095,7 @@ class TpuBackend(ProcessBackend):
         failing here is dropped (its members retry next interval) rather
         than re-assembled — the f32/bucket false-positive rate this guards
         is per-mille, and reference semantics permit unmatched leftovers."""
-        idx = np.nonzero(proposer & (partner >= 0))[0]
+        idx = np.nonzero(partner >= 0)[0]
         i_slots = slots[idx]
         j_slots = partner[idx].astype(np.int32)
         ok = self._exact_accepts_vec(i_slots, j_slots)

@@ -81,7 +81,9 @@ def _fits(compiled, pallas: bool):
     return mem
 
 
-def _compile_big(one_chip, rows, n_cols, emb, rev):
+def _compile_big(
+    one_chip, rows, n_cols, emb, rev, capacity=CAP, order_exact=True
+):
     """The two-stage kernel for `rows` actives against `n_cols` pool
     columns at shipped widths; its temporaries must stay far under the
     chip."""
@@ -90,10 +92,10 @@ def _compile_big(one_chip, rows, n_cols, emb, rev):
         (CFG.numeric_fields,), jnp.float32, sharding=one_chip
     )
     compiled = device2.topk_candidates_big.lower(
-        _pool(one_chip), a, grid, grid,
+        _pool(one_chip, capacity), a, grid, grid,
         fn=CFG.numeric_fields, fs=CFG.string_fields, n_cols=n_cols,
         k=CFG.candidates_per_ticket, rev=rev, with_should=False,
-        with_embedding=emb, interpret=False,
+        with_embedding=emb, interpret=False, order_exact=order_exact,
     ).compile()
     assert _fits(compiled, pallas=True).temp_size_in_bytes < 4e9
     return compiled
@@ -150,12 +152,42 @@ def test_big_kernel_sharded_mutual(topo):
     assert _fits(compiled, pallas=True).temp_size_in_bytes < 4e9
 
 
-def test_pair_partners(one_chip):
-    cand = jax.ShapeDtypeStruct(
-        (CAP, CFG.candidates_per_ticket), jnp.int32, sharding=one_chip
+def test_big_kernel_multiqueue_pool_dispatch(one_chip):
+    """`multiqueue8x20k.burst`'s dispatch, at the big kernel's edge: a
+    pool of capacity `MAX_COLS` (the last column the packed winner word
+    can name), 160,000 duel tickets padded to 262,144 rows against
+    163,840 columns (160 column blocks: one winner a block, 256 lanes
+    of winners, the row tile halved to 512), no embedding, and stage 2
+    without its second sort, as the pairs path asks for it."""
+    cap = device2.MAX_COLS
+    assert cap == 2 * CAP == 262144
+    rows, n_cols = cap, 160 * 1024
+    _compile_big(
+        one_chip, rows, n_cols, emb=False, rev=False, capacity=cap,
+        order_exact=False,
     )
-    a = jax.ShapeDtypeStruct((CAP,), jnp.int32, sharding=one_chip)
-    _fits(device2.pair_partners.lower(cand, a, cap=CAP).compile(), False)
+    assert device2.stage1_plan(
+        n=n_cols, n_local=n_cols, k=CFG.candidates_per_ticket, bm=1024,
+        bn=1024, fn=CFG.numeric_fields, fs=CFG.string_fields, de=8,
+        rev=False,
+    ) == (1, 256, 512)
+
+
+@pytest.mark.parametrize("cap", [CAP, device2.MAX_COLS])
+def test_pair_partners(one_chip, cap):
+    """The device pairing over a full pool's lists, at the shipped
+    capacity and at `multiqueue8x20k`'s (A = cap = 262,144): eight
+    rounds in one scan, three small outputs, temporaries a few hundred
+    MB."""
+    cand = jax.ShapeDtypeStruct(
+        (cap, CFG.candidates_per_ticket), jnp.int32, sharding=one_chip
+    )
+    a = jax.ShapeDtypeStruct((cap,), jnp.int32, sharding=one_chip)
+    compiled = device2.pair_partners.lower(cand, a, cap=cap).compile()
+    assert _fits(compiled, False).temp_size_in_bytes < 1e9
+    partner, formed, listed = compiled.out_info
+    assert (partner.shape, formed.shape, listed.shape) == (
+        (cap,), (1, 8), (1,))
 
 
 def test_small_exact_kernel(one_chip):

@@ -522,12 +522,21 @@ def _metric_files():
 
 @pytest.fixture(scope="module")
 def produced():
-    """The ledger rows and interval crumbs of one small pipelined run."""
-    rig = Rig()
-    asyncio.run(_cycle(rig, 2))
-    rig.backend.count_cohorts()  # the interval loop's idle-gap sweep
-    rig.mm.process()
-    return rig.tracing.recent_deliveries(8), rig.crumbs()
+    """The ledger rows and interval crumbs of two small pipelined runs:
+    one on the exact kernel with the native assembler, one over
+    `big_pool_threshold`, where a pool of solo 1v1 tickets is paired on
+    the device and the row carries the pairing's counters."""
+    rows, crumbs = [], []
+    for kw in ({}, {"big_pool_threshold": 2}):
+        rig = Rig(**kw)
+        asyncio.run(_cycle(rig, 2))
+        rig.backend.count_cohorts()  # the interval loop's idle-gap sweep
+        rig.mm.process()
+        rows += rig.tracing.recent_deliveries(8)
+        crumbs += rig.crumbs()
+    assert [c["kernel"]["kernel"] for c in crumbs if "kernel" in c] == [
+        "topk_candidates", "topk_candidates_big+pair_partners"]
+    return rows, crumbs
 
 
 @pytest.mark.parametrize("spec", _metric_files())

@@ -616,9 +616,13 @@ def test_pair_partners_pad_rows_do_not_clobber_slot0():
         [[1, -1], [0, -1], [0, -1], [-1, -1]], dtype=jnp.int32
     )
     active = jnp.asarray([0, 1, 2, -1], dtype=jnp.int32)
-    partner, proposer = pair_partners(cand, active, cap=8, rounds=4)
+    partner, formed, listed = pair_partners(cand, active, cap=8, rounds=4)
     partner = np.asarray(partner)
-    proposer = np.asarray(proposer)
+    proposer = partner >= 0
+    # the counters: one row each, pairs round by round, filled cells
+    assert np.asarray(formed).shape == (1, 4)
+    assert int(np.asarray(formed).sum()) == int(proposer.sum())
+    assert np.asarray(listed).tolist() == [3]
     pairs = {
         tuple(sorted((int(active[i]), int(partner[i]))))
         for i in np.nonzero(proposer)[0]
