@@ -835,16 +835,34 @@ def _stage2_rows(
 # -------------------------------------------------------- device pairing
 
 
+# Rows of one step of a pairing round's walk over the open rows: the
+# ladder of `pair_ladder` rises by it.
+PAIR_CHUNK = 8192
+
+
+def pair_ladder(a: int) -> tuple[int, ...]:
+    """The row counts a pairing round over `a` rows can run its [rows, k]
+    work at: every multiple of PAIR_CHUNK up to the first that holds `a`
+    (`a` alone where it is no larger than one chunk). A function of the
+    shape, nothing else; a round takes the smallest that holds its open
+    rows, and none where no row is open."""
+    step = min(PAIR_CHUNK, a)
+    return tuple(range(step, a + step, step))
+
+
 @functools.partial(jax.jit, static_argnames=("cap", "rounds"))
 def pair_partners(
-    cand: jnp.ndarray,  # i32 [A, k] candidate slots, best-first, -1 pad
+    cand: jnp.ndarray,  # i32 [A, k] candidate slots, -1 pad
     active_slots: jnp.ndarray,  # i32 [A] row slots, oldest-first, -1 pad
     *,
     cap: int,
     rounds: int = 8,
 ):
     """Greedy 1v1 assignment entirely on device: parallel propose-accept
-    rounds over the exact-ranked candidate lists, oldest-first priority.
+    rounds over the candidate lists, oldest-first priority. The lists
+    are eligible and compacted but not exactly ranked (the pairs path
+    asks `topk_candidates_big` for `order_exact=False`): their order is
+    stage 1's selection priority.
 
     Replaces the candidate-matrix D2H ([A,k] i32 is ~16MB at a 100k
     pool) with a partner vector (~0.5MB) and removes the native greedy
@@ -857,7 +875,7 @@ def pair_partners(
     reactivate. Semantics per round:
 
     - every open row proposes to a still-available candidate — its
-      top-ranked one in round 0, pseudo-randomly diffused afterwards
+      first-listed one in round 0, pseudo-randomly diffused afterwards
       (equal-score pools give every row the SAME candidate order, and
       un-diffused proposals serialize to one pair per round);
     - every proposed-to slot accepts its oldest proposer (min row index —
@@ -869,25 +887,40 @@ def pair_partners(
       older row. Passive pool slots (inactive but matchable tickets) can
       accept but never propose.
 
-    Built scatter-free where it counts: TPU scatters over ~100k random
-    indices measured ~8-10ms EACH (the first cut spent 1.17s in 24
-    rounds of them). Acceptance (per-slot min proposer) runs as a
-    sort + neighbor-compare + un-sort — two [A] sorts — and availability
-    updates batch into ONE fused scatter per round.
+    What a round costs is its gathers, which the chip charges by the
+    index (a word of a 1-D column ~8ns): `avail_slot[cand]` over every
+    cell of [A, k] was 87% of the program while it ran for every row.
+    Only open rows use it and few stay open, so each round compacts the
+    open rows' indices (a cumsum and one scatter) and walks them in
+    steps of PAIR_CHUNK rows, as many steps as hold the round's open
+    rows: the lists' row gather, the availability gather, the count, the
+    cumsum and the pick of the j-th available candidate all run at
+    [PAIR_CHUNK, k], each step scatters its proposals back to its rows,
+    and a closed row keeps the -1 the dense form computed for it (bit
+    for bit the same partners). The rows a round ran at are the smallest
+    of `pair_ladder(A)` that holds its open rows; the count is the
+    device's own, so one executable serves every history of one shape.
+    Acceptance (per-slot
+    min proposer) is one scatter-min and one gather over [A] (a sort +
+    neighbor-compare + un-sort was tried and measured slower), and
+    availability updates batch into ONE fused scatter per round.
 
     Returns (partner i32 [A] — formed-pair target slot on the PROPOSER
     row, -1 elsewhere (each pair reports exactly once); formed i32
-    [1, rounds] — pairs each round formed, the scan's by-product; listed
-    i32 [1] — cells of `cand` that hold a ticket). The two counters are
-    one row each, so that a caller that cuts what it fetches to its rows
-    (tpu._bg_asm) keeps them whole; they cross D2H with the partner
-    vector, end on the cohort's ledger row (tpu.Cohort.list_counts) and
-    change nothing of the rounds.
+    [1, rounds] — pairs each round formed; listed i32 [1] — cells of
+    `cand` that hold a ticket; ran i32 [1, rounds] — rows each round
+    ran its [rows, k] work at). The three counters are one row each, so
+    that a caller that cuts what it fetches to its rows (tpu._bg_asm)
+    keeps them whole; they cross D2H with the partner vector, end on
+    the cohort's ledger row (tpu.Cohort.list_counts) and change nothing
+    of the rounds.
     """
     a = cand.shape[0]
     i32 = jnp.int32
     rows = jnp.arange(a, dtype=i32)
     big = jnp.int32(2**31 - 1)
+    ladder = pair_ladder(a)
+    step, top = ladder[0], ladder[-1]
     valid_row = active_slots >= 0
     slot_of_row = jnp.maximum(active_slots, 0)
     # Pad rows (active_slots == -1) must not scatter: an index of
@@ -898,28 +931,51 @@ def pair_partners(
         .at[jnp.where(valid_row, slot_of_row, cap)]
         .set(rows, mode="drop")
     )
-    cand_safe = jnp.maximum(cand, 0)
-    # 2654435761 (Knuth) wrapped to int32 — jnp int32 math must not see a
-    # Python int above 2^31.
-    row_mix = (_mix(rows * jnp.int32(-1640531527) + 97) & 0x7FFFFFFF).astype(
-        i32
-    )
+
+    def row_mix(ids):
+        # 2654435761 (Knuth) wrapped to int32 — jnp int32 math must not
+        # see a Python int above 2^31.
+        return (
+            _mix(ids * jnp.int32(-1640531527) + 97) & 0x7FFFFFFF
+        ).astype(i32)
 
     def round_fn(state, r):
         avail_slot, partner = state
         # A row is open while it neither formed a pair (partner set) nor
         # had its own slot taken by an accepted proposal.
         row_open = valid_row & (partner < 0) & avail_slot[slot_of_row]
-        cand_ok = (cand >= 0) & avail_slot[cand_safe] & row_open[:, None]
-        navail = jnp.sum(cand_ok, axis=1).astype(i32)
-        has = navail > 0
-        j = jnp.where(
-            has & (r > 0), (row_mix * r) % jnp.maximum(navail, 1), 0
+        # The open rows' indices, in row order, at the head of `open_rows`.
+        pos = jnp.cumsum(row_open.astype(i32)) - 1
+        open_rows = (
+            jnp.full((top,), a, i32)
+            .at[jnp.where(row_open, pos, top)]
+            .set(rows, mode="drop")
         )
-        csum = jnp.cumsum(cand_ok, axis=1)
-        first = jnp.argmax(csum == (j + 1)[:, None], axis=1)
-        prop = jnp.where(has, jnp.take_along_axis(
-            cand, first[:, None], axis=1)[:, 0], -1)
+        steps = (pos[-1] + step) // step  # ceil(open / step)
+
+        def propose(i, prop):
+            # past the last open row the walk holds `a`: no row
+            to = jax.lax.dynamic_slice(open_rows, (i * step,), (step,))
+            live = to < a
+            ids = jnp.minimum(to, a - 1)
+            c = cand[ids]
+            cand_ok = (c >= 0) & avail_slot[jnp.maximum(c, 0)] & live[:, None]
+            navail = jnp.sum(cand_ok, axis=1).astype(i32)
+            has = navail > 0
+            j = jnp.where(
+                has & (r > 0), (row_mix(ids) * r) % jnp.maximum(navail, 1), 0
+            )
+            csum = jnp.cumsum(cand_ok, axis=1)
+            first = jnp.argmax(csum == (j + 1)[:, None], axis=1)
+            p = jnp.where(has, jnp.take_along_axis(
+                c, first[:, None], axis=1)[:, 0], -1)
+            return prop.at[to].set(p, mode="drop")
+
+        # A closed row proposes nothing: the -1 the dense rounds
+        # computed for it from a list with nothing available.
+        prop = jax.lax.fori_loop(
+            0, steps, propose, jnp.full((a,), -1, i32)
+        )
         prop_safe = jnp.maximum(prop, 0)
 
         # Acceptance: oldest proposer (min row index) per slot, one
@@ -951,14 +1007,14 @@ def pair_partners(
             ]
         )
         avail_slot = avail_slot.at[taken].set(False, mode="drop")
-        return (avail_slot, partner), jnp.sum(form, dtype=i32)
+        return (avail_slot, partner), (jnp.sum(form, dtype=i32), steps * step)
 
     init = (
         jnp.ones((cap,), dtype=bool),
         jnp.full((a,), -1, i32),
     )
-    (_, partner), formed = jax.lax.scan(
+    (_, partner), (formed, ran) = jax.lax.scan(
         round_fn, init, jnp.arange(rounds, dtype=i32)
     )
     listed = jnp.count_nonzero(cand >= 0).astype(i32)
-    return partner, formed[None], listed[None]
+    return partner, formed[None], listed[None], ran[None]

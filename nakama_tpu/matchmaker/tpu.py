@@ -242,7 +242,7 @@ class Cohort:
         if pairs is not None:
             # The lists stayed on the device: what `pair_partners`
             # counted there, and what the host's exact re-check left.
-            formed, listed = pairs
+            formed, listed, ran, a_pad = pairs
             total = int(formed.sum())
             out.update(
                 pairs_formed=total,
@@ -250,6 +250,11 @@ class Cohort:
                 pair_rounds_formed=formed.tolist(),
                 pairs_formed_last_round=int(formed[-1]),
                 pair_rounds=len(formed),
+                # rows each round ran its [rows, k] work at, against
+                # what rounds over every row would have
+                pair_round_rows=ran.tolist(),
+                pair_rows_gathered=int(ran.sum()),
+                pair_rows_dense=a_pad * len(ran),
                 candidates_valid=int(listed),
                 candidates_pool=self.pool,
             )
@@ -1515,7 +1520,10 @@ class TpuBackend(ProcessBackend):
         and the pairing's own: `pairs_formed` on the device,
         `pair_rounds_formed` round by round (`pair_rounds` of them,
         `pairs_formed_last_round` the last), `pairs_rejected` by the
-        host's exact re-check or for sharing a session."""
+        host's exact re-check or for sharing a session, and
+        `pair_round_rows`, the rows each round gathered availability
+        for (`pair_rows_gathered` their sum, of `pair_rows_dense` had
+        every round run over every row)."""
         meta = self.meta
         while self._uncounted:
             work = self._uncounted.popleft()
@@ -1945,9 +1953,9 @@ class TpuBackend(ProcessBackend):
         return self._nonpair_count == 0
 
     def _pairs_dispatch(self, cand_dev, slots, a_pad, last, rev):
-        """Propose-accept handshake over (exact-ranked or merged)
+        """Propose-accept handshake over the (one chip's or merged)
         candidate lists; only the partner vector and the handshake's
-        two counters (a row each) cross D2H — the candidate matrix
+        three counters (a row each) cross D2H — the candidate matrix
         (~16MB at 100k) stays on device."""
         import jax.numpy as jnp
 
@@ -2026,8 +2034,11 @@ class TpuBackend(ProcessBackend):
                 out.t_fetched = time.perf_counter()
                 with annotate("cohort.assemble"):
                     if kind == "pairs":
-                        partner, formed, listed = fetched
-                        out.pairs = (formed[0], listed[0])
+                        partner, formed, listed, ran = fetched
+                        out.pairs = (
+                            formed[0], listed[0], ran[0],
+                            dev_arrays[0].shape[0],
+                        )
                         out.asm = self._assemble_pairs(slots, partner, rev)
                     elif kind == "big":
                         # Already exactly ordered by (-score, created)

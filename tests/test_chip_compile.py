@@ -177,17 +177,19 @@ def test_big_kernel_multiqueue_pool_dispatch(one_chip):
 def test_pair_partners(one_chip, cap):
     """The device pairing over a full pool's lists, at the shipped
     capacity and at `multiqueue8x20k`'s (A = cap = 262,144): eight
-    rounds in one scan, three small outputs, temporaries a few hundred
-    MB."""
+    rounds in one scan, each walking its open rows in steps of
+    `PAIR_CHUNK` inside a device loop, three small outputs, temporaries
+    under the dense rounds' few hundred MB."""
     cand = jax.ShapeDtypeStruct(
         (cap, CFG.candidates_per_ticket), jnp.int32, sharding=one_chip
     )
     a = jax.ShapeDtypeStruct((cap,), jnp.int32, sharding=one_chip)
     compiled = device2.pair_partners.lower(cand, a, cap=cap).compile()
-    assert _fits(compiled, False).temp_size_in_bytes < 1e9
-    partner, formed, listed = compiled.out_info
-    assert (partner.shape, formed.shape, listed.shape) == (
-        (cap,), (1, 8), (1,))
+    assert _fits(compiled, False).temp_size_in_bytes < 3e8
+    partner, formed, listed, ran = compiled.out_info
+    assert (partner.shape, formed.shape, listed.shape, ran.shape) == (
+        (cap,), (1, 8), (1,), (1, 8))
+    assert cap // device2.PAIR_CHUNK == len(device2.pair_ladder(cap)) > 1
 
 
 def test_small_exact_kernel(one_chip):

@@ -616,11 +616,16 @@ def test_pair_partners_pad_rows_do_not_clobber_slot0():
         [[1, -1], [0, -1], [0, -1], [-1, -1]], dtype=jnp.int32
     )
     active = jnp.asarray([0, 1, 2, -1], dtype=jnp.int32)
-    partner, formed, listed = pair_partners(cand, active, cap=8, rounds=4)
+    partner, formed, listed, ran = pair_partners(
+        cand, active, cap=8, rounds=4)
     partner = np.asarray(partner)
     proposer = partner >= 0
-    # the counters: one row each, pairs round by round, filled cells
+    # the counters: one row each, pairs round by round, filled cells,
+    # rows each round ran at (four rows: one step, or none once no row
+    # is open)
     assert np.asarray(formed).shape == (1, 4)
+    assert set(np.asarray(ran)[0].tolist()) <= {0, 4}
+    assert np.asarray(ran)[0, 0] == 4
     assert int(np.asarray(formed).sum()) == int(proposer.sum())
     assert np.asarray(listed).tolist() == [3]
     pairs = {

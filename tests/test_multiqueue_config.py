@@ -37,8 +37,9 @@ N = POOLS * 200
 SEED = [3000000030, 0]
 PAIR_COUNTERS = (
     "pairs_formed", "pairs_rejected", "pairs_formed_last_round",
-    "pair_rounds", "candidates_valid", "candidates_pool",
-    "actives_unmatched", "matches_below_max",
+    "pair_rounds", "pair_rows_gathered", "pair_rows_dense",
+    "candidates_valid", "candidates_pool", "actives_unmatched",
+    "matches_below_max",
 )
 # The program's one-tick yield against the unbounded walk's, as a share
 # of the pool. The walk pairs a searcher with the oldest ticket it
@@ -211,7 +212,7 @@ def test_one_tick_yield_is_the_unbounded_walks(one_tick, specs):
 def test_pair_counters_are_on_the_row_and_add_up(one_tick, config):
     matches, row, _, before, work = one_tick
     # written by the idle-gap sweep, never between dispatch and publish
-    assert not set(PAIR_COUNTERS) & set(before)
+    assert not (set(PAIR_COUNTERS) | {"pair_round_rows"}) & set(before)
     assert before["publish_lag_s"] is not None
     assert work.pairs is None  # the sweep let go of what it read
     for key in PAIR_COUNTERS:
@@ -223,6 +224,7 @@ def test_pair_counters_are_on_the_row_and_add_up(one_tick, config):
     assert sum(rounds) == row["pairs_formed"]
     assert rounds[-1] == row["pairs_formed_last_round"]
     assert rounds[0] > rounds[-1]
+    _assert_round_rows_hold_the_open_rows(row, a_pad=2048)
     # whole-number ranks compare exactly on the device: nothing for the
     # host's f64 re-check to throw away
     assert row["pairs_rejected"] == 0
@@ -236,6 +238,26 @@ def test_pair_counters_are_on_the_row_and_add_up(one_tick, config):
     assert row["matches_below_max"] == 0
     assert row["d2h_bytes"] < 5 * 4 * 2048  # a partner vector, no lists
     json.dumps(row)  # the console's matchmaker view sends the row as it is
+
+
+def _assert_round_rows_hold_the_open_rows(row, a_pad):
+    """`pair_round_rows`: the rows each round gathered availability for,
+    a size of the shape's ladder that holds the rows open at the round's
+    start (every ticket here is a row, so a pair closes two), or none
+    once no row is open; its sum and the dense rounds' rows beside it."""
+    from nakama_tpu.matchmaker.device2 import pair_ladder
+
+    ran = row["pair_round_rows"]
+    assert len(ran) == row["pair_rounds"]
+    assert all(isinstance(n, int) for n in ran)
+    opened = row["actives"]
+    for rows, formed in zip(ran, row["pair_rounds_formed"]):
+        assert rows in pair_ladder(a_pad) if opened else rows == 0
+        assert rows >= opened
+        opened -= 2 * formed
+    assert row["pair_rows_gathered"] == sum(ran)
+    assert row["pair_rows_dense"] == a_pad * row["pair_rounds"]
+    assert 0 < row["pair_rows_gathered"] <= row["pair_rows_dense"]
 
 
 def test_a_pair_the_exact_recheck_refuses_is_counted_rejected():
@@ -264,6 +286,9 @@ def test_a_cohort_of_fewer_rows_than_rounds_keeps_every_round():
     assert sum(row["pair_rounds_formed"]) == row["pairs_formed"] == 2
     assert row["pairs_rejected"] == 0 and row["matches"] == len(got) == 2
     assert row["candidates_valid"] == 4  # each lists its one partner
+    # both pairs form in round 0: one step of rows, then none open
+    assert row["pair_round_rows"] == [128] + [0] * 7
+    _assert_round_rows_hold_the_open_rows(row, a_pad=128)
 
 
 # A cohort row's keys at the parent (commit 3bdfc14) for a pool under
@@ -330,7 +355,7 @@ def test_partner_vector_is_bit_for_bit_the_parents(seed, digest, pairs):
     from nakama_tpu.matchmaker.device2 import pair_partners
 
     cand, active = _seeded_lists(seed)
-    partner, formed, listed = pair_partners(
+    partner, formed, listed, _ = pair_partners(
         jnp.asarray(cand), jnp.asarray(active), cap=1024)
     partner = np.asarray(partner)
     assert hashlib.sha256(partner.tobytes()).hexdigest() == digest
@@ -364,7 +389,8 @@ def test_rehearsal_run_of_the_cell_is_correct_and_prints_the_counters(
     metrics = result["metrics"]
     for name in ("candidates_valid_per_active.burst",
                  "unmatched_actives_pct.burst", "pairs_rejected_pct.burst",
-                 "pairs_last_round_pct.burst"):
+                 "pairs_last_round_pct.burst",
+                 "pair_rows_gathered_pct.burst"):
         assert name in metrics, sorted(metrics)
     # a CPU run reports no device metric
     assert not {"pair_device_ms.burst", "pair_roofline",
@@ -373,6 +399,7 @@ def test_rehearsal_run_of_the_cell_is_correct_and_prints_the_counters(
     assert 0.0 <= metrics["unmatched_actives_pct.burst"]["value"] < 10.0
     assert metrics["pairs_rejected_pct.burst"]["value"] == 0.0
     assert 0.0 <= metrics["pairs_last_round_pct.burst"]["value"] < 10.0
+    assert 0.0 < metrics["pair_rows_gathered_pct.burst"]["value"] <= 100.0
     (ticks,) = [ln for ln in lines if ln.get("line") == "ticks"]
     assert ticks["pool"][0] == 1600
     (dispatched,) = [ln for ln in lines if ln.get("line") == "dispatched"]
