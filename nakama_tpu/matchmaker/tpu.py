@@ -110,9 +110,11 @@ class Cohort:
         "seq", "interval_seq", "variant", "actives",
         # the work: dispatched slots, the store generations at dispatch,
         # the worker and what it leaves (`cand`: the fetched candidate
-        # lists, or `pairs`: the device pairing's own counts, either
-        # kept until `list_counts` has read it)
-        "slots", "gen", "thread", "asm", "err", "cand", "pairs", "pool",
+        # lists, or `pairs`: the device pairing's own counts; `walk`:
+        # the native assembler's WALK_COUNTERS sums; each kept until
+        # `list_counts` has read it)
+        "slots", "gen", "thread", "asm", "err", "cand", "pairs", "walk",
+        "pool",
         # stamps, perf_counter seconds (wall twins for trace spans)
         "t_dispatch", "t_dispatch_wall", "t_window_wall", "deadline",
         "t_device_done", "t_fetched", "t_ready", "t_collect", "t_accept",
@@ -134,6 +136,7 @@ class Cohort:
         self.err = None
         self.cand = None
         self.pairs = None
+        self.walk = None
         self.pool = 0  # tickets in the pool at dispatch
         self.t_dispatch = time.perf_counter()
         # Wall-clock twin of t_dispatch: ledger consumers (bench slip
@@ -238,6 +241,11 @@ class Cohort:
                 candidates_distinct=int(seen[:-1].sum()),
                 candidates_pool=self.pool,
             )
+        walk, self.walk = self.walk, None
+        if walk is not None:
+            # What validation did to the assembler's walk (the two
+            # refusal sums are 0 unless the cohort ran under `rev`).
+            out.update(zip(native.WALK_COUNTERS, walk.tolist()))
         pairs, self.pairs = self.pairs, None
         if pairs is not None:
             # The lists stayed on the device: what `pair_partners`
@@ -1512,10 +1520,16 @@ class TpuBackend(ProcessBackend):
         at least one list) of `candidates_pool` (tickets in the pool at
         dispatch) — and what the assembler made of them:
         `actives_unmatched` (searchers in no match), `matches_below_max`
-        (matches smaller than their searcher's max_count). O(actives x
+        (matches smaller than their searcher's max_count), and the
+        four sums the native assembler's call returned for what
+        validation did to its walk (native.WALK_COUNTERS, described
+        there): `hits_walked`, `hits_rev_refused`,
+        `hits_combo_conflicts`, `matches_needing_host`; the middle two
+        are 0 unless the cohort ran under `rev`. O(actives x
         k) numpy, which is why no stage between dispatch and publish
-        pays for it. A pairs cohort's lists never leave the device: it
-        gets the last two, `candidates_valid` and `candidates_pool` as
+        pays for it. A pairs cohort never walks (none of the four) and
+        its lists never leave the device: of the rest it gets the last
+        two, `candidates_valid` and `candidates_pool` as
         `pair_partners` counted them there (no `candidates_distinct`),
         and the pairing's own: `pairs_formed` on the device,
         `pair_rounds_formed` round by round (`pair_rounds` of them,
@@ -2040,15 +2054,17 @@ class TpuBackend(ProcessBackend):
                             dev_arrays[0].shape[0],
                         )
                         out.asm = self._assemble_pairs(slots, partner, rev)
-                    elif kind == "big":
-                        # Already exactly ordered by (-score, created)
-                        # on device; a row slice of the contiguous fetch
-                        # stays C-contiguous.
-                        (out.cand,) = fetched
-                        out.asm = self._assemble(slots, last, out.cand, rev)
                     else:
-                        out.cand = self._order_small(*fetched)
-                        out.asm = self._assemble(slots, last, out.cand, rev)
+                        if kind == "big":
+                            # Already exactly ordered by (-score,
+                            # created) on device; a row slice of the
+                            # contiguous fetch stays C-contiguous.
+                            (out.cand,) = fetched
+                        else:
+                            out.cand = self._order_small(*fetched)
+                        out.asm, out.walk = self._assemble(
+                            slots, last, out.cand, rev
+                        )
             except Exception as e:  # surfaced at collect
                 out.err = e
             finally:
@@ -2076,9 +2092,11 @@ class TpuBackend(ProcessBackend):
         continues with the next hit — matching the reference, whose index
         search never returns non-matching hits. Only matches flagged
         needs_host (host-only member under mutual validation) fall back
-        to the AST check."""
+        to the AST check. Returns the (n_matches, offsets, flat, ok)
+        that accept reads and, beside it, the walk's counters
+        (native.WALK_COUNTERS) for the cohort's ledger row."""
         meta = self.meta
-        n_matches, offsets, flat, needs_host = native.assemble_arrays(
+        n_matches, offsets, flat, needs_host, walk = native.assemble_arrays(
             slots,
             last,
             cand_np,
@@ -2094,7 +2112,7 @@ class TpuBackend(ProcessBackend):
             rev=rev,
         )
         ok = self._validate_flagged(n_matches, offsets, flat, needs_host, rev)
-        return n_matches, offsets, flat, ok
+        return (n_matches, offsets, flat, ok), walk
 
     def _assemble_pairs(self, slots, partner, rev):
         """Host tail of the device-pairing path: exact (f64) validation of

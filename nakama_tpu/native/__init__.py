@@ -212,6 +212,18 @@ def _ptr(arr: np.ndarray, dtype) -> ctypes.c_void_p:
     return arr.ctypes.data_as(ctypes.c_void_p)
 
 
+# What `mm_assemble` sums over a call, in `out_walk`'s order: hits the
+# walk reached (a ticket, not the searcher, not yet in a match), hits
+# whose own query refused the searcher (mutual validation), combos a hit
+# was kept out of by the pairwise query check, matches flagged for the
+# host's AST check. The cohort's ledger row carries them under these
+# names (tpu.Cohort.list_counts).
+WALK_COUNTERS = (
+    "hits_walked", "hits_rev_refused", "hits_combo_conflicts",
+    "matches_needing_host",
+)
+
+
 def assemble_arrays(
     active_slots: np.ndarray,  # i32 [A]
     last_interval: np.ndarray,  # u8 [A]
@@ -227,11 +239,12 @@ def assemble_arrays(
     session_counts: np.ndarray,  # i32 [slots]
     exact: dict,  # TpuBackend.exact mirror arrays (f64/i64/bool by slot)
     rev: bool,
-) -> tuple[int, np.ndarray, np.ndarray, np.ndarray]:
+) -> tuple[int, np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
     """Greedy assembly with in-loop exact match validation; returns
-    (n_matches, offsets i32 [n+1], flat slot array, needs_host u8 [n]) —
-    needs_host marks matches containing members without exact query
-    mirrors under mutual validation (caller AST-validates those)."""
+    (n_matches, offsets i32 [n+1], flat slot array, needs_host u8 [n],
+    walk i64 [4]) — needs_host marks matches containing members without
+    exact query mirrors under mutual validation (caller AST-validates
+    those); walk holds the call's WALK_COUNTERS sums."""
     lib = load()
     a = len(active_slots)
     if a == 0:
@@ -240,6 +253,7 @@ def assemble_arrays(
             np.zeros(1, dtype=np.int32),
             np.zeros(0, dtype=np.int32),
             np.zeros(0, dtype=np.uint8),
+            np.zeros(len(WALK_COUNTERS), dtype=np.int64),
         )
     k = cand.shape[1] if cand.ndim == 2 else 0
     n_slots = len(min_count)
@@ -249,6 +263,7 @@ def assemble_arrays(
     out_offsets = np.zeros(max_matches + 1, dtype=np.int32)
     out_slots = np.zeros(max_slots_out, dtype=np.int32)
     out_needs_host = np.zeros(max_matches, dtype=np.uint8)
+    out_walk = np.zeros(len(WALK_COUNTERS), dtype=np.int64)
     fn = exact["v_num"].shape[1]
     fs = exact["v_str"].shape[1]
     n_should = exact["q_sh_op"].shape[1]
@@ -294,7 +309,8 @@ def assemble_arrays(
         _ptr(out_slots, np.int32),
         ctypes.c_int32(max_slots_out),
         _ptr(out_needs_host, np.uint8),
+        _ptr(out_walk, np.int64),
     )
     if n < 0:
         raise RuntimeError("assembler output buffer overflow")
-    return n, out_offsets, out_slots, out_needs_host
+    return n, out_offsets, out_slots, out_needs_host, out_walk

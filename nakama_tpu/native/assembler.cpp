@@ -174,6 +174,11 @@ extern "C" {
 //   out_needs_host: [max_matches] 1 where a match involved a ticket with
 //                no exact query mirror (host-only member under mutual
 //                validation) — the caller AST-validates those on host.
+//   out_walk:    [4] sums over the call, what validation did to the walk:
+//                hits the walk reached (not -1, not self, not already
+//                selected), hits whose own query refused the searcher
+//                (mutual validation), combos a hit was kept out of by the
+//                pairwise query check, matches flagged in out_needs_host.
 // A return of -1 means the output buffers were too small.
 int32_t mm_assemble(
     // Active rows, already ordered oldest-first.
@@ -197,7 +202,7 @@ int32_t mm_assemble(
     int32_t fs, int32_t n_should, int32_t rev,
     // Outputs.
     int32_t* out_offsets, int32_t max_matches, int32_t* out_slots,
-    int32_t max_slots_out, uint8_t* out_needs_host) {
+    int32_t max_slots_out, uint8_t* out_needs_host, int64_t* out_walk) {
     Pool pool{min_count,      max_count,      count_multiple, count,
               intervals,      created,        session_hashes, session_counts,
               session_stride};
@@ -209,6 +214,8 @@ int32_t mm_assemble(
     std::vector<uint8_t> selected(static_cast<size_t>(n_slots), 0);
     int32_t n_matches = 0;
     int64_t slots_used = 0;
+    int64_t hits_walked = 0, hits_rev_refused = 0, hits_combo_conflicts = 0,
+            matches_needing_host = 0;
     out_offsets[0] = 0;
 
     // Scratch combo storage: combos of ticket slots (entry counts tracked).
@@ -298,6 +305,7 @@ int32_t mm_assemble(
             out_slots[slots_used++] = aslot;
             selected[aslot] = 1;
             out_needs_host[n_matches] = needs_host;
+            matches_needing_host += needs_host;
             ++n_matches;
             out_offsets[n_matches] = static_cast<int32_t>(slots_used);
             combos.erase(combos.begin() + found_idx);
@@ -324,10 +332,13 @@ int32_t mm_assemble(
             int32_t hslot = row[h];
             if (hslot < 0) break;
             if (selected[hslot] || hslot == aslot) continue;
+            ++hits_walked;
             if (a_exact && !ex.accepts(aslot, hslot)) continue;
             if (ex.rev && a_exact && ex.exact_ok[hslot] &&
-                !ex.accepts(hslot, aslot))
+                !ex.accepts(hslot, aslot)) {
+                ++hits_rev_refused;
                 continue;
+            }
             TicketView hit = pool.view(hslot);
             if (sessions_overlap(active, hit)) {
                 tail_placed = false;
@@ -349,8 +360,10 @@ int32_t mm_assemble(
                     combo_entries += pool.count[s];
                     if (sessions_overlap(pool.view(s), hit)) conflict = true;
                     if (!conflict && ex.rev && h_exact && ex.exact_ok[s] &&
-                        (!ex.accepts(s, hslot) || !ex.accepts(hslot, s)))
+                        (!ex.accepts(s, hslot) || !ex.accepts(hslot, s))) {
                         conflict = true;
+                        ++hits_combo_conflicts;
+                    }
                 }
                 if (conflict) continue;
                 if (combo_entries + hit.count + active.count >
@@ -381,6 +394,10 @@ int32_t mm_assemble(
             !tail_attempted)
             try_accept(static_cast<size_t>(tail_combo), true);
     }
+    out_walk[0] = hits_walked;
+    out_walk[1] = hits_rev_refused;
+    out_walk[2] = hits_combo_conflicts;
+    out_walk[3] = matches_needing_host;
     return overflow ? -1 : n_matches;
 }
 }

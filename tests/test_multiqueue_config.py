@@ -292,14 +292,16 @@ def test_a_cohort_of_fewer_rows_than_rounds_keeps_every_round():
 
 
 # A cohort row's keys at the parent (commit 3bdfc14) for a pool under
-# `big_pool_threshold`: exact kernel, native assembler, pipelined.
+# `big_pool_threshold`: exact kernel, native assembler, pipelined; and
+# the assembler's four walk counters, on every assembled row since PR 33.
 SMALL_PATH_ROW_KEYS = {
     "_pc_dispatch", "accept_lag_s", "actives", "actives_unmatched",
     "candidates_distinct", "candidates_pool", "candidates_valid",
     "collect_lag_s", "d2h_bytes", "deliver_remove_s", "delivery_held_s",
     "device_done_lag_s", "device_timeline", "dispatched_ts", "envelopes",
-    "fetch_lag_s", "interval_seq", "matches", "matches_below_max",
-    "publish_gc_collections", "publish_lag_s", "ready_lag_s", "seq",
+    "fetch_lag_s", "hits_combo_conflicts", "hits_rev_refused",
+    "hits_walked", "interval_seq", "matches", "matches_below_max",
+    "matches_needing_host", "publish_gc_collections", "publish_lag_s", "ready_lag_s", "seq",
     "slipped", "status", "trace_id", "ts",
 }
 
