@@ -27,13 +27,13 @@ forbidden compares, count-range, party/self/pool/validity, mutual (rev)
 when on, exact should-boost and embedding scores — then lexicographically
 sorted by (-score, created) on device. Stage-1 false positives die here.
 A candidate is gathered as rows only: its `num`, `str` and `emb` rows
-(and its query mirrors under rev) from the pool's row tables, and its six
-scalar columns (RECORD_KEYS) as one row of a record table built from the
-pool once per dispatch. On a v5e a gather costs by the index, not by the
-word: one word from a 1-D column 7.45 ns, a row of 8 to 64 words 1.8 ns
-(PR 24's and PR 25's traces). Six one-word gathers for each of 16.8
-million candidates were 0.735 s of a 1.14 s pass; the record row is
-0.030 s (PERF.md, PR 25).
+from the pool's row tables, its six scalar columns (RECORD_KEYS) as one
+row of a record built from the pool once per dispatch, and under rev its
+query mirrors on that row and one f32 row beside it. On a v5e a gather
+costs by the index, not by the word: a word from a 1-D column 7.45 ns, a
+row of 6 to 128 words 1.8 ns (PR 24's, PR 25's and PR 34's traces). Six
+one-word gathers for each of 16.8 million candidates were 0.735 s of a
+1.14 s pass; the record row is 0.030 s (PERF.md, PR 25).
 Stage 1's eligibility test is a superset filter, so it never rejects a
 true candidate; its per-block argmax can still drop one, when false
 positives of the same block outrank it (a required string term whose
@@ -73,7 +73,7 @@ MIN_ROW_TILE = 128
 # candidate gather (per kept winner: its record row and a row of every
 # table in _stage2_tables) stays under this many bytes: its temporaries
 # then do not grow with the pool (un-striped, a 131072-row dispatch
-# needs 9 GB of HBM without mutual matching and 32 GB with it).
+# needs 9 GB of HBM without mutual matching and 19 GB with it).
 STAGE2_GATHER_BYTES = 256 << 20
 
 # Every pool field the row (query) side of the kernels reads.
@@ -672,23 +672,18 @@ def _stage2(
     pass runs stripe by stripe (lax.map) with identical output and
     temporaries bounded by the stripe, not by A_pad."""
     a_pad = active_slots.shape[0]
-    keep = min(winners.shape[1], max(2 * k, 8))  # see _stage2_rows
-    # Built once, before the stripes: the loop body only reads it.
-    record = _stage2_record(pool_n)
-    words = record.shape[1] + sum(
-        pool_n[key].shape[1] for key in _stage2_tables(rev)
+    keep = stage2_keep(winners.shape[1], k)
+    stripe = stage2_stripe(
+        a_pad, keep, stage2_words(pool_n, rev, with_should)
     )
-    stripe = a_pad
-    while (
-        stripe % 2 == 0
-        and stripe * keep * words * 4 > STAGE2_GATHER_BYTES
-    ):
-        stripe //= 2
+    # Built once, before the stripes: the loop body only reads them.
+    record = _stage2_record(pool_n, rev, with_should)
+    mirror = _stage2_mirror(pool_n, with_should) if rev else None
 
     def rerank(args):
         rq, act, win = args
         return _stage2_rows(
-            pool_n, record, rq, act, win, k=k, keep=keep, rev=rev,
+            pool_n, record, mirror, rq, act, win, k=k, keep=keep,
             with_should=with_should, with_embedding=with_embedding,
             order_exact=order_exact,
         )
@@ -715,41 +710,170 @@ def _stage2(
 RECORD_KEYS = (
     "min_count", "max_count", "party", "pool_id", "flags", "created",
 )
+# A candidate's QUERY as `_accepts` reads it back against the searcher's
+# values under rev (mutual): the int32 tables ride the record row after
+# the scalars, the f32 tables are a row of their own (_stage2_mirror);
+# the should slots join only when the pool holds should queries.
+MIRROR_KEYS = ("n_lo", "n_hi", "n_flo", "n_fhi", "s_req", "s_forb")
+SHOULD_KEYS = ("sh_op", "sh_fld", "sh_lo", "sh_hi", "sh_term", "sh_boost")
 
 
-def _stage2_tables(rev: bool) -> list[str]:
-    """Pool row tables ([n, w]) the exact checks gather per candidate
-    beside its record — the candidate's VALUES always; its QUERY mirrors
-    only under rev (mutual)."""
-    needed = ["num", "str", "emb"]
-    if rev:
-        needed += [
-            "n_lo", "n_hi", "n_flo", "n_fhi", "s_req", "s_forb",
-            "sh_op", "sh_fld", "sh_lo", "sh_hi", "sh_term", "sh_boost",
-        ]
-    return needed
+def _mirror_keys(with_should: bool) -> tuple[str, ...]:
+    return MIRROR_KEYS + (SHOULD_KEYS if with_should else ())
 
 
-def _stage2_record(pool_n):
-    """int32 [n, len(RECORD_KEYS)]: row j holds slot j's scalars."""
-    return jnp.stack([pool_n[key] for key in RECORD_KEYS], axis=1)
+def _stage2_tables(rev: bool, with_should: bool) -> list[str]:
+    """Pool row tables ([n, w]) whose rows the exact checks read per
+    candidate beside its record — the candidate's VALUES always, each a
+    gather of its own; its QUERY mirrors only under rev (mutual), as
+    spans of the record row and of the `_stage2_mirror` row."""
+    return ["num", "str", "emb"] + (
+        list(_mirror_keys(with_should)) if rev else []
+    )
 
 
-def _stage2_gather(pool_n, record, cand, rev):
+def stage2_keep(out_w: int, k: int) -> int:
+    """Winners a row keeps of stage 1's `out_w` for the exact checks."""
+    return min(out_w, max(2 * k, 8))  # see _stage2_rows
+
+
+def stage2_words(pool, rev: bool, with_should: bool) -> int:
+    """32-bit words stage 2 gathers per kept winner: its record row and
+    a row of every table in `_stage2_tables`, whatever row carries it
+    (`pool`: anything that maps a key to its [n, w] table)."""
+    return len(RECORD_KEYS) + sum(
+        pool[key].shape[1] for key in _stage2_tables(rev, with_should)
+    )
+
+
+def stage2_stripe(a_pad: int, keep: int, words: int) -> int:
+    """Rows of one stage-2 stripe: `a_pad` halved until one stripe's
+    candidate gather fits STAGE2_GATHER_BYTES."""
+    stripe = a_pad
+    while (
+        stripe % 2 == 0
+        and stripe * keep * words * 4 > STAGE2_GATHER_BYTES
+    ):
+        stripe //= 2
+    return stripe
+
+
+def _mirror_split(pool_n, with_should):
+    """The mirror keys by the record that carries them: (int32, f32)."""
+    keys = _mirror_keys(with_should)
+    ints = [key for key in keys if pool_n[key].dtype == jnp.int32]
+    return ints, [key for key in keys if key not in ints]
+
+
+def stage2_shape(pool, a_pad, out_w, k, rev, with_should) -> dict:
+    """The static shape `_stage2` runs at for a dispatch of `a_pad` rows
+    with `out_w` stage-1 winners a row, under the names the dispatch
+    record carries it by (tpu.py: the crumb's `kernel`, the cohort's
+    ledger row): by the same functions, so it cannot drift from the
+    program."""
+    words = stage2_words(pool, rev, with_should)
+    return dict(
+        stage2_words=words,
+        stage2_stripe_rows=stage2_stripe(a_pad, stage2_keep(out_w, k), words),
+    )
+
+
+def stage2_shape_of(variant: dict) -> dict:
+    """`stage2_shape`'s keys of a dispatch record that has them."""
+    return {
+        key: variant[key]
+        for key in ("stage2_words", "stage2_stripe_rows")
+        if key in variant
+    }
+
+
+def _stage2_record(pool_n, rev=False, with_should=False):
+    """int32 [n, W]: row j holds slot j's scalars (RECORD_KEYS) and,
+    under rev, the int32 tables of its query mirror after them."""
+    record = jnp.stack([pool_n[key] for key in RECORD_KEYS], axis=1)
+    if not rev:
+        return record
+    ints, _ = _mirror_split(pool_n, with_should)
+    return jnp.concatenate([record] + [pool_n[key] for key in ints], axis=1)
+
+
+def _stage2_mirror(pool_n, with_should):
+    """f32 [n, W]: row j holds the f32 tables of slot j's query mirror,
+    key after key."""
+    _, floats = _mirror_split(pool_n, with_should)
+    return jnp.concatenate([pool_n[key] for key in floats], axis=1)
+
+
+def _stage2_gather(pool_n, record, mirror, cand, with_should):
     """Everything the exact checks read of the candidates `cand`
     [R, B], by pool key → [R, B, ...]: one row gather per table, the
-    scalars as columns of the gathered record block."""
-    col = {key: pool_n[key][cand] for key in _stage2_tables(rev)}
-    # Fields come out of the gathered block [R, B, 6] along its second
+    scalars as columns of the gathered record block, the query mirrors
+    (`mirror` is None without rev) as spans of the two record blocks
+    and, under "mirror", the two blocks as they were gathered."""
+    col = {key: pool_n[key][cand] for key in _stage2_tables(False, False)}
+    block = record[cand]  # [R, B, W]
+    n_rec = len(RECORD_KEYS)
+    # Scalars come out of the gathered block [R, B, 6] along its second
     # axis: a slice of the last one leaves six [R, B, 1] blocks, each
     # tiled out to the size of the whole record block.
-    rec = jnp.swapaxes(record[cand], 1, 2)  # [R, 6, B]
+    rec = jnp.swapaxes(block[:, :, :n_rec], 1, 2)  # [R, 6, B]
     col.update({key: rec[:, i] for i, key in enumerate(RECORD_KEYS)})
+    if mirror is not None:
+        col["mirror"] = blocks = (block, mirror[cand])
+        spans = zip(_mirror_split(pool_n, with_should), blocks, (n_rec, 0))
+        for keys, rows, at in spans:
+            for key in keys:
+                w = pool_n[key].shape[1]
+                col[key] = rows[:, :, at:at + w]
+                at += w
     return col
 
 
+def _stage2_rev_ok(col, rowq, with_should):
+    """Does each candidate's own query accept its row's ticket (mutual
+    matching)? [R, B]: `_accepts` with the two sides exchanged."""
+    if not with_should:
+        return _mirror_accepts(*col["mirror"], rowq, col["flags"])
+
+    def one_row_rev(colrow, qrow):
+        vals = {key: v[None] for key, v in qrow.items()}
+        ok_r, _ = _accepts(colrow, vals, with_should)  # [1, B]
+        return ok_r[0]
+
+    return jax.vmap(one_row_rev)(col, rowq)
+
+
+def _mirror_accepts(ints, floats, rowq, flags):
+    """`_accepts(candidates' queries, rows' values)` for queries without
+    should slots, read off the gathered record blocks as they lie:
+    `ints` [R, B, 6 + 2 fs] holds scalars | s_req | s_forb, `floats`
+    [R, B, 4 fn] n_lo | n_hi | n_flo | n_fhi, and the row's own `str`
+    and `num` are laid out against them lane for lane. The same
+    comparisons on the same words as `_accepts` makes, AND-reduced over
+    whole blocks: a slice of a block's last axis costs a relayout of the
+    block, six of them cost 0.09 s of a 0.34 s pass (PERF.md, PR 34)."""
+    num, sv = rowq["num"], rowq["str"]  # [R, fn], [R, fs]
+    fn, fs, n_rec = num.shape[1], sv.shape[1], len(RECORD_KEYS)
+    lane = jnp.arange(ints.shape[2], dtype=jnp.int32)
+    own = jnp.concatenate(
+        [jnp.zeros((sv.shape[0], n_rec), sv.dtype), sv, sv], axis=1
+    )[:, None]
+    # (req == 0) | (sv == req) on s_req's lanes, (forb == 0) | (sv != forb)
+    # on s_forb's; the scalars' lanes pass.
+    met = jnp.where(lane >= n_rec + fs, own != ints, own == ints)
+    ok_str = jnp.all((lane < n_rec) | (ints == 0) | met, axis=-1)
+    lane = jnp.arange(4 * fn, dtype=jnp.int32)
+    own = jnp.concatenate([num] * 4, axis=1)[:, None]
+    # num >= n_lo | num <= n_hi | num >= n_flo | num <= n_fhi
+    met = jnp.where((lane // fn) % 2 == 0, own >= floats, own <= floats)
+    ok_num = jnp.all(met | (lane >= 2 * fn), axis=-1) & ~jnp.any(
+        met[:, :, 2 * fn:3 * fn] & met[:, :, 3 * fn:], axis=-1
+    )
+    return ok_str & ok_num & ((flags & FLAG_NEVER) == 0)
+
+
 def _stage2_rows(
-    pool_n, record, rowq, active_slots, winners, *, k, keep, rev,
+    pool_n, record, mirror, rowq, active_slots, winners, *, k, keep,
     with_should, with_embedding, order_exact,
 ):
     """One stripe of `_stage2`: rows [R] against their winners [R, B],
@@ -767,7 +891,9 @@ def _stage2_rows(
     alive = winners != PACKED_NONE
 
     # Gather only what the exact checks read.
-    col = _stage2_gather(pool_n, record, cand, rev)  # [A, B, ...]
+    col = _stage2_gather(
+        pool_n, record, mirror, cand, with_should
+    )  # [A, B, ...]
 
     # Exact per-field predicate, reusing the small-kernel form: _accepts
     # wants fcol [Bc,...] vs qrow [Br,...]; vmap over rows gives
@@ -780,14 +906,8 @@ def _stage2_rows(
     ok, score = jax.vmap(one_row)(col, rowq)
     if not with_should:
         score = jnp.zeros(ok.shape, jnp.float32)
-    if rev:
-
-        def one_row_rev(colrow, qrow):
-            vals = {key: v[None] for key, v in qrow.items()}
-            ok_r, _ = _accepts(colrow, vals, with_should)  # [1, B]
-            return ok_r[0]
-
-        ok = ok & jax.vmap(one_row_rev)(col, rowq)
+    if mirror is not None:  # rev
+        ok = ok & _stage2_rev_ok(col, rowq, with_should)
 
     minmax_ok = (col["min_count"] >= rowq["min_count"][:, None]) & (
         col["max_count"] <= rowq["max_count"][:, None]

@@ -66,7 +66,7 @@ from .device import (
     pad_to,
     topk_candidates,
 )
-from .device2 import MAX_COLS, topk_candidates_big
+from .device2 import MAX_COLS, stage2_shape_of, topk_candidates_big
 from .local import ProcessBackend
 from .process import _mutual, process_default
 from .types import MatchBatch, MatchmakerTicket
@@ -197,7 +197,7 @@ class Cohort:
             actives=self.actives,
             d2h_bytes=self.d2h_bytes,
             matches=self.matches,
-            envelopes=self.envelopes,
+            envelopes=self.envelopes, **stage2_shape_of(self.variant),
         )
         if self.error_stage is not None:
             row["error_stage"] = self.error_stage
@@ -1940,13 +1940,13 @@ class TpuBackend(ProcessBackend):
         big = {}
         if kernel.startswith("topk_candidates_big"):
             from .device2 import stage1_plan
-
-            m, _, bm = stage1_plan(
+            m, out_w, bm = stage1_plan(
                 n=n_cols, n_local=n_local or n_cols, k=self.k, bm=bm,
                 bn=bn, fn=self.fn, fs=self.fs,
                 de=self.d if with_embedding else 8, rev=rev,
             )
-            big = dict(winners_per_block=m)
+            big = _big_variant(
+                self, m, a_pad, n_cols, n_local, out_w, rev, with_should)
         return dict(
             kernel=kernel, a_pad=int(a_pad), n_cols=int(n_cols),
             row_block=bm, col_block=bn, **big, fn=self.fn, fs=self.fs,
@@ -2546,3 +2546,23 @@ class TpuBackend(ProcessBackend):
                 searcher = tickets[-1]
                 ok[i] = all(_mutual(searcher, m) for m in tickets[:-1])
         return ok
+
+
+def _big_variant(backend, m, a_pad, n_cols, n_local, out_w, rev, with_should):
+    """What `_variant` adds for a two-stage program: the winners a column
+    block keeps and stage 2's static shape (`device2.stage2_shape`; on
+    the mesh the rows' winners are the shards' side by side). Down here
+    because a Pallas kernel's compile-cache key carries the line of every
+    frame that called it (`_dispatch`, the warm-up threads, the mesh
+    dispatch): lines added above them cost every two-stage cell a cold
+    compile."""
+    from .device2 import stage2_shape
+
+    winners = n_cols // (n_local or n_cols) * out_w
+    return dict(
+        winners_per_block=m,
+        **stage2_shape(
+            backend.pool.device, int(a_pad), winners, backend.k, rev,
+            with_should,
+        ),
+    )

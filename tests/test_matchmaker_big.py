@@ -3,6 +3,7 @@ CPU oracle, running the Pallas stage-1 in interpreter mode on the virtual
 CPU device. Mirrors the small-kernel parity tier (test_matchmaker_tpu.py)
 at a pool size that exercises the bucket-mask prefilter + exact re-rank."""
 
+import jax
 import numpy as np
 import pytest
 
@@ -211,12 +212,12 @@ def test_big_kernel_output_independent_of_chip_shapes(
         got = topk(pool, slots, grid_lo, grid_inv, bm=16, **kw)
     else:
         # 512 rows x 64 kept winners x 4 B x words -> 8 stripes of 64 rows
-        words = len(device2.RECORD_KEYS) + sum(
-            pool[c].shape[1] for c in device2._stage2_tables(rev)
-        )
+        words = device2.stage2_words(pool, rev, kw["with_should"])
+        assert device2.stage2_stripe(512, 64, words) == 512
         monkeypatch.setattr(
             device2, "STAGE2_GATHER_BYTES", 64 * 64 * 4 * words
         )
+        assert device2.stage2_stripe(512, 64, words) == 64
         topk.clear_cache()
         got = topk(pool, slots, grid_lo, grid_inv, bm=64, **kw)
         topk.clear_cache()
@@ -227,10 +228,11 @@ def test_big_kernel_output_independent_of_chip_shapes(
 @pytest.mark.parametrize("with_should", [False, True])
 @pytest.mark.parametrize("rev", [False, True])
 def test_stage2_record_round_trips(rev, with_should, order_exact, monkeypatch):
-    """A candidate's scalars travel as one record row: what stage 2
-    unpacks for a candidate block is, key by key and bit for bit, what a
-    gather of each pool column gives, and the kernel's output is what it
-    is with those column gathers (the parent's) in the record's place."""
+    """A candidate's scalars travel as one record row and, under rev, its
+    query mirrors as one more: what stage 2 unpacks for a candidate block
+    is, key by key and bit for bit, what a gather of each pool column
+    gives, and the kernel's output is what it is with those column
+    gathers (the parent's) in the records' place."""
     from nakama_tpu.matchmaker import device2
 
     pool, slots, grid_lo, grid_inv, kw = _kernel_inputs(rev)
@@ -238,23 +240,62 @@ def test_stage2_record_round_trips(rev, with_should, order_exact, monkeypatch):
     n = kw["n_cols"]
     rng = np.random.default_rng(8)
     pool = {key: np.array(v[:n]) for key, v in pool.items()}
-    # Words a lossy packing would bend: negative values, the high bit.
-    odd = rng.choice(n, size=3 * 40, replace=False).reshape(3, 40)
+    # Words a lossy packing would bend: negative values, the high bit,
+    # and in the f32 mirrors what a float round trip would not keep.
+    odd = rng.choice(n, size=5 * 40, replace=False).reshape(5, 40)
     pool["created"][odd[0]] = -1 - rng.integers(0, 2**31, 40)
     pool["party"][odd[1]] = -1 - rng.integers(0, 2**31, 40)
     pool["flags"][odd[2]] |= np.int32(-(2**31))
+    bent = np.array(
+        [-0.0, np.inf, -np.inf, np.nan, 1e-45, -1e-40, 1.1754942e-38],
+        np.float32,
+    )
+    for key in ("n_lo", "n_hi", "n_flo", "n_fhi"):
+        pool[key][odd[3]] = rng.choice(bent, size=pool[key][odd[3]].shape)
+    # a NaN with a payload of its own
+    pool["n_lo"][odd[3][0], 0] = np.array([0x7FC12345], np.int32).view(
+        np.float32
+    )[0]
+    for key in ("s_req", "s_forb"):
+        words = pool[key][odd[4]].shape
+        pool[key][odd[4]] = -1 - rng.integers(0, 2**31, words)
+        pool[key][odd[4][:20]] |= np.int32(-(2**31))
 
-    def by_column(pool_n, record, cand, rev):
-        keys = device2._stage2_tables(rev) + list(device2.RECORD_KEYS)
-        return {key: pool_n[key][cand] for key in keys}
+    def by_column(pool_n, record, mirror, cand, with_should):
+        keys = device2._stage2_tables(mirror is not None, with_should)
+        return {
+            key: pool_n[key][cand]
+            for key in keys + list(device2.RECORD_KEYS)
+        }
+
+    def rev_ok_by_accepts(col, rowq, with_should):
+        # the parent's: `_accepts` over the column gathers, sides exchanged
+        def one_row_rev(colrow, qrow):
+            vals = {key: v[None] for key, v in qrow.items()}
+            return device2._accepts(colrow, vals, with_should)[0][0]
+
+        return jax.vmap(one_row_rev)(col, rowq)
 
     cand = rng.integers(0, n, size=(96, 64)).astype(np.int32)
-    cand[0, :40], cand[1, :40], cand[2, :40] = odd
-    got = device2._stage2_gather(
-        pool, device2._stage2_record(pool), cand, rev
-    )
-    want = by_column(pool, None, cand, rev)
+    for i, rows in enumerate(odd):
+        cand[i, :40] = rows
+    mirror = device2._stage2_mirror(pool, with_should) if rev else None
+    record = device2._stage2_record(pool, rev, with_should)
+    # every word the mirrors add rides one of the two rows
+    assert record.shape[1] + (mirror.shape[1] if rev else 0) + sum(
+        pool[key].shape[1] for key in ("num", "str", "emb")
+    ) == device2.stage2_words(pool, rev, with_should)
+    got = device2._stage2_gather(pool, record, mirror, cand, with_should)
+    want = by_column(pool, None, mirror, cand, with_should)
+    if rev:  # the two gathered blocks, as `_mirror_accepts` reads them
+        ints, floats = got.pop("mirror")
+        assert np.array_equal(ints, np.asarray(record)[cand])
+        assert np.asarray(floats).tobytes() == (
+            np.asarray(mirror)[cand].tobytes()
+        )
     assert set(device2.RECORD_KEYS) < set(got) == set(want)
+    assert (set(device2.MIRROR_KEYS) <= set(got)) == rev
+    assert (set(device2.SHOULD_KEYS) <= set(got)) == (rev and with_should)
     for key, block in want.items():
         assert got[key].dtype == block.dtype, key
         assert np.asarray(got[key]).tobytes() == block.tobytes(), key
@@ -263,11 +304,121 @@ def test_stage2_record_round_trips(rev, with_should, order_exact, monkeypatch):
     topk.clear_cache()
     out = np.asarray(topk(pool, slots, grid_lo, grid_inv, bm=64, **kw))
     monkeypatch.setattr(device2, "_stage2_gather", by_column)
+    monkeypatch.setattr(device2, "_stage2_rev_ok", rev_ok_by_accepts)
     topk.clear_cache()
     ref = np.asarray(topk(pool, slots, grid_lo, grid_inv, bm=64, **kw))
     topk.clear_cache()
     assert (ref >= 0).sum() > 1000
     assert np.array_equal(out, ref)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("widths", [(24, 16), (8, 8), (3, 5)])
+def test_mirror_accepts_is_accepts_on_whole_blocks(widths, seed):
+    """The reverse check reads the gathered record blocks lane for lane:
+    on every pair it says what `_accepts` says of the same words —
+    bounds met on the edge and missed by one float, empty and full
+    forbidden intervals, NaN and infinities on either side, required and
+    forbidden terms that are 0, the row's own, another's, negative."""
+    from nakama_tpu.matchmaker import device, device2
+
+    fn, fs = widths
+    rng = np.random.default_rng(seed)
+    r, b = 24, 40
+    edge = np.array(
+        [-np.inf, -3.4e38, -1.0, -0.0, 0.0, 1e-45, 1.0, np.nextafter(
+            np.float32(1.0), np.float32(2.0)), 2.0, 3.4e38, np.inf, np.nan],
+        np.float32,
+    )
+    num = rng.choice(edge, size=(r, fn))
+    words = np.array([0, 1, 2, 7, -5, -(2**31), 2**31 - 1], np.int32)
+    sv = rng.choice(words, size=(r, fs))
+    q = dict(
+        n_lo=rng.choice(edge, size=(r, b, fn)),
+        n_hi=rng.choice(edge, size=(r, b, fn)),
+        n_flo=rng.choice(edge, size=(r, b, fn)),
+        n_fhi=rng.choice(edge, size=(r, b, fn)),
+        s_req=rng.choice(words, size=(r, b, fs)),
+        s_forb=rng.choice(words, size=(r, b, fs)),
+    )
+    # most fields unconstrained, so that whole queries pass too
+    free = rng.random((r, b, fn)) < 1 - 1.0 / fn
+    q["n_lo"][free], q["n_hi"][free] = -np.inf, np.inf
+    free = rng.random((r, b, fn)) < 1 - 1.0 / fn
+    q["n_flo"][free], q["n_fhi"][free] = 1.0, -1.0
+    num[np.isnan(num) & (rng.random(num.shape) < 0.95)] = 0.5
+    for key in ("s_req", "s_forb"):
+        q[key][rng.random((r, b, fs)) < 1 - 0.7 / fs] = 0
+    flags = rng.choice(
+        np.array([1, 3, 1 | device.FLAG_NEVER], np.int32), size=(r, b),
+        p=[0.6, 0.3, 0.1],
+    )
+    scalars = rng.integers(-9, 9, size=(r, b, len(device2.RECORD_KEYS)))
+    ints = np.concatenate(
+        [scalars.astype(np.int32), q["s_req"], q["s_forb"]], axis=2
+    )
+    floats = np.concatenate(
+        [q[key] for key in ("n_lo", "n_hi", "n_flo", "n_fhi")], axis=2
+    )
+    rowq = dict(num=num, str=sv)
+    got = np.asarray(device2._mirror_accepts(ints, floats, rowq, flags))
+
+    def one_row(colrow, qrow):
+        vals = {key: v[None] for key, v in qrow.items()}
+        return device2._accepts(colrow, vals, False)[0][0]
+
+    want = np.asarray(jax.vmap(one_row)(dict(q, flags=flags), rowq))
+    assert got.dtype == want.dtype == bool and got.shape == (r, b)
+    assert 0.02 < want.mean() < 0.9, want.mean()  # both answers are there
+    assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize(
+    "a_pad,words,want",
+    [
+        (131072, 62, 8192),  # the forward program, `ranked100k.burst`
+        (262144, 62, 8192),  # ... at `multiqueue8x20k.burst`'s rows
+        (65536, 62, 8192),  # ... at `squad50k.burst`'s
+        (131072, 190, 2048),  # under rev, `mutual100k.burst`
+        (131072, 286, 1024),  # under rev with should queries
+        (4096, 62, 4096),  # a dispatch smaller than one stripe: itself
+        (1024, 286, 1024),
+    ],
+)
+def test_stage2_stripe_at_shipped_widths(a_pad, words, want):
+    """The stripe rule counts the words the program gathers per kept
+    winner (keep = 128 at k = 64) and no others."""
+    from nakama_tpu.matchmaker import device, device2
+
+    cfg = MatchmakerConfig()
+    keep = device2.stage2_keep(256, cfg.candidates_per_ticket)
+    assert keep == 128
+    assert device2.stage2_stripe(a_pad, keep, words) == want
+    pool = device.pool_schema(
+        8, cfg.numeric_fields, cfg.string_fields, cfg.max_constraints,
+        cfg.embedding_dims,
+    )
+    assert [
+        device2.stage2_words(pool, rev, should)
+        for rev, should in ((False, False), (True, False), (True, True))
+    ] == [62, 190, 286]
+    # without rev the should slots never travel, queries or not
+    assert device2.stage2_words(pool, False, True) == 62
+
+
+def test_stage2_tables_hold_what_the_program_reads():
+    from nakama_tpu.matchmaker import device2
+
+    assert device2._stage2_tables(False, False) == ["num", "str", "emb"]
+    assert device2._stage2_tables(False, True) == ["num", "str", "emb"]
+    lone = device2._stage2_tables(True, False)
+    assert set(device2.MIRROR_KEYS) < set(lone)
+    assert not [key for key in lone if key.startswith("sh_")]
+    both = device2._stage2_tables(True, True)
+    assert both[: len(lone)] == lone
+    assert set(both) - set(lone) == set(device2.SHOULD_KEYS)
+    assert len(device2.SHOULD_KEYS) == 6
+    assert all(key in device2.ROWQ_KEYS for key in both)
 
 
 @pytest.mark.parametrize(

@@ -17,6 +17,7 @@ import subprocess
 import sys
 import threading
 
+import numpy as np
 import pytest
 
 from nakama_tpu import native
@@ -174,6 +175,29 @@ def test_the_walks_counters_are_on_the_row(one_tick):
     json.dumps(row)  # the console's matchmaker view sends the row as it is
 
 
+def test_stage2s_shape_is_on_the_row(one_tick, config):
+    """Every two-stage cohort's row names the stripe its stage 2 ran in
+    and the words it gathered a kept winner, from the functions the
+    program itself calls: under `rev` the record, the values and the six
+    query mirrors (no should slot: the pool holds no should query),
+    without it the record and the values alone."""
+    from nakama_tpu.matchmaker import device2
+
+    rev, (_, row, (k,)) = one_tick
+    assert row["stage2_words"] == k["stage2_words"] == (190 if rev else 62)
+    pool = dict(zip(
+        device2._stage2_tables(rev, False),
+        [np.empty((1, w)) for w in (24, 16, 16) + (24,) * 4 + (16,) * 2],
+    ))
+    assert device2.stage2_words(pool, rev, False) == row["stage2_words"]
+    # the rehearsal's dispatch is smaller than one stripe at either width
+    assert row["stage2_stripe_rows"] == k["stage2_stripe_rows"] == k["a_pad"]
+    keep = device2.stage2_keep(256, config["candidates_per_ticket"])
+    assert device2.stage2_stripe(131072, keep, row["stage2_words"]) == (
+        2048 if rev else 8192
+    )
+
+
 def test_a_pairs_cohort_carries_no_walk_counter():
     """A pool that is all solo 1v1 over `big_pool_threshold` is paired
     on the device: no walk, none of its counters."""
@@ -185,6 +209,7 @@ def test_a_pairs_cohort_carries_no_walk_counter():
         cfg, tickets, big_row_block=128, big_col_block=128)
     assert k["kernel"] == "topk_candidates_big+pair_partners" and k["rev"]
     assert matches and row["pairs_formed"] >= len(matches)
+    assert (row["stage2_words"], row["stage2_stripe_rows"]) == (190, 512)
     assert not set(WALK) & set(row)
 
 
@@ -192,8 +217,6 @@ def test_assemble_arrays_returns_the_four_sums():
     """Straight at the native call: three tickets, one list each. `a`
     (any-region, eu) lists `b` (strict us, which refuses it) and `c`
     (strict eu): under `rev` the walk reaches both and refuses `b`."""
-    import numpy as np
-
     cfg = MatchmakerConfig(pool_capacity=256, max_intervals=2,
                            rev_precision=True)
     backend = TpuBackend(cfg, quiet_logger(), row_block=8, col_block=64)
