@@ -504,66 +504,7 @@ class LocalMatchmaker:
                 await asyncio.sleep(gap)
                 if self._stopped:
                     break
-                # Backpressure: while an unfinished cohort needs the host
-                # (slow D2H fetch, heap-contended assembly), the gap work
-                # is SHED for this gap — GC/drain/flush are deferrable
-                # optimizations, delivery is not, and on a small host
-                # they queue the cohort's worker thread behind seconds of
-                # main-thread work. The streak cap keeps a permanently
-                # slow pipeline from starving heap maintenance forever.
-                if self.backend.pipeline_backlogged() and shed_streak < 2:
-                    shed_streak += 1
-                    if self.metrics is not None:
-                        self.metrics.mm_gap_shed.inc()
-                else:
-                    shed_streak = 0
-                    # Delivered cohorts' candidate-list counters go on
-                    # their ledger rows here, before the drain: they
-                    # read the removed tickets' slots as matched.
-                    try:
-                        self.backend.count_cohorts()
-                    except Exception as e:
-                        self.logger.error("cohort count error", error=str(e))
-                    # Preemptible: stop the teardown pass early rather
-                    # than queue a due cohort delivery behind it. The
-                    # budget is floored at 200ms forward — when the head
-                    # cohort is already past its guard point (chronically
-                    # slow pipeline, forced maintenance gap) the drain
-                    # must still make progress, or the graveyard grows
-                    # until the allocator pays the full teardown inline
-                    # on the add path.
-                    guard_at = self.backend.guard_point()
-                    self.store.drain(
-                        None
-                        if guard_at is None
-                        else max(time.perf_counter() + 0.2, guard_at)
-                    )
-                    gc.collect()
-                    # Idle-gap flush: push ticket rows staged so far so
-                    # the interval's own flush handles only the adds that
-                    # arrive during the remaining sleep (eager 2048-row
-                    # chunking already streams the bulk as adds come in).
-                    try:
-                        self.backend.flush()
-                    except Exception as e:
-                        self.logger.error("gap flush error", error=str(e))
-                    if (
-                        self.checkpointer is not None
-                        and self.checkpointer.due()
-                    ):
-                        # Crash-recovery checkpoint rides the same idle
-                        # gap as GC/drain/flush: pool snapshot + journal
-                        # truncation, bounded replay for the next boot.
-                        # Failure is survivable (WARNed inside) and must
-                        # never kill the interval loop.
-                        try:
-                            await self.checkpointer.maybe_checkpoint(
-                                self
-                            )
-                        except Exception as e:
-                            self.logger.error(
-                                "checkpoint error", error=str(e)
-                            )
+                shed_streak = await self._gap_pass(t0 + gap, shed_streak)
                 # Delivery is NOT this loop's job: the dedicated
                 # delivery stage (spawned alongside, below) wakes on the
                 # cohort-completion event the worker thread fires and
@@ -668,6 +609,90 @@ class LocalMatchmaker:
         self.backend.set_ready_callback(_signal)
         self._task = loop.create_task(_loop())
         self._delivery_task = loop.create_task(_delivery_loop())
+
+    async def _gap_pass(self, due: float, shed_streak: int) -> int:
+        """One pass of the interval loop's idle-gap maintenance, due at
+        perf_counter `due` (where the gap sleep should have ended):
+        candidate-list counters onto the delivered cohorts' rows, the
+        store's graveyard drained, one full collection, the staged rows
+        flushed, the checkpoint when one is due. Returns the new shed
+        streak. Every pass stores a `kind: "gap"` breadcrumb beside the
+        interval crumbs (tracing.py): when the loop woke and how late,
+        the cohorts in flight, whether the pass was shed, and otherwise
+        its stages, each also `mm.gap.<stage>` in a captured profile."""
+        backend = self.backend
+        crumb = self.tracing.open_crumb(kind="gap")
+        crumb["wake_late_s"] = crumb["_pc_start"] - due
+        crumb["in_flight"] = backend.pipeline_depth()
+        cpu_start = time.thread_time()
+
+        def stage(name):
+            return self.tracing.span(
+                crumb, f"{name}_s", f"mm.gap.{name}", backend.annotate
+            )
+
+        try:
+            # Backpressure: while an unfinished cohort needs the host
+            # (slow D2H fetch, heap-contended assembly), the gap work
+            # is SHED for this gap — GC/drain/flush are deferrable
+            # optimizations, delivery is not, and on a small host
+            # they queue the cohort's worker thread behind seconds of
+            # main-thread work. The streak cap keeps a permanently
+            # slow pipeline from starving heap maintenance forever.
+            crumb["shed"] = backend.pipeline_backlogged() and shed_streak < 2
+            if crumb["shed"]:
+                if self.metrics is not None:
+                    self.metrics.mm_gap_shed.inc()
+                return shed_streak + 1
+            # Delivered cohorts' candidate-list counters go on
+            # their ledger rows here, before the drain: they
+            # read the removed tickets' slots as matched.
+            with stage("count"):
+                try:
+                    backend.count_cohorts()
+                except Exception as e:
+                    self.logger.error("cohort count error", error=str(e))
+            # Preemptible: stop the teardown pass early rather
+            # than queue a due cohort delivery behind it. The
+            # budget is floored at 200ms forward — when the head
+            # cohort is already past its guard point (chronically
+            # slow pipeline, forced maintenance gap) the drain
+            # must still make progress, or the graveyard grows
+            # until the allocator pays the full teardown inline
+            # on the add path.
+            with stage("drain"):
+                guard_at = backend.guard_point()
+                self.store.drain(
+                    None
+                    if guard_at is None
+                    else max(time.perf_counter() + 0.2, guard_at)
+                )
+            with stage("gc"):
+                crumb["gc_collected"] = gc.collect()
+            # Idle-gap flush: push ticket rows staged so far so
+            # the interval's own flush handles only the adds that
+            # arrive during the remaining sleep (eager 2048-row
+            # chunking already streams the bulk as adds come in).
+            with stage("flush"):
+                try:
+                    backend.flush()
+                except Exception as e:
+                    self.logger.error("gap flush error", error=str(e))
+            if self.checkpointer is not None and self.checkpointer.due():
+                # Crash-recovery checkpoint rides the same idle
+                # gap as GC/drain/flush: pool snapshot + journal
+                # truncation, bounded replay for the next boot.
+                # Failure is survivable (WARNed inside) and must
+                # never kill the interval loop.
+                with stage("checkpoint"):
+                    try:
+                        await self.checkpointer.maybe_checkpoint(self)
+                    except Exception as e:
+                        self.logger.error("checkpoint error", error=str(e))
+            return 0
+        finally:
+            crumb["cpu_s"] = time.thread_time() - cpu_start
+            self.tracing.record(crumb)
 
     # ------------------------------------------------------------------ add
 
@@ -1002,8 +1027,11 @@ class LocalMatchmaker:
         cohorts journal unpublished. Where the handler keeps publish
         stage sums (`stages`, api/matchmaker_events.py), they are moved
         onto `row`, the ledger row of the delivery call, beside
-        `publish_gc_collections`: the young-generation collections that
-        ran while the handler did."""
+        `publish_gc_collections`, the young-generation collections that
+        ran while the handler did, and the loop thread's CPU account of
+        the handler's call (`tracing.cpu_split`: `publish_cpu_s`,
+        `publish_offcpu_s`, `publish_other_cpu_s`,
+        `publish_invol_switches`, `publish_minor_faults`)."""
         try:
             if faults.fire("delivery.publish"):
                 # drop-mode chaos: delivery intentionally discarded.
@@ -1023,10 +1051,18 @@ class LocalMatchmaker:
             gc_was_enabled = gc.isenabled()
             gc.disable()
             collections = _young_collections()
+            started = trace_api.cpu_stamp()
             try:
                 self.on_matched(batch)
             finally:
                 if row is not None:
+                    # Running or waiting: the loop thread's own CPU
+                    # beside the wall of the handler's call.
+                    row.update(
+                        trace_api.cpu_split(
+                            "publish", started, trace_api.cpu_stamp()
+                        )
+                    )
                     row["publish_gc_collections"] = (
                         _young_collections() - collections
                     )

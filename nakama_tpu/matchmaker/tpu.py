@@ -119,6 +119,8 @@ class Cohort:
         "t_dispatch", "t_dispatch_wall", "t_window_wall", "deadline",
         "t_device_done", "t_fetched", "t_ready", "t_collect", "t_accept",
         "t_publish",
+        # the worker thread's own CPU from t_fetched to t_ready
+        "assemble_cpu_s",
         # what came of it
         "d2h_bytes", "matches", "envelopes", "matched_slots", "slipped",
         "status", "error_stage", "probe", "trace", "entry",
@@ -150,6 +152,7 @@ class Cohort:
         self.deadline = self.t_dispatch + max(1.0, float(interval_sec))
         self.t_device_done = self.t_fetched = self.t_ready = None
         self.t_collect = self.t_accept = self.t_publish = None
+        self.assemble_cpu_s = None
         self.d2h_bytes = 0
         self.matches = self.envelopes = 0
         self.matched_slots = None
@@ -182,6 +185,11 @@ class Cohort:
         """The delivery-ledger row: every stage an unrounded lag since
         dispatch (None where the cohort never got there), under the
         names the console, chip_smoke.py and the benchmark read."""
+        cpu, offcpu = self.assemble_cpu_s, None
+        if cpu is not None:
+            # the assembly's wall less the worker's own CPU: it waited
+            # for the GIL, was descheduled or blocked
+            offcpu = self.t_ready - self.t_fetched - cpu
         row = dict(
             seq=self.seq,
             interval_seq=self.interval_seq,
@@ -189,6 +197,8 @@ class Cohort:
             device_done_lag_s=self.lag(self.t_device_done),
             fetch_lag_s=self.lag(self.t_fetched),
             ready_lag_s=self.lag(self.t_ready),
+            assemble_cpu_s=cpu,
+            assemble_offcpu_s=offcpu,
             collect_lag_s=self.lag(self.t_collect),
             accept_lag_s=self.lag(self.t_accept),
             slipped=self.slipped,
@@ -1503,8 +1513,13 @@ class TpuBackend(ProcessBackend):
         same record as the host stage chain: kernel events between the
         cohort's flush and now (shared-mesh neighbors — leaderboard
         flushes — land here too, which is the point: contention reads
-        off one record)."""
+        off one record), and `gap_in_flight_s`: how long the interval
+        loop's gap passes ran between the cohort's dispatch and now."""
         row = work.row()
+        # Maintenance that ran on the loop while these players waited.
+        row["gap_in_flight_s"] = self.tracing.gap_seconds_since(
+            work.t_dispatch
+        )
         row["device_timeline"] = DEVOBS.timeline_between(
             work.t_window_wall or work.t_dispatch_wall, time.time()
         )
@@ -2046,6 +2061,7 @@ class TpuBackend(ProcessBackend):
                 with annotate("cohort.d2h"):
                     fetched = [_fetch(a)[:n_rows] for a in dev_arrays]
                 out.t_fetched = time.perf_counter()
+                cpu_fetched = time.thread_time()
                 with annotate("cohort.assemble"):
                     if kind == "pairs":
                         partner, formed, listed, ran = fetched
@@ -2065,6 +2081,7 @@ class TpuBackend(ProcessBackend):
                         out.asm, out.walk = self._assemble(
                             slots, last, out.cand, rev
                         )
+                out.assemble_cpu_s = time.thread_time() - cpu_fetched
             except Exception as e:  # surfaced at collect
                 out.err = e
             finally:

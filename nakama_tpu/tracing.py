@@ -14,6 +14,12 @@ layers live here:
      the cohort it dispatched (`cohort_seq`) and the add stages of
      every `mm.add` since the previous interval (`AddStages`: `adds`,
      `add_parse_s`, `add_register_s`, …);
+   - the **gap record**: one breadcrumb of `kind: "gap"` per pass of
+     the interval loop's idle-gap maintenance (`local.py _gap_pass`),
+     among the interval crumbs: when the loop woke for it and how late
+     (`wake_late_s`), whether backpressure shed it, the cohorts in
+     flight, its stages (`count_s`, `drain_s`, `gc_s`, `flush_s`,
+     `checkpoint_s`) and the loop thread's CPU over it (`cpu_s`);
    - the **cohort record**: one delivery-ledger row per pipelined
      cohort, made by `matchmaker.tpu.Cohort.row()` from the stamps the
      cohort carried from dispatch to accept (`device_done_lag_s`,
@@ -26,6 +32,15 @@ layers live here:
      the `publish_*_s` stages and counts of
      `on_matched`, `delivery_held_s`), beside each row's
      `publish_lag_s`.
+
+   Wall time alone cannot tell a stage that ran slowly from one that
+   stood waiting, so the delivery call's handler and the worker's
+   assembly carry the thread's own CPU beside it (`cpu_stamp` /
+   `cpu_split`: `publish_cpu_s`, `publish_offcpu_s`,
+   `publish_other_cpu_s`, `publish_invol_switches`,
+   `publish_minor_faults`; `assemble_cpu_s`, `assemble_offcpu_s`), and
+   a cohort's row says how long gap passes held the loop while it was
+   in flight (`gap_in_flight_s`).
 
    The coarse sites also open a `jax.profiler.TraceAnnotation`
    (`annotate`), so a captured profile shows them on the host lines
@@ -67,6 +82,11 @@ import threading
 import time
 import zlib
 from collections import Counter, OrderedDict, deque
+
+try:  # the calling thread's own switches and faults: Linux
+    from resource import RUSAGE_THREAD, getrusage
+except ImportError:  # pragma: no cover - a platform without it
+    RUSAGE_THREAD = None
 
 # Per-boot salt for the p-sampling hash (see TraceStore._p_sample).
 # Cluster deployments may override it with a fleet-shared secret
@@ -940,6 +960,38 @@ def annotate(name: str):
     return _TRACE_ANNOTATION(name)
 
 
+def cpu_stamp() -> tuple:
+    """The calling thread's account at one point: the wall clock, the
+    thread's own CPU, the whole process's CPU and, where the platform
+    keeps them by the thread, its involuntary context switches and
+    minor page faults. Two of these around a stage, once a cohort,
+    never a match; `cpu_split` makes the row keys."""
+    usage = getrusage(RUSAGE_THREAD) if RUSAGE_THREAD is not None else None
+    return time.perf_counter(), time.thread_time(), time.process_time(), usage
+
+
+def cpu_split(stage: str, before: tuple, after: tuple) -> dict:
+    """Was the thread running or waiting between two `cpu_stamp`s?
+    `<stage>_cpu_s` is its own CPU; `_offcpu_s` the wall less that (it
+    waited for the GIL, was descheduled or blocked); `_other_cpu_s` the
+    CPU the process's other threads burned meanwhile;
+    `_invol_switches` and `_minor_faults` the times it was taken off
+    its core and the pages it touched first (left out where the
+    platform does not count them by the thread)."""
+    wall0, cpu0, process0, usage0 = before
+    wall1, cpu1, process1, usage1 = after
+    cpu = cpu1 - cpu0
+    out = {
+        f"{stage}_cpu_s": cpu,
+        f"{stage}_offcpu_s": wall1 - wall0 - cpu,
+        f"{stage}_other_cpu_s": process1 - process0 - cpu,
+    }
+    if usage1 is not None:
+        out[f"{stage}_invol_switches"] = usage1.ru_nivcsw - usage0.ru_nivcsw
+        out[f"{stage}_minor_faults"] = usage1.ru_minflt - usage0.ru_minflt
+    return out
+
+
 class AddStages:
     """Where `mm.add` and its envelope spent their time since the last
     interval record, summed: `Tracing.record` folds these into the
@@ -1085,11 +1137,12 @@ class Tracing:
         }
 
     @contextlib.contextmanager
-    def span(self, crumb: dict, key: str, annotation: str):
+    def span(self, crumb: dict, key: str, annotation: str, annotate=annotate):
         """Accumulating timing crumb (NOT a request-scoped trace span —
         that is the module-level `span()`): adds elapsed seconds under
         `key` on the aggregate interval breadcrumb, and shows the block
-        as `annotation` in a captured profile."""
+        as `annotation` in a captured profile. A caller that may run on
+        a host-only backend hands in the seam's `backend.annotate`."""
         t0 = time.perf_counter()
         try:
             with annotate(annotation):
@@ -1108,6 +1161,20 @@ class Tracing:
 
     def recent(self, n: int = 32) -> list[dict]:
         return self.breadcrumbs.recent(n)
+
+    def gap_seconds_since(self, since: float) -> float:
+        """Seconds the interval loop's gap passes (crumbs of `kind`
+        "gap", not shed) ran at or after perf_counter `since`: a
+        cohort's `gap_in_flight_s`, asked at its accept with its
+        dispatch stamp. Crumbs are stored as they end, so the walk from
+        the newest stops at the first that ended before `since`."""
+        total = 0.0
+        for crumb in reversed(self.breadcrumbs):
+            if crumb["_pc_end"] <= since:
+                break
+            if crumb.get("kind") == "gap" and crumb.get("shed") is False:
+                total += crumb["_pc_end"] - max(since, crumb["_pc_start"])
+        return total
 
     # -------------------------------------------------- cohort deliveries
 

@@ -177,7 +177,9 @@ async def test_console_accounts_storage_and_ban():
         await server.stop(0)
 
 
-async def _tick_on_device_backend(tickets: int, pipelined: bool):
+async def _tick_on_device_backend(
+    tickets: int, pipelined: bool, interval_sec: int = 15
+):
     """A started server on the device backend (small exact kernel) after
     one `process()` over `tickets` wildcard 1v1 tickets, and its console."""
     from nakama_tpu.matchmaker import MatchmakerPresence
@@ -188,6 +190,7 @@ async def _tick_on_device_backend(tickets: int, pipelined: bool):
     config.matchmaker.pool_capacity = 4096
     config.matchmaker.big_pool_threshold = 1 << 30  # small exact kernel
     config.matchmaker.interval_pipelining = pipelined
+    config.matchmaker.interval_sec = interval_sec
     server = NakamaServer(config, quiet_logger())
     backend = TpuBackend(config.matchmaker, quiet_logger())
     server.matchmaker.backend = backend
@@ -241,6 +244,42 @@ async def test_console_matchmaker_delivery_row_counts_tokens():
         assert row["publish_route_calls"] == 2  # one a match
         assert row["publish_gc_collections"] == 0
         assert row["publish_token_s"] > 0.0
+    finally:
+        await console.close()
+        await server.stop(0)
+
+
+async def test_console_matchmaker_shows_gap_passes_and_the_cpu_split():
+    """The interval loop's gap pass is a `kind: "gap"` crumb among
+    `intervals`, and a delivered cohort's row carries the CPU beside the
+    wall of its delivery call and of its assembly (README "Reading the
+    records", steps 2 and 6)."""
+    server, console = await _tick_on_device_backend(
+        4, pipelined=True, interval_sec=1
+    )
+    try:
+        await console.login()
+        for _ in range(400):
+            status, out = await console.call("GET", "/v2/console/matchmaker")
+            assert status == 200
+            gaps = [c for c in out["intervals"] if c.get("kind") == "gap"]
+            rows = [r for r in out["deliveries"] if "publish_lag_s" in r]
+            if gaps and rows:
+                break
+            await asyncio.sleep(0.05)
+        gap = gaps[0]
+        assert gap["shed"] is False and gap["wake_late_s"] >= 0.0
+        assert gap["_pc_start"] <= gap["_pc_end"]
+        for key in ("count_s", "drain_s", "gc_s", "flush_s", "cpu_s"):
+            assert gap[key] >= 0.0, (key, gap)
+        assert "actives" not in gap and "in_flight" in gap
+        (row,) = rows
+        wall = row["publish_cpu_s"] + row["publish_offcpu_s"]
+        assert 0.0 < wall <= row["publish_lag_s"] - row["accept_lag_s"]
+        for key in ("publish_other_cpu_s", "publish_invol_switches",
+                    "publish_minor_faults", "assemble_cpu_s",
+                    "assemble_offcpu_s", "gap_in_flight_s"):
+            assert key in row, (key, row)
     finally:
         await console.close()
         await server.stop(0)

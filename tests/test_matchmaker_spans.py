@@ -508,14 +508,15 @@ def test_failed_collect_leaves_one_error_row_and_no_half_stamped_one():
 # ------------------------- the keys the benchmark's metric files name
 
 
-def _metric_files():
+def _metric_files(readers=("ledger", "ledger_ratio", "crumb")):
+    """The benchmark's metric files that name one of `readers`."""
     out = []
     for path in sorted(glob.glob(
         os.path.join(ROOT, "benchmark", "layer_metrics", "*.json")
     )):
         with open(path) as f:
             spec = json.load(f)
-        if spec["reader"] in ("ledger", "ledger_ratio", "crumb"):
+        if spec["reader"] in readers:
             out.append(pytest.param(spec, id=os.path.basename(path)[:-5]))
     return out
 

@@ -293,8 +293,13 @@ def test_a_cohort_of_fewer_rows_than_rounds_keeps_every_round():
 
 # A cohort row's keys at the parent (commit 3bdfc14) for a pool under
 # `big_pool_threshold`: exact kernel, native assembler, pipelined; and
-# the assembler's four walk counters, on every assembled row since PR 33.
+# the assembler's four walk counters, on every assembled row since PR 33;
+# since PR 35 the worker's and the delivery call's CPU beside their wall
+# and the gap passes that ran during the flight.
 SMALL_PATH_ROW_KEYS = {
+    "assemble_cpu_s", "assemble_offcpu_s", "gap_in_flight_s",
+    "publish_cpu_s", "publish_offcpu_s", "publish_other_cpu_s",
+    "publish_invol_switches", "publish_minor_faults",
     "_pc_dispatch", "accept_lag_s", "actives", "actives_unmatched",
     "candidates_distinct", "candidates_pool", "candidates_valid",
     "collect_lag_s", "d2h_bytes", "deliver_remove_s", "delivery_held_s",

@@ -311,11 +311,22 @@ def test_rehearsal_run_of_the_cell(bench_copy, planted):
     metrics = result["metrics"]
     # the cell's per-layer metrics that need no device trace
     assert sorted(metrics) == [
+        "assemble_offcpu_ms.burst",
         "assign_ms.burst", "candidates_distinct_pct.burst",
         "candidates_valid_per_active.burst", "fetch_ms.burst",
+        # PR 35: the gap pass's crumb, read by `readers/gap.py`
+        "gap_count_ms.burst", "gap_drain_ms.burst", "gap_gc_ms.burst",
+        "gap_in_flight_ms.burst", "gap_pass_ms.burst", "gap_start_ms.burst",
+        "gap_wake_late_ms.burst",
         "mutual_refused_pct.burst", "process_host_ms.burst",
-        "publish_ms.burst", "unmatched_actives_pct.burst",
+        # PR 35: when the delivery call began, and its CPU beside its wall
+        "publish_begin_ms.burst", "publish_minor_faults.burst",
+        "publish_ms.burst", "publish_offcpu_ms.burst",
+        "publish_other_cpu_ms.burst", "unmatched_actives_pct.burst",
     ]
+    assert metrics["gap_in_flight_ms.burst"]["value"] == 0.0
+    assert metrics["gap_start_ms.burst"]["value"] > (
+        metrics["publish_begin_ms.burst"]["value"])  # the pass came after
     assert 0.0 < metrics["mutual_refused_pct.burst"]["value"] < 100.0
     assert 0.0 < metrics["candidates_valid_per_active.burst"]["value"] <= 64.0
     (dispatched,) = [ln for ln in lines if ln.get("line") == "dispatched"]
