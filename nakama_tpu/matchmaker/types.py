@@ -114,13 +114,17 @@ class MatchBatch:
     straight out of the native assembler; this wrapper exposes them to
     consumers WITHOUT materializing ~100k per-entry Python objects on the
     interval's critical path (the round-2 host floor). It behaves as a
-    sequence of entry lists — ``len``, iteration, indexing — materializing
-    each match's `MatchmakerEntry` list lazily from the slot-indexed
-    ticket array; columnar consumers (metrics, the bench, batched envelope
-    fan-out) read `.offsets` / `.slots` / `.entry_count` directly.
+    sequence of entry lists — ``len``, iteration, indexing — and makes
+    them lazily, in ONE pass over its columns on the first entry access:
+    every match is then a slice of one flat entry list. Columnar
+    consumers (metrics, the bench, batched envelope fan-out) read
+    `.offsets` / `.slots` / `.entry_count` directly.
     """
 
-    __slots__ = ("offsets", "slots", "_tickets", "_counts", "_cache")
+    __slots__ = (
+        "offsets", "slots", "_tickets", "_counts", "_cache", "_flat",
+        "_bounds",
+    )
 
     def __init__(self, offsets, slots, ticket_at=None, counts=None):
         self.offsets = offsets  # i32/i64 [n_matches + 1]
@@ -133,7 +137,13 @@ class MatchBatch:
         # object fancy-index per interval.
         self._tickets = None if ticket_at is None else ticket_at[slots]
         self._counts = None if counts is None else counts[slots]
-        self._cache: dict[int, list[MatchmakerEntry]] = {}
+        # The entry lists themselves, of `from_lists` alone: a columnar
+        # batch keeps no list a match.
+        self._cache: list[list[MatchmakerEntry]] = []
+        # Every match's entries in slot order, and match i's bounds in
+        # them (`_flat[_bounds[i]:_bounds[i + 1]]`); made by `_columns`.
+        self._flat = None
+        self._bounds = None
 
     def bind_tickets(self, tickets_arr):
         """Late-bind the ticket snapshot (aligned with `slots`): either
@@ -148,9 +158,36 @@ class MatchBatch:
         """Adapter for object-path producers (CPU oracle, runtime
         overrides): wraps pre-built entry lists without slot data."""
         batch = cls(None, None, None)
-        batch._cache = dict(enumerate(matched))
-        batch.offsets = None
+        batch._cache = list(matched)
         return batch
+
+    def _snapshot(self):
+        """The ticket snapshot, the store's deferred one resolved."""
+        if callable(self._tickets):
+            self._tickets = self._tickets()  # lazy store snapshot
+        return self._tickets
+
+    def _columns(self):
+        """(flat entries, bounds), built on the first entry access in one
+        pass over the snapshot. Where every ticket holds one entry (a
+        pool of solo tickets) a ticket's slot is its entry's and the
+        bounds are `offsets`; party tickets spread theirs, and the bounds
+        are the running entry count read at `offsets`."""
+        flat = self._flat
+        if flat is None:
+            tickets = self._snapshot().tolist()
+            bounds = self.offsets.tolist()
+            counts = self._counts
+            if counts is not None and (counts == 1).all():
+                flat = [t.entries[0] for t in tickets]
+            else:
+                lists = [t.entries for t in tickets]
+                flat = list(itertools.chain.from_iterable(lists))
+                ends = [0, *itertools.accumulate(map(len, lists))]
+                bounds = [ends[o] for o in bounds]
+            self._bounds = bounds
+            self._flat = flat
+        return flat, self._bounds
 
     def __len__(self) -> int:
         if self.offsets is None:
@@ -158,26 +195,28 @@ class MatchBatch:
         return len(self.offsets) - 1
 
     def __getitem__(self, i: int) -> list["MatchmakerEntry"]:
-        if isinstance(i, slice):
-            return [self[j] for j in range(*i.indices(len(self)))]
+        if self.offsets is None:
+            return self._cache[i]
         n = len(self)
+        if isinstance(i, slice):
+            return [self[j] for j in range(*i.indices(n))]
         if i < 0:
             i += n
         if not 0 <= i < n:
             raise IndexError(i)
-        hit = self._cache.get(i)
-        if hit is None:
-            if callable(self._tickets):
-                self._tickets = self._tickets()  # lazy store snapshot
-            entries: list[MatchmakerEntry] = []
-            for t in self._tickets[self.offsets[i] : self.offsets[i + 1]]:
-                entries.extend(t.entries)
-            self._cache[i] = hit = entries
-        return hit
+        flat, bounds = self._columns()
+        return flat[bounds[i] : bounds[i + 1]]
 
     def __iter__(self):
-        for i in range(len(self)):
-            yield self[i]
+        if self.offsets is None:
+            yield from self._cache
+            return
+        flat, bounds = self._columns()
+        ends = iter(bounds)
+        lo = next(ends)
+        for hi in ends:
+            yield flat[lo:hi]
+            lo = hi
 
     def __bool__(self) -> bool:
         return len(self) > 0
@@ -193,18 +232,16 @@ class MatchBatch:
     def entry_count(self) -> int:
         """Total matched entries, without materializing entry objects."""
         if self.offsets is None:
-            return sum(len(m) for m in self._cache.values())
+            return sum(len(m) for m in self._cache)
         if self._counts is not None:
             return int(self._counts.sum())
-        return sum(len(m) for m in self)
+        return len(self._columns()[0])
 
     def tickets(self, i: int) -> list["MatchmakerTicket"]:
         """The ticket objects of match i (active ticket last)."""
         if self.offsets is None:
             raise ValueError("object-path batch has no slot data")
-        if callable(self._tickets):
-            self._tickets = self._tickets()  # lazy store snapshot
-        return list(self._tickets[self.offsets[i] : self.offsets[i + 1]])
+        return list(self._snapshot()[self.offsets[i] : self.offsets[i + 1]])
 
 
 def freeze_ticket(t: MatchmakerTicket) -> tuple:

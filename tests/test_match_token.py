@@ -16,6 +16,7 @@ import os
 import time
 import uuid
 
+import numpy as np
 import pytest
 
 from fixtures import FakeSession, quiet_logger
@@ -25,7 +26,11 @@ from nakama_tpu.api.matchmaker_events import make_matched_handler
 from nakama_tpu.api.pipeline import Components, Pipeline, PipelineError
 from nakama_tpu.config import Config
 from nakama_tpu.matchmaker import MatchmakerPresence
-from nakama_tpu.matchmaker.types import MatchBatch, MatchmakerEntry
+from nakama_tpu.matchmaker.types import (
+    MatchBatch,
+    MatchmakerEntry,
+    MatchmakerTicket,
+)
 from nakama_tpu.realtime import (
     LocalMessageRouter,
     LocalSessionRegistry,
@@ -72,6 +77,32 @@ def _match(k, size, name="n"):
         )
         for j in range(size)
     ]
+
+
+def _columnar(matches, per_ticket=1):
+    """The matches as the interval path hands them: columns over a
+    snapshot of tickets, `per_ticket` entries to a ticket (1: a pool of
+    solo tickets; more: party tickets, a match's last one the shorter),
+    the snapshot deferred to the first entry access."""
+    tickets, offsets = [], [0]
+    for entries in matches:
+        for at in range(0, len(entries), per_ticket):
+            own = entries[at:at + per_ticket]
+            tickets.append(MatchmakerTicket(
+                ticket=own[0].ticket, query="*", min_count=2, max_count=10,
+                count_multiple=1, session_id="", party_id="", entries=own,
+                string_properties={}, numeric_properties={}, created_at=0.0,
+            ))
+        offsets.append(len(tickets))
+    ticket_at = np.empty(len(tickets), dtype=object)
+    ticket_at[:] = tickets
+    slots = np.arange(len(tickets), dtype=np.int32)
+    batch = MatchBatch(
+        np.array(offsets, dtype=np.int64), slots,
+        counts=np.array([len(t.entries) for t in tickets], dtype=np.int32),
+    )
+    batch.bind_tickets(lambda: ticket_at[slots])
+    return batch
 
 
 def _user_list(entries):
@@ -247,14 +278,25 @@ def test_expiry_is_read_for_each_match_not_once_a_batch(monkeypatch):
 # ------------------------------------------------------------- the batch
 
 
-@pytest.mark.parametrize("columnar", [False, True], ids=["list", "batch"])
-def test_two_batches_of_ten_thousand_share_no_id_and_no_token(columnar):
+HANDED_AS = {
+    "list": list,
+    "batch": MatchBatch.from_lists,
+    "columns": _columnar,
+    "party_columns": lambda matches: _columnar(matches, per_ticket=3),
+}
+
+
+@pytest.mark.parametrize("handed_as", HANDED_AS)
+def test_two_batches_of_ten_thousand_share_no_id_and_no_token(handed_as):
     handler, router = _handler()
     for call in range(2):
         matches = [_match(10_000 * call + k, 2) for k in range(10_000)]
-        handler(MatchBatch.from_lists(matches) if columnar else matches)
+        handler(HANDED_AS[handed_as](matches))
         assert handler.stages["publish_tokens"] == 10_000 * (call + 1)
     assert handler.stages["publish_matches"] == 20_000
+    assert handler.stages["publish_bulk_matches"] == (
+        20_000 if "columns" in handed_as else 0
+    )
     assert len(router.sent) == 40_000
     tokens, tids, mids = set(), set(), set()
     for (_, first), (_, second) in zip(router.sent[::2], router.sent[1::2]):
@@ -283,6 +325,87 @@ def test_entropy_is_read_once_a_batch_and_never_kept(monkeypatch):
     handler([_match(k, 4) for k in range(100, 103)])
     handler(MatchBatch.from_lists([_match(200, 4)]))
     assert reads == [3200, 96, 32]
+
+
+@pytest.mark.parametrize("n", [0, 1, 2, 257])
+def test_uuid4_texts_are_canonical_version_4_from_one_read(monkeypatch, n):
+    reads = []
+    real = os.urandom
+
+    def urandom(size):
+        reads.append(size)
+        return real(size)
+
+    monkeypatch.setattr(matchmaker_events.os, "urandom", urandom)
+    texts = matchmaker_events._uuid4_texts(n)
+    assert reads == [16 * n]  # one read, 122 random bits an id of it
+    assert len(texts) == len(set(texts)) == n
+    for text in texts:
+        assert [len(part) for part in text.split("-")] == [8, 4, 4, 4, 12]
+        parsed = uuid.UUID(text)
+        assert str(parsed) == text  # lower case, dashes where they belong
+        assert parsed.version == 4 and parsed.variant == uuid.RFC_4122
+
+
+@pytest.mark.parametrize("n", [1, 3, 64])
+def test_uuid4_texts_are_uuid4s_of_the_same_bytes(monkeypatch, n):
+    entropy = bytes((37 * i + 11) % 256 for i in range(16 * n))
+    monkeypatch.setattr(matchmaker_events.os, "urandom", lambda size: entropy)
+    assert matchmaker_events._uuid4_texts(n) == [
+        str(uuid.UUID(bytes=entropy[i:i + 16], version=4))
+        for i in range(0, 16 * n, 16)
+    ]
+
+
+@pytest.mark.parametrize("per_ticket", [1, 3], ids=["solo", "party"])
+@pytest.mark.parametrize("size", SIZES)
+def test_columns_give_the_envelopes_and_tokens_the_lists_give(
+    monkeypatch, size, per_ticket
+):
+    """Byte for byte: ids pinned by the entropy, expiry by the clock."""
+    _pin(monkeypatch, bytes(range(256)))
+    matches = [_match(k, size, NAMES["non_ascii"]) for k in range(9)]
+    as_lists, lists_router = _handler()
+    as_lists(matches)
+    as_columns, columns_router = _handler()
+    as_columns(_columnar(matches, per_ticket))
+    assert len(lists_router.sent) == 9 * size
+    assert columns_router.sent == lists_router.sent
+    assert json.dumps(columns_router.sent) == json.dumps(lists_router.sent)
+    for key in ("publish_matches", "publish_envelopes", "publish_tokens",
+                "publish_route_calls"):
+        assert as_columns.stages[key] == as_lists.stages[key] > 0
+    assert as_columns.stages["publish_bulk_matches"] == 9
+    assert as_lists.stages["publish_bulk_matches"] == 0
+
+
+@pytest.mark.parametrize("handed_as", HANDED_AS)
+def test_the_account_is_on_stages_when_the_router_raises(handed_as):
+    class Raises(_Router):
+        def send_envelopes(self, recipients):
+            if len(self.sent) == 3 * 4:
+                raise OSError("router down")
+            super().send_envelopes(recipients)
+
+    router = Raises()
+    handler = make_matched_handler(quiet_logger(), router, "n1", KEY)
+    with pytest.raises(OSError, match="router down"):
+        handler(HANDED_AS[handed_as]([_match(k, 4) for k in range(10)]))
+    stages = handler.stages
+    # The three matches that were routed, not the one that was not.
+    assert stages["publish_matches"] == stages["publish_route_calls"] == 3
+    assert stages["publish_envelopes"] == 12
+    assert stages["publish_tokens"] == 4  # counted when minted
+    assert stages["publish_bulk_matches"] == (
+        3 if "columns" in handed_as else 0
+    )
+    for key in ("publish_materialise_s", "publish_hook_s", "publish_token_s",
+                "publish_envelope_s", "publish_route_s"):
+        assert stages[key] > 0.0
+    # and the next batch adds to them
+    router.sent.append(None)
+    handler([_match(99, 4)])
+    assert stages["publish_matches"] == 4
 
 
 class _Runtime:

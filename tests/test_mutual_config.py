@@ -320,10 +320,13 @@ def test_rehearsal_run_of_the_cell(bench_copy, planted):
         "gap_wake_late_ms.burst",
         "mutual_refused_pct.burst", "process_host_ms.burst",
         # PR 35: when the delivery call began, and its CPU beside its wall
-        "publish_begin_ms.burst", "publish_minor_faults.burst",
+        "publish_begin_ms.burst",
+        # PR 36: the matches served as slices of the batch's flat list
+        "publish_bulk_pct.burst", "publish_minor_faults.burst",
         "publish_ms.burst", "publish_offcpu_ms.burst",
         "publish_other_cpu_ms.burst", "unmatched_actives_pct.burst",
     ]
+    assert metrics["publish_bulk_pct.burst"]["value"] == 100.0
     assert metrics["gap_in_flight_ms.burst"]["value"] == 0.0
     assert metrics["gap_start_ms.burst"]["value"] > (
         metrics["publish_begin_ms.burst"]["value"])  # the pass came after
