@@ -26,14 +26,13 @@ bench size) are gathered and re-checked *exactly* — full interval/term/
 forbidden compares, count-range, party/self/pool/validity, mutual (rev)
 when on, exact should-boost and embedding scores — then lexicographically
 sorted by (-score, created) on device. Stage-1 false positives die here.
-A candidate is gathered as rows only: its `num`, `str` and `emb` rows
-from the pool's row tables, its six scalar columns (RECORD_KEYS) as one
-row of a record built from the pool once per dispatch, and under rev its
-query mirrors on that row and one f32 row beside it. On a v5e a gather
-costs by the index, not by the word: a word from a 1-D column 7.45 ns, a
-row of 6 to 128 words 1.8 ns (PR 24's, PR 25's and PR 34's traces). Six
-one-word gathers for each of 16.8 million candidates were 0.735 s of a
-1.14 s pass; the record row is 0.030 s (PERF.md, PR 25).
+A candidate is gathered as ONE row of a table built from the pool once
+per dispatch (its scalars, values and embedding: `_stage2_tables`), two
+under rev (its query mirrors beside them), and checked on whole rows. On
+a v5e a gather costs by the index, not by the word: a word from a 1-D
+column 7.45 ns, a row of 6 to 128 words 1.8 ns (the traces in PERF.md).
+Six one-word gathers for each of 16.8 million candidates were 0.735 s of
+a 1.14 s pass; one row of 6 to 128 words is 0.030 s.
 Stage 1's eligibility test is a superset filter, so it never rejects a
 true candidate; its per-block argmax can still drop one, when false
 positives of the same block outrank it. Required string words are no
@@ -54,6 +53,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -74,8 +74,8 @@ PACKED_NONE = -(2**31)  # plain int: pallas kernels must not capture arrays
 VMEM_LIMIT_BYTES = 16 << 20
 MIN_ROW_TILE = 128
 # Stage 2 re-ranks the active rows in stripes sized so one stripe's
-# candidate gather (per kept winner: its record row and a row of every
-# table in _stage2_tables) stays under this many bytes: its temporaries
+# candidate gather (per kept winner: a row of every table of
+# _stage2_tables) stays under this many bytes: its temporaries
 # then do not grow with the pool (un-striped, a 131072-row dispatch
 # needs 9 GB of HBM without mutual matching and 19 GB with it).
 STAGE2_GATHER_BYTES = 256 << 20
@@ -736,16 +736,16 @@ def _stage2(
     a_pad = active_slots.shape[0]
     keep = stage2_keep(winners.shape[1], k)
     stripe = stage2_stripe(
-        a_pad, keep, stage2_words(pool_n, rev, with_should)
+        a_pad, keep, stage2_words(pool_n, rev, with_should, with_embedding)
     )
     # Built once, before the stripes: the loop body only reads them.
-    record = _stage2_record(pool_n, rev, with_should)
-    mirror = _stage2_mirror(pool_n, with_should) if rev else None
+    layout = _stage2_tables(pool_n, rev, with_should, with_embedding)
+    tables = _stage2_record(pool_n, layout)
 
     def rerank(args):
         rq, act, win = args
         return _stage2_rows(
-            pool_n, record, mirror, rq, act, win, k=k, keep=keep,
+            tables, layout, rq, act, win, k=k, keep=keep, rev=rev,
             with_should=with_should, with_embedding=with_embedding,
             order_exact=order_exact,
         )
@@ -767,31 +767,49 @@ def _stage2(
     return out.reshape(a_pad, k)
 
 
-# A candidate's scalar columns, in the order its record row holds them
-# (no padding: rows of 6, 8 and 16 words gather in the same time).
+# A candidate's scalar columns, in the order its rows hold them.
 RECORD_KEYS = (
     "min_count", "max_count", "party", "pool_id", "flags", "created",
 )
 # A candidate's QUERY as `_accepts` reads it back against the searcher's
-# values under rev (mutual): the int32 tables ride the record row after
-# the scalars, the f32 tables are a row of their own (_stage2_mirror);
-# the should slots join only when the pool holds should queries.
+# values under rev (mutual); the should slots join only when the pool
+# holds should queries.
 MIRROR_KEYS = ("n_lo", "n_hi", "n_flo", "n_fhi", "s_req", "s_forb")
 SHOULD_KEYS = ("sh_op", "sh_fld", "sh_lo", "sh_hi", "sh_term", "sh_boost")
 
 
-def _mirror_keys(with_should: bool) -> tuple[str, ...]:
-    return MIRROR_KEYS + (SHOULD_KEYS if with_should else ())
+def _stage2_tables(pool, rev, with_should, with_embedding):
+    """The rows stage 2 gathers a kept winner by, each as the pool keys
+    it holds in lane order (`pool`: anything that maps a key to its
+    table). ONE row on the forward programs: the embedding, the scalars
+    and the values (62 words at shipped widths, 46 without the
+    embedding). TWO under rev: the scalars, the int32 query mirrors and
+    the values (78); the embedding and the f32 mirrors (112). On a v5e a
+    row gather costs by the index, not the word, from 6 to 128 words, the
+    widest measured. The embedding leads its row: its score reads its
+    lanes by a slice at lane 0, which moves no word. Pools
+    with should queries keep a row per table beside a record of the
+    scalars and, under rev, of the query mirrors split by dtype:
+    `_accepts` takes their should slots by field from `num` and `str`."""
+    emb = ("emb",) if with_embedding else ()
+    if not with_should:
+        if not rev:
+            return (emb + RECORD_KEYS + ("num", "str"),)
+        return (
+            RECORD_KEYS + ("s_req", "s_forb", "num", "str"),
+            emb + ("n_lo", "n_hi", "n_flo", "n_fhi"),
+        )
+    mirror = MIRROR_KEYS + SHOULD_KEYS if rev else ()
+    ints = tuple(key for key in mirror if pool[key].dtype == jnp.int32)
+    floats = tuple(key for key in mirror if key not in ints)
+    rows = (RECORD_KEYS + ints, floats, ("num",), ("str",), emb)
+    return tuple(row for row in rows if row)
 
 
-def _stage2_tables(rev: bool, with_should: bool) -> list[str]:
-    """Pool row tables ([n, w]) whose rows the exact checks read per
-    candidate beside its record — the candidate's VALUES always, each a
-    gather of its own; its QUERY mirrors only under rev (mutual), as
-    spans of the record row and of the `_stage2_mirror` row."""
-    return ["num", "str", "emb"] + (
-        list(_mirror_keys(with_should)) if rev else []
-    )
+def _width(pool, key) -> int:
+    """Words a row of `pool[key]` holds: 1 for a scalar column."""
+    shape = pool[key].shape
+    return shape[1] if len(shape) > 1 else 1
 
 
 def stage2_keep(out_w: int, k: int) -> int:
@@ -799,12 +817,13 @@ def stage2_keep(out_w: int, k: int) -> int:
     return min(out_w, max(2 * k, 8))  # see _stage2_rows
 
 
-def stage2_words(pool, rev: bool, with_should: bool) -> int:
-    """32-bit words stage 2 gathers per kept winner: its record row and
-    a row of every table in `_stage2_tables`, whatever row carries it
-    (`pool`: anything that maps a key to its [n, w] table)."""
-    return len(RECORD_KEYS) + sum(
-        pool[key].shape[1] for key in _stage2_tables(rev, with_should)
+def stage2_words(pool, rev, with_should, with_embedding) -> int:
+    """32-bit words stage 2 gathers per kept winner: every row of
+    `_stage2_tables`, whatever its dtype."""
+    return sum(
+        _width(pool, key)
+        for row in _stage2_tables(pool, rev, with_should, with_embedding)
+        for key in row
     )
 
 
@@ -820,21 +839,19 @@ def stage2_stripe(a_pad: int, keep: int, words: int) -> int:
     return stripe
 
 
-def _mirror_split(pool_n, with_should):
-    """The mirror keys by the record that carries them: (int32, f32)."""
-    keys = _mirror_keys(with_should)
-    ints = [key for key in keys if pool_n[key].dtype == jnp.int32]
-    return ints, [key for key in keys if key not in ints]
-
-
-def stage2_shape(pool, a_pad, out_w, k, rev, with_should) -> dict:
+def stage2_shape(
+    pool, a_pad, out_w, k, rev, with_should, with_embedding
+) -> dict:
     """The static shape `_stage2` runs at for a dispatch of `a_pad` rows
     with `out_w` stage-1 winners a row, under the names the dispatch
     record carries it by (tpu.py: the crumb's `kernel`, the cohort's
     ledger row): by the same functions, so it cannot drift from the
-    program."""
-    words = stage2_words(pool, rev, with_should)
+    program. `stage2_gathers` is the row gathers a kept winner costs."""
+    words = stage2_words(pool, rev, with_should, with_embedding)
     return dict(
+        stage2_gathers=len(
+            _stage2_tables(pool, rev, with_should, with_embedding)
+        ),
         stage2_words=words,
         stage2_stripe_rows=stage2_stripe(a_pad, stage2_keep(out_w, k), words),
     )
@@ -844,58 +861,93 @@ def stage2_shape_of(variant: dict) -> dict:
     """`stage2_shape`'s keys of a dispatch record that has them."""
     return {
         key: variant[key]
-        for key in ("stage2_words", "stage2_stripe_rows")
+        for key in ("stage2_gathers", "stage2_words", "stage2_stripe_rows")
         if key in variant
     }
 
 
-def _stage2_record(pool_n, rev=False, with_should=False):
-    """int32 [n, W]: row j holds slot j's scalars (RECORD_KEYS) and,
-    under rev, the int32 tables of its query mirror after them."""
-    record = jnp.stack([pool_n[key] for key in RECORD_KEYS], axis=1)
-    if not rev:
-        return record
-    ints, _ = _mirror_split(pool_n, with_should)
-    return jnp.concatenate([record] + [pool_n[key] for key in ints], axis=1)
+def _stage2_record(pool_n, layout):
+    """The tables of `layout`, built once per dispatch, [n, W] each: a
+    row of one key is the pool's own table; a row of several is f32
+    where every key is f32, else int32 with the f32 keys bitcast (a
+    bitcast round trip keeps every word: NaN payloads, -0.0, denormals)."""
+    tables = []
+    for keys in layout:
+        cols = [pool_n[key].reshape(pool_n[key].shape[0], -1) for key in keys]
+        if len(cols) == 1:
+            tables.append(cols[0])
+            continue
+        same = all(col.dtype == cols[0].dtype for col in cols)
+        dtype = cols[0].dtype if same else jnp.int32
+        tables.append(jnp.concatenate(
+            [jax.lax.bitcast_convert_type(col, dtype) for col in cols],
+            axis=1,
+        ))
+    return tables
 
 
-def _stage2_mirror(pool_n, with_should):
-    """f32 [n, W]: row j holds the f32 tables of slot j's query mirror,
-    key after key."""
-    _, floats = _mirror_split(pool_n, with_should)
-    return jnp.concatenate([pool_n[key] for key in floats], axis=1)
-
-
-def _stage2_gather(pool_n, record, mirror, cand, with_should):
-    """Everything the exact checks read of the candidates `cand`
-    [R, B], by pool key → [R, B, ...]: one row gather per table, the
-    scalars as columns of the gathered record block, the query mirrors
-    (`mirror` is None without rev) as spans of the two record blocks
-    and, under "mirror", the two blocks as they were gathered."""
-    col = {key: pool_n[key][cand] for key in _stage2_tables(False, False)}
-    block = record[cand]  # [R, B, W]
-    n_rec = len(RECORD_KEYS)
-    # Scalars come out of the gathered block [R, B, 6] along its second
-    # axis: a slice of the last one leaves six [R, B, 1] blocks, each
-    # tiled out to the size of the whole record block.
-    rec = jnp.swapaxes(block[:, :, :n_rec], 1, 2)  # [R, 6, B]
-    col.update({key: rec[:, i] for i, key in enumerate(RECORD_KEYS)})
-    if mirror is not None:
-        col["mirror"] = blocks = (block, mirror[cand])
-        spans = zip(_mirror_split(pool_n, with_should), blocks, (n_rec, 0))
-        for keys, rows, at in spans:
-            for key in keys:
-                w = pool_n[key].shape[1]
-                col[key] = rows[:, :, at:at + w]
-                at += w
+def _stage2_gather(layout, blocks, like):
+    """The gathered rows `blocks` ([R, B, W], in `layout`'s order) by
+    pool key → [R, B, ...] in the key's dtype (`like` maps a key to an
+    array of its rank and dtype): the column form `_accepts` reads on
+    pools with should queries. The scalars come out along a block's
+    second axis: a slice of the last one leaves [R, B, 1] blocks, each
+    tiled out to the size of the whole block."""
+    col = {}
+    for keys, block in zip(layout, blocks):
+        at = np.cumsum([0] + [_width(like, key) for key in keys])
+        scalars = [i for i, key in enumerate(keys) if like[key].ndim == 1]
+        if scalars:
+            lo, hi = at[scalars[0]], at[scalars[-1]] + 1
+            rec = jnp.swapaxes(block[:, :, lo:hi], 1, 2)  # [R, n, B]
+        for i, key in enumerate(keys):
+            if i in scalars:
+                part = rec[:, at[i] - lo]
+            elif len(keys) == 1:
+                part = block
+            else:
+                part = block[:, :, at[i]:at[i + 1]]
+            col[key] = jax.lax.bitcast_convert_type(part, like[key].dtype)
     return col
+
+
+def _stage2_by_column(col, rowq, rev, with_should, with_embedding):
+    """(ok, score, created) [R, B] of candidates in column form: the
+    small kernel's `_accepts` (vmapped over rows: fcol = [B, ...] per
+    row against that row's query broadcast as Br = 1), under rev also
+    with the two sides exchanged, and the scalars' tests."""
+
+    def one_row(colrow, qrow):
+        q1 = {key: v[None] for key, v in qrow.items()}
+        ok, score = _accepts(q1, colrow, with_should)  # [B, 1]
+        return ok[:, 0], (score[:, 0] if with_should else jnp.zeros(()))
+
+    ok, score = jax.vmap(one_row)(col, rowq)
+    if not with_should:
+        score = jnp.zeros(ok.shape, jnp.float32)
+    if rev:
+        ok = ok & _stage2_rev_ok(col, rowq, with_should)
+    minmax_ok = (col["min_count"] >= rowq["min_count"][:, None]) & (
+        col["max_count"] <= rowq["max_count"][:, None]
+    )
+    party_ok = (rowq["party"][:, None] == 0) | (
+        col["party"] != rowq["party"][:, None]
+    )
+    pool_ok = col["pool_id"] == rowq["pool_id"][:, None]
+    col_valid = (col["flags"] & FLAG_VALID) != 0
+    if with_embedding:
+        score = score + jnp.einsum(
+            "abd,ad->ab",
+            col["emb"].astype(jnp.bfloat16),
+            rowq["emb"].astype(jnp.bfloat16),
+        ).astype(jnp.float32)
+    ok = ok & minmax_ok & party_ok & pool_ok & col_valid
+    return ok, score, col["created"]
 
 
 def _stage2_rev_ok(col, rowq, with_should):
     """Does each candidate's own query accept its row's ticket (mutual
     matching)? [R, B]: `_accepts` with the two sides exchanged."""
-    if not with_should:
-        return _mirror_accepts(*col["mirror"], rowq, col["flags"])
 
     def one_row_rev(colrow, qrow):
         vals = {key: v[None] for key, v in qrow.items()}
@@ -905,37 +957,102 @@ def _stage2_rev_ok(col, rowq, with_should):
     return jax.vmap(one_row_rev)(col, rowq)
 
 
-def _mirror_accepts(ints, floats, rowq, flags):
-    """`_accepts(candidates' queries, rows' values)` for queries without
-    should slots, read off the gathered record blocks as they lie:
-    `ints` [R, B, 6 + 2 fs] holds scalars | s_req | s_forb, `floats`
-    [R, B, 4 fn] n_lo | n_hi | n_flo | n_fhi, and the row's own `str`
-    and `num` are laid out against them lane for lane. The same
-    comparisons on the same words as `_accepts` makes, AND-reduced over
-    whole blocks: a slice of a block's last axis costs a relayout of the
-    block, six of them cost 0.09 s of a 0.34 s pass (PERF.md, PR 34)."""
-    num, sv = rowq["num"], rowq["str"]  # [R, fn], [R, fs]
-    fn, fs, n_rec = num.shape[1], sv.shape[1], len(RECORD_KEYS)
-    lane = jnp.arange(ints.shape[2], dtype=jnp.int32)
-    own = jnp.concatenate(
-        [jnp.zeros((sv.shape[0], n_rec), sv.dtype), sv, sv], axis=1
-    )[:, None]
-    # (req == 0) | (sv == req) on s_req's lanes, (forb == 0) | (sv != forb)
-    # on s_forb's; the scalars' lanes pass.
-    met = jnp.where(lane >= n_rec + fs, own != ints, own == ints)
-    ok_str = jnp.all((lane < n_rec) | (ints == 0) | met, axis=-1)
-    lane = jnp.arange(4 * fn, dtype=jnp.int32)
-    own = jnp.concatenate([num] * 4, axis=1)[:, None]
-    # num >= n_lo | num <= n_hi | num >= n_flo | num <= n_fhi
-    met = jnp.where((lane // fn) % 2 == 0, own >= floats, own <= floats)
-    ok_num = jnp.all(met | (lane >= 2 * fn), axis=-1) & ~jnp.any(
-        met[:, :, 2 * fn:3 * fn] & met[:, :, 3 * fn:], axis=-1
-    )
-    return ok_str & ok_num & ((flags & FLAG_NEVER) == 0)
+def _stage2_on_rows(layout, blocks, rowq, rev):
+    """`_stage2_by_column`'s (ok, score, created) for pools without
+    should queries, read off the gathered rows as they lie. The row's
+    own words are laid out lane for lane against each block ([R, 1, W],
+    0 on the lanes a test does not read); each test is the comparison
+    `_accepts` or the scalars' tests make, on the same words, kept to
+    its lanes by a mask; the block is AND-reduced whole, and `created`
+    is a sum masked to its lane. No slice of a gathered block's last axis
+    but the embedding's, at lane 0: one elsewhere costs a relayout of the
+    block (0.10 + 0.05 s of a 0.34 s pass on a v5e; PERF.md)."""
+    r = rowq["flags"].shape[0]
+    ok = ((rowq["flags"] & FLAG_NEVER) == 0)[:, None]
+    score = jnp.zeros(blocks[0].shape[:2], jnp.float32)
+    flag_mask = FLAG_VALID | (FLAG_NEVER if rev else 0)
+    for keys, block in zip(layout, blocks):
+        widths = [_width(rowq, key) for key in keys]
+        lane = np.repeat(np.array(keys), widths)  # each lane's key
+
+        def on(*names):
+            return np.isin(lane, names)
+
+        def laid(own, dtype):
+            return jnp.concatenate([
+                own[key].reshape(r, w).astype(dtype) if key in own
+                else jnp.zeros((r, w), dtype)
+                for key, w in zip(keys, widths)
+            ], axis=1)[:, None]
+
+        bits = jax.lax.bitcast_convert_type(block, jnp.int32)
+        flt = jax.lax.bitcast_convert_type(block, jnp.float32)
+        own = laid(dict(
+            {key: rowq[key] for key in RECORD_KEYS[:4]},
+            str=rowq["s_req"], s_req=rowq["str"], s_forb=rowq["str"],
+        ), jnp.int32)
+        forb = laid({"str": rowq["s_forb"]}, jnp.int32)
+
+        def num_ok():  # the candidate's value against the row's query
+            lo, hi, flo, fhi = (
+                laid({"num": rowq[key]}, jnp.float32)
+                for key in MIRROR_KEYS[:4]
+            )
+            return (flt >= lo) & (flt <= hi) & ~((flt >= flo) & (flt <= fhi))
+
+        if "n_lo" in keys:  # the row's value against the candidate's
+            # query: num >= n_lo | num <= n_hi | num >= n_flo | num <= n_fhi
+            mine = laid(dict.fromkeys(MIRROR_KEYS[:4], rowq["num"]),
+                        jnp.float32)
+            met = jnp.where(on("n_lo", "n_flo"), mine >= flt, mine <= flt)
+
+        tests = (
+            (("min_count",), lambda: bits >= own),
+            (("max_count",), lambda: bits <= own),
+            (("party",), lambda: (own == 0) | (bits != own)),
+            (("pool_id",), lambda: bits == own),
+            (("flags",), lambda: (bits & flag_mask) == FLAG_VALID),
+            (("num",), num_ok),
+            (("str",), lambda: ((own == 0) | (bits == own))
+             & ((forb == 0) | (bits != forb))),
+            (("s_req",), lambda: (bits == 0) | (bits == own)),
+            (("s_forb",), lambda: (bits == 0) | (bits != own)),
+            (("n_lo", "n_hi"), lambda: met),
+        )
+        lane_ok = True
+        for names, test in tests:
+            if set(names) & set(keys):
+                lane_ok = jnp.where(on(*names), test(), lane_ok)
+        ok = ok & jnp.all(lane_ok, axis=-1)
+        if "n_flo" in keys:  # ~(num >= n_flo & num <= n_fhi), by field
+            fn = _width(rowq, "num")
+            flo, fhi = (
+                sum(widths[:keys.index(key)]) for key in ("n_flo", "n_fhi")
+            )
+            ok = ok & ~jnp.any(
+                met[:, :, flo:flo + fn] & met[:, :, fhi:fhi + fn], axis=-1
+            )
+        if "created" in keys:
+            created = jnp.sum(jnp.where(on("created"), bits, 0), axis=-1)
+        if "emb" in keys:
+            # `_stage2_by_column`'s einsum on the same words, summed as
+            # the chip sums it there: over a bf16 block of the embedding
+            # alone, held apart by the barrier (fused into a pass over the
+            # whole row, a v5e adds the products in another order and the
+            # candidates' order moves). The embedding leads its row.
+            at = sum(widths[:keys.index("emb")])
+            emb = block[:, :, at:at + _width(rowq, "emb")]
+            emb = jax.lax.optimization_barrier(
+                jax.lax.bitcast_convert_type(emb, jnp.float32).astype(
+                    jnp.bfloat16))
+            score = jnp.einsum(
+                "abd,ad->ab", emb, rowq["emb"].astype(jnp.bfloat16),
+            ).astype(jnp.float32)
+    return ok, score, created
 
 
 def _stage2_rows(
-    pool_n, record, mirror, rowq, active_slots, winners, *, k, keep,
+    tables, layout, rowq, active_slots, winners, *, k, keep, rev,
     with_should, with_embedding, order_exact,
 ):
     """One stripe of `_stage2`: rows [R] against their winners [R, B],
@@ -952,53 +1069,25 @@ def _stage2_rows(
     cand = winners & (MAX_COLS - 1)  # [A, B]
     alive = winners != PACKED_NONE
 
-    # Gather only what the exact checks read.
-    col = _stage2_gather(
-        pool_n, record, mirror, cand, with_should
-    )  # [A, B, ...]
-
-    # Exact per-field predicate, reusing the small-kernel form: _accepts
-    # wants fcol [Bc,...] vs qrow [Br,...]; vmap over rows gives
-    # fcol=[B,...] per row vs that row's query broadcast as Br=1.
-    def one_row(colrow, qrow):
-        q1 = {key: v[None] for key, v in qrow.items()}
-        ok, score = _accepts(q1, colrow, with_should)  # [B, 1]
-        return ok[:, 0], (score[:, 0] if with_should else jnp.zeros(()))
-
-    ok, score = jax.vmap(one_row)(col, rowq)
-    if not with_should:
-        score = jnp.zeros(ok.shape, jnp.float32)
-    if mirror is not None:  # rev
-        ok = ok & _stage2_rev_ok(col, rowq, with_should)
-
-    minmax_ok = (col["min_count"] >= rowq["min_count"][:, None]) & (
-        col["max_count"] <= rowq["max_count"][:, None]
-    )
-    party_ok = (rowq["party"][:, None] == 0) | (
-        col["party"] != rowq["party"][:, None]
-    )
-    pool_ok = col["pool_id"] == rowq["pool_id"][:, None]
-    col_valid = (col["flags"] & FLAG_VALID) != 0
+    # One gather a row of the layout: only what the exact checks read.
+    blocks = [table[cand] for table in tables]  # [A, B, W] each
+    if with_should:
+        ok, score, created = _stage2_by_column(
+            _stage2_gather(layout, blocks, rowq), rowq, rev, with_should,
+            with_embedding,
+        )
+    else:
+        ok, score, created = _stage2_on_rows(layout, blocks, rowq, rev)
     not_self = cand != jnp.maximum(active_slots, 0)[:, None]
     row_live = (active_slots >= 0)[:, None]
-
-    eligible = (
-        ok & alive & minmax_ok & party_ok & pool_ok & col_valid & not_self
-        & row_live
-    )
-    if with_embedding:
-        score = score + jnp.einsum(
-            "abd,ad->ab",
-            col["emb"].astype(jnp.bfloat16),
-            rowq["emb"].astype(jnp.bfloat16),
-        ).astype(jnp.float32)
+    eligible = ok & alive & not_self & row_live
 
     # Truncate K' -> k by the stage-1 selection priority (jitter/score),
     # NOT by age: truncating oldest-first would re-concentrate every row's
     # list onto the same old tickets and resurrect assembler starvation.
     neg_prio = jnp.where(eligible, -winners, jnp.int32(2**31 - 1))
     neg_score = jnp.where(eligible, -score, jnp.inf)
-    created = jnp.where(eligible, col["created"], jnp.int32(2**31 - 1))
+    created = jnp.where(eligible, created, jnp.int32(2**31 - 1))
     slot = jnp.where(eligible, cand, jnp.int32(2**31 - 1))
     _, s_k, c_k, slot_k = jax.lax.sort(
         (neg_prio, neg_score, created, slot), dimension=1, num_keys=1

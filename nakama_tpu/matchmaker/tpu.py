@@ -1978,7 +1978,7 @@ class TpuBackend(ProcessBackend):
             )
             big = _big_variant(
                 self, m, a_pad, n_cols, n_local, out_w, rev, with_should,
-                str_cols)
+                with_embedding, str_cols)
         return dict(
             kernel=kernel, a_pad=int(a_pad), n_cols=int(n_cols),
             row_block=bm, col_block=bn, **big, fn=self.fn, fs=self.fs,
@@ -2587,7 +2587,7 @@ class TpuBackend(ProcessBackend):
 
 
 def _big_variant(backend, m, a_pad, n_cols, n_local, out_w, rev, with_should,
-                 str_cols):
+                 with_embedding, str_cols):
     """What `_variant` adds for a two-stage program: the winners a column
     block keeps, how many string columns stage 1 compares exactly, and
     stage 2's static shape (`device2.stage2_shape`; on
@@ -2604,7 +2604,7 @@ def _big_variant(backend, m, a_pad, n_cols, n_local, out_w, rev, with_should,
         str_exact_fields=len(str_cols),
         **stage2_shape(
             backend.pool.device, int(a_pad), winners, backend.k, rev,
-            with_should,
+            with_should, with_embedding,
         ),
     )
 

@@ -12,6 +12,8 @@ worker imports this file), compiles happen in this process, and all of
 them live in this one file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -142,6 +144,49 @@ def test_big_kernel_full_pool_dispatch(one_chip, emb_rev):
         and "slice_sizes={1}" in line
     ]
     assert not one_word, one_word
+
+
+@pytest.mark.parametrize("cell", ["ranked100k", "mutual100k"])
+def test_stage2_gathers_a_candidate_as_rows(one_chip, cell):
+    """Stage 2 at `ranked100k.burst`'s shape (131,072 rows against
+    114,688 columns, the embedding) and under rev at `mutual100k.burst`'s
+    (with stage 1's exact word test): it fits and lowers, its stripe loop
+    gathers one candidate row forward and two under rev, as
+    `stage2_gathers` says, and copies no gathered block to another
+    layout (a slice of a block's last axis compiles to such a copy)."""
+    rev = cell == "mutual100k"
+    n_cols = 112 * 1024
+    compiled = _compile_big(
+        one_chip, CAP, n_cols, True, rev, str_cols=(2,) if rev else ())
+    loop = [
+        line.strip() for line in compiled.as_text().splitlines()
+        if "while/body" in line
+    ]
+    rows = [line for line in loop
+            if " gather(" in line and "offset_dims={2}" in line]
+    shape = device2.stage2_shape(
+        _pool(one_chip), CAP, 256, CFG.candidates_per_ticket, rev, False,
+        True)
+    assert len(rows) == shape["stage2_gathers"] == (2 if rev else 1)
+    stripe, keep = shape["stage2_stripe_rows"], 128
+    assert stripe == (2048 if rev else 8192)
+    block = f"[{stripe},{keep},"
+    relayouts = [line[:160] for line in loop
+                 if " copy(" in line and block in line.split("=")[1][:40]]
+    assert not relayouts, relayouts
+    # The embedding score is the column form's einsum on a bf16 block of
+    # the embedding alone, as the chip sums it there: fed the gathered
+    # row itself, the sum ran in another order and the candidates' order
+    # moved.
+    text = compiled.as_text()
+    emb = f"bf16[{stripe},{keep},{CFG.embedding_dims}]"
+    sums = [
+        body for body in re.split(r"\n}\n", text)
+        if re.search(rf"= bf16\[{stripe},{keep}\]\S* reduce\(", body)
+    ]
+    assert len(sums) == 1, len(sums)
+    params = re.findall(r"= (\w+\[[\d,]+\])\S* parameter\(", sums[0])
+    assert emb in params, params
 
 
 @pytest.mark.parametrize("rows", [1 << 16, 2048])

@@ -212,7 +212,9 @@ def test_big_kernel_output_independent_of_chip_shapes(
         got = topk(pool, slots, grid_lo, grid_inv, bm=16, **kw)
     else:
         # 512 rows x 64 kept winners x 4 B x words -> 8 stripes of 64 rows
-        words = device2.stage2_words(pool, rev, kw["with_should"])
+        words = device2.stage2_words(
+            pool, rev, kw["with_should"], kw["with_embedding"]
+        )
         assert device2.stage2_stripe(512, 64, words) == 512
         monkeypatch.setattr(
             device2, "STAGE2_GATHER_BYTES", 64 * 64 * 4 * words
@@ -224,25 +226,32 @@ def test_big_kernel_output_independent_of_chip_shapes(
     assert np.array_equal(base, np.asarray(got))
 
 
+@pytest.mark.parametrize("with_embedding", [True, False])
 @pytest.mark.parametrize("order_exact", [True, False])
 @pytest.mark.parametrize("with_should", [False, True])
 @pytest.mark.parametrize("rev", [False, True])
-def test_stage2_record_round_trips(rev, with_should, order_exact, monkeypatch):
-    """A candidate's scalars travel as one record row and, under rev, its
-    query mirrors as one more: what stage 2 unpacks for a candidate block
-    is, key by key and bit for bit, what a gather of each pool column
-    gives, and the kernel's output is what it is with those column
-    gathers (the parent's) in the records' place."""
+def test_stage2_record_round_trips(
+    rev, with_should, order_exact, with_embedding, monkeypatch
+):
+    """A candidate travels as the rows of `_stage2_tables`: one (two
+    under rev) without should queries, a row per table beside a record
+    with them. What stage 2 unpacks of a candidate block is, key by key
+    and bit for bit, what a gather of each pool column gives, and the
+    kernel's output is what it is with a gather per pool column and the
+    column form's checks (`_accepts`, the parent's) in the rows' place."""
     from nakama_tpu.matchmaker import device2
 
     pool, slots, grid_lo, grid_inv, kw = _kernel_inputs(rev)
-    kw.update(with_should=with_should, order_exact=order_exact)
+    kw.update(
+        with_should=with_should, order_exact=order_exact,
+        with_embedding=with_embedding,
+    )
     n = kw["n_cols"]
     rng = np.random.default_rng(8)
     pool = {key: np.array(v[:n]) for key, v in pool.items()}
     # Words a lossy packing would bend: negative values, the high bit,
-    # and in the f32 mirrors what a float round trip would not keep.
-    odd = rng.choice(n, size=5 * 40, replace=False).reshape(5, 40)
+    # and in the f32 tables what a float round trip would not keep.
+    odd = rng.choice(n, size=6 * 40, replace=False).reshape(6, 40)
     pool["created"][odd[0]] = -1 - rng.integers(0, 2**31, 40)
     pool["party"][odd[1]] = -1 - rng.integers(0, 2**31, 40)
     pool["flags"][odd[2]] |= np.int32(-(2**31))
@@ -252,48 +261,42 @@ def test_stage2_record_round_trips(rev, with_should, order_exact, monkeypatch):
     )
     for key in ("n_lo", "n_hi", "n_flo", "n_fhi"):
         pool[key][odd[3]] = rng.choice(bent, size=pool[key][odd[3]].shape)
-    # a NaN with a payload of its own
-    pool["n_lo"][odd[3][0], 0] = np.array([0x7FC12345], np.int32).view(
-        np.float32
-    )[0]
+    # a NaN with a payload of its own, in a bound and in a value
+    payload = np.array([0x7FC12345], np.int32).view(np.float32)[0]
+    pool["n_lo"][odd[3][0], 0] = payload
     for key in ("s_req", "s_forb"):
         words = pool[key][odd[4]].shape
         pool[key][odd[4]] = -1 - rng.integers(0, 2**31, words)
         pool[key][odd[4][:20]] |= np.int32(-(2**31))
-
-    def by_column(pool_n, record, mirror, cand, with_should):
-        keys = device2._stage2_tables(mirror is not None, with_should)
-        return {
-            key: pool_n[key][cand]
-            for key in keys + list(device2.RECORD_KEYS)
-        }
-
-    def rev_ok_by_accepts(col, rowq, with_should):
-        # the parent's: `_accepts` over the column gathers, sides exchanged
-        def one_row_rev(colrow, qrow):
-            vals = {key: v[None] for key, v in qrow.items()}
-            return device2._accepts(colrow, vals, with_should)[0][0]
-
-        return jax.vmap(one_row_rev)(col, rowq)
+    # the values ride an int32 row beside the scalars: bend them too
+    pool["num"][odd[5]] = rng.choice(bent, size=pool["num"][odd[5]].shape)
+    pool["num"][odd[5][0], 1] = payload
+    pool["emb"][odd[5][1:]] = rng.choice(
+        np.array([-0.0, 1e-45, -1e-40, 1.1754942e-38], np.float32),
+        size=pool["emb"][odd[5][1:]].shape,
+    )
 
     cand = rng.integers(0, n, size=(96, 64)).astype(np.int32)
     for i, rows in enumerate(odd):
         cand[i, :40] = rows
-    mirror = device2._stage2_mirror(pool, with_should) if rev else None
-    record = device2._stage2_record(pool, rev, with_should)
-    # every word the mirrors add rides one of the two rows
-    assert record.shape[1] + (mirror.shape[1] if rev else 0) + sum(
-        pool[key].shape[1] for key in ("num", "str", "emb")
-    ) == device2.stage2_words(pool, rev, with_should)
-    got = device2._stage2_gather(pool, record, mirror, cand, with_should)
-    want = by_column(pool, None, mirror, cand, with_should)
-    if rev:  # the two gathered blocks, as `_mirror_accepts` reads them
-        ints, floats = got.pop("mirror")
-        assert np.array_equal(ints, np.asarray(record)[cand])
-        assert np.asarray(floats).tobytes() == (
-            np.asarray(mirror)[cand].tobytes()
-        )
+    layout = device2._stage2_tables(pool, rev, with_should, with_embedding)
+    tables = device2._stage2_record(pool, layout)
+    if not with_should:
+        assert len(layout) == 1 + rev
+    else:  # the record (and its f32 mirror), `num`, `str`, `emb`
+        assert len(layout) == 3 + rev + with_embedding
+    assert [t.shape[1] for t in tables] == [
+        sum(pool[key].reshape(n, -1).shape[1] for key in row)
+        for row in layout
+    ]
+    assert sum(t.shape[1] for t in tables) == device2.stage2_words(
+        pool, rev, with_should, with_embedding
+    )
+    blocks = [np.asarray(t)[cand] for t in tables]
+    got = device2._stage2_gather(layout, blocks, pool)
+    want = {key: pool[key][cand] for row in layout for key in row}
     assert set(device2.RECORD_KEYS) < set(got) == set(want)
+    assert ("emb" in got) == with_embedding
     assert (set(device2.MIRROR_KEYS) <= set(got)) == rev
     assert (set(device2.SHOULD_KEYS) <= set(got)) == (rev and with_should)
     for key, block in want.items():
@@ -303,8 +306,18 @@ def test_stage2_record_round_trips(rev, with_should, order_exact, monkeypatch):
     topk = device2.topk_candidates_big
     topk.clear_cache()
     out = np.asarray(topk(pool, slots, grid_lo, grid_inv, bm=64, **kw))
-    monkeypatch.setattr(device2, "_stage2_gather", by_column)
-    monkeypatch.setattr(device2, "_stage2_rev_ok", rev_ok_by_accepts)
+
+    def by_column(pool_, rev_, with_should_, with_embedding_):
+        return tuple((key,) for row in layout for key in row)
+
+    def checks_by_column(layout_, blocks_, rowq, rev_):
+        col = device2._stage2_gather(layout_, blocks_, rowq)
+        return device2._stage2_by_column(
+            col, rowq, rev_, False, with_embedding
+        )
+
+    monkeypatch.setattr(device2, "_stage2_tables", by_column)
+    monkeypatch.setattr(device2, "_stage2_on_rows", checks_by_column)
     topk.clear_cache()
     ref = np.asarray(topk(pool, slots, grid_lo, grid_inv, bm=64, **kw))
     topk.clear_cache()
@@ -312,65 +325,76 @@ def test_stage2_record_round_trips(rev, with_should, order_exact, monkeypatch):
     assert np.array_equal(out, ref)
 
 
+@pytest.mark.parametrize("rev", [False, True])
 @pytest.mark.parametrize("seed", [0, 1, 2])
 @pytest.mark.parametrize("widths", [(24, 16), (8, 8), (3, 5)])
-def test_mirror_accepts_is_accepts_on_whole_blocks(widths, seed):
-    """The reverse check reads the gathered record blocks lane for lane:
-    on every pair it says what `_accepts` says of the same words —
-    bounds met on the edge and missed by one float, empty and full
-    forbidden intervals, NaN and infinities on either side, required and
-    forbidden terms that are 0, the row's own, another's, negative."""
+def test_mirror_accepts_is_accepts_on_whole_blocks(widths, seed, rev):
+    """Stage 2 reads the gathered rows lane for lane: on every pair its
+    checks say what `_accepts` (both ways under rev) and the scalars'
+    tests say of the same words — bounds met on the edge and missed by
+    one float, empty and full forbidden intervals, NaN and infinities on
+    either side, required and forbidden terms that are 0, the row's own,
+    another's, negative, counts, parties, pools and flags on and off
+    their edges — and `created` and the embedding score are the column
+    form's, bit for bit."""
     from nakama_tpu.matchmaker import device, device2
 
     fn, fs = widths
     rng = np.random.default_rng(seed)
-    r, b = 24, 40
+    n, r, b = 96, 24, 40
     edge = np.array(
         [-np.inf, -3.4e38, -1.0, -0.0, 0.0, 1e-45, 1.0, np.nextafter(
             np.float32(1.0), np.float32(2.0)), 2.0, 3.4e38, np.inf, np.nan],
         np.float32,
     )
-    num = rng.choice(edge, size=(r, fn))
     words = np.array([0, 1, 2, 7, -5, -(2**31), 2**31 - 1], np.int32)
-    sv = rng.choice(words, size=(r, fs))
-    q = dict(
-        n_lo=rng.choice(edge, size=(r, b, fn)),
-        n_hi=rng.choice(edge, size=(r, b, fn)),
-        n_flo=rng.choice(edge, size=(r, b, fn)),
-        n_fhi=rng.choice(edge, size=(r, b, fn)),
-        s_req=rng.choice(words, size=(r, b, fs)),
-        s_forb=rng.choice(words, size=(r, b, fs)),
+    pool = dict(
+        num=rng.choice(edge, size=(n, fn)),
+        str=rng.choice(words, size=(n, fs)),
+        n_lo=rng.choice(edge, size=(n, fn)),
+        n_hi=rng.choice(edge, size=(n, fn)),
+        n_flo=rng.choice(edge, size=(n, fn)),
+        n_fhi=rng.choice(edge, size=(n, fn)),
+        s_req=rng.choice(words, size=(n, fs)),
+        s_forb=rng.choice(words, size=(n, fs)),
+        emb=rng.normal(size=(n, 16)).astype(np.float32),
+        min_count=rng.choice(np.array([1, 2, 2, 2], np.int32), size=n),
+        max_count=rng.choice(np.array([2, 2, 2, 3], np.int32), size=n),
+        party=rng.choice(np.array([0, 0, 0, 5, -7], np.int32), size=n),
+        pool_id=rng.choice(np.array([0, 0, 0, 0, 1], np.int32), size=n),
+        flags=rng.choice(
+            np.array([1, 3, 1 | device.FLAG_NEVER, 0, 1 | -(2**31)],
+                     np.int32),
+            size=n, p=[0.6, 0.25, 0.05, 0.05, 0.05],
+        ),
+        created=rng.integers(-(2**31), 2**31 - 1, size=n).astype(np.int32),
     )
     # most fields unconstrained, so that whole queries pass too
-    free = rng.random((r, b, fn)) < 1 - 1.0 / fn
-    q["n_lo"][free], q["n_hi"][free] = -np.inf, np.inf
-    free = rng.random((r, b, fn)) < 1 - 1.0 / fn
-    q["n_flo"][free], q["n_fhi"][free] = 1.0, -1.0
-    num[np.isnan(num) & (rng.random(num.shape) < 0.95)] = 0.5
+    free = rng.random((n, fn)) < 1 - 0.5 / fn
+    pool["n_lo"][free], pool["n_hi"][free] = -np.inf, np.inf
+    free = rng.random((n, fn)) < 1 - 0.5 / fn
+    pool["n_flo"][free], pool["n_fhi"][free] = 1.0, -1.0
+    pool["num"][np.isnan(pool["num"]) & (rng.random((n, fn)) < 0.95)] = 0.5
     for key in ("s_req", "s_forb"):
-        q[key][rng.random((r, b, fs)) < 1 - 0.7 / fs] = 0
-    flags = rng.choice(
-        np.array([1, 3, 1 | device.FLAG_NEVER], np.int32), size=(r, b),
-        p=[0.6, 0.3, 0.1],
-    )
-    scalars = rng.integers(-9, 9, size=(r, b, len(device2.RECORD_KEYS)))
-    ints = np.concatenate(
-        [scalars.astype(np.int32), q["s_req"], q["s_forb"]], axis=2
-    )
-    floats = np.concatenate(
-        [q[key] for key in ("n_lo", "n_hi", "n_flo", "n_fhi")], axis=2
-    )
-    rowq = dict(num=num, str=sv)
-    got = np.asarray(device2._mirror_accepts(ints, floats, rowq, flags))
+        pool[key][rng.random((n, fs)) < 1 - 0.4 / fs] = 0
+    pool["emb"][rng.random((n, 16)) < 0.1] = -0.0
+    pool["emb"][rng.random((n, 16)) < 0.05] = 1e-40
 
-    def one_row(colrow, qrow):
-        vals = {key: v[None] for key, v in qrow.items()}
-        return device2._accepts(colrow, vals, False)[0][0]
-
-    want = np.asarray(jax.vmap(one_row)(dict(q, flags=flags), rowq))
-    assert got.dtype == want.dtype == bool and got.shape == (r, b)
-    assert 0.02 < want.mean() < 0.9, want.mean()  # both answers are there
-    assert np.array_equal(got, want)
+    rows = rng.choice(n, size=r)
+    rowq = {key: v[rows] for key, v in pool.items()}
+    cand = rng.integers(0, n, size=(r, b))
+    layout = device2._stage2_tables(pool, rev, False, True)
+    blocks = [np.asarray(t)[cand]
+              for t in device2._stage2_record(pool, layout)]
+    got = device2._stage2_on_rows(layout, blocks, rowq, rev)
+    col = {key: v[cand] for key, v in pool.items()}
+    want = device2._stage2_by_column(col, rowq, rev, False, True)
+    ok, score, created = (np.asarray(x) for x in got)
+    assert ok.dtype == bool and ok.shape == (r, b)
+    assert 0.02 < np.asarray(want[0]).mean() < 0.9, want[0].mean()
+    assert np.array_equal(ok, np.asarray(want[0]))
+    assert score.tobytes() == np.asarray(want[1]).tobytes()
+    assert created.tobytes() == np.asarray(want[2]).tobytes()
 
 
 @pytest.mark.parametrize(
@@ -379,7 +403,10 @@ def test_mirror_accepts_is_accepts_on_whole_blocks(widths, seed):
         (131072, 62, 8192),  # the forward program, `ranked100k.burst`
         (262144, 62, 8192),  # ... at `multiqueue8x20k.burst`'s rows
         (65536, 62, 8192),  # ... at `squad50k.burst`'s
+        (262144, 46, 8192),  # ... without the embedding, as those two run
+        (65536, 46, 8192),
         (131072, 190, 2048),  # under rev, `mutual100k.burst`
+        (131072, 174, 2048),  # ... without the embedding
         (131072, 286, 1024),  # under rev with should queries
         (4096, 62, 4096),  # a dispatch smaller than one stripe: itself
         (1024, 286, 1024),
@@ -398,27 +425,66 @@ def test_stage2_stripe_at_shipped_widths(a_pad, words, want):
         8, cfg.numeric_fields, cfg.string_fields, cfg.max_constraints,
         cfg.embedding_dims,
     )
+    flags = ((False, False), (True, False), (True, True))
     assert [
-        device2.stage2_words(pool, rev, should)
-        for rev, should in ((False, False), (True, False), (True, True))
+        device2.stage2_words(pool, rev, should, True)
+        for rev, should in flags
     ] == [62, 190, 286]
+    # the embedding's 16 words travel only where the program scores it
+    assert [
+        device2.stage2_words(pool, rev, should, False)
+        for rev, should in flags
+    ] == [46, 174, 270]
     # without rev the should slots never travel, queries or not
-    assert device2.stage2_words(pool, False, True) == 62
+    assert device2.stage2_words(pool, False, True, True) == 62
 
 
 def test_stage2_tables_hold_what_the_program_reads():
-    from nakama_tpu.matchmaker import device2
+    """One row a candidate forward, two under rev, each of at most 128
+    words at shipped widths (the widest row a gather was measured at);
+    a row per table beside the record for pools with should queries."""
+    from nakama_tpu.matchmaker import device, device2
 
-    assert device2._stage2_tables(False, False) == ["num", "str", "emb"]
-    assert device2._stage2_tables(False, True) == ["num", "str", "emb"]
-    lone = device2._stage2_tables(True, False)
-    assert set(device2.MIRROR_KEYS) < set(lone)
-    assert not [key for key in lone if key.startswith("sh_")]
-    both = device2._stage2_tables(True, True)
-    assert both[: len(lone)] == lone
-    assert set(both) - set(lone) == set(device2.SHOULD_KEYS)
+    cfg = MatchmakerConfig()
+    pool = device.pool_schema(
+        8, cfg.numeric_fields, cfg.string_fields, cfg.max_constraints,
+        cfg.embedding_dims,
+    )
+
+    def tables(*flags):
+        return device2._stage2_tables(pool, *flags)
+
+    def widths(layout):
+        return [sum(device2._width(pool, key) for key in row)
+                for row in layout]
+
+    rec = device2.RECORD_KEYS
+    # the embedding leads its row: its lanes are where a block of it
+    # alone holds them
+    assert tables(False, False, True) == (("emb",) + rec + ("num", "str"),)
+    assert tables(False, False, False) == (rec + ("num", "str"),)
+    assert widths(tables(False, False, True)) == [62]
+    lone = tables(True, False, True)
+    assert lone == (
+        rec + ("s_req", "s_forb", "num", "str"),
+        ("emb", "n_lo", "n_hi", "n_flo", "n_fhi"),
+    )
+    assert widths(lone) == [78, 112]
+    assert all(pool[key].dtype == np.float32 for key in lone[1])
+    assert tables(True, False, False) == (lone[0], lone[1][1:])
+    # should pools: `_accepts` takes their slots by field from num, str
+    assert tables(False, True, True) == (rec, ("num",), ("str",), ("emb",))
+    both = tables(True, True, True)
+    assert [row[:1] for row in both[2:]] == [("num",), ("str",), ("emb",)]
+    assert both[0][:len(rec)] == rec
+    mirrors = set(both[0] + both[1]) - set(rec)
+    assert mirrors == set(device2.MIRROR_KEYS + device2.SHOULD_KEYS)
     assert len(device2.SHOULD_KEYS) == 6
-    assert all(key in device2.ROWQ_KEYS for key in both)
+    assert all(pool[key].dtype == np.int32 for key in both[0])
+    assert all(pool[key].dtype == np.float32 for key in both[1])
+    assert all(
+        key in device2.ROWQ_KEYS for row in both + lone for key in row
+    )
 
 
 @pytest.mark.parametrize(

@@ -176,20 +176,22 @@ def test_the_walks_counters_are_on_the_row(one_tick):
 
 
 def test_stage2s_shape_is_on_the_row(one_tick, config):
-    """Every two-stage cohort's row names the stripe its stage 2 ran in
-    and the words it gathered a kept winner, from the functions the
-    program itself calls: under `rev` the record, the values and the six
-    query mirrors (no should slot: the pool holds no should query),
-    without it the record and the values alone."""
-    from nakama_tpu.matchmaker import device2
+    """Every two-stage cohort's row names the stripe its stage 2 ran in,
+    the row gathers a kept winner cost and the words they brought, from
+    the functions the program itself calls: under `rev` two rows, the
+    scalars, values and int32 query mirrors on one, the embedding and
+    the f32 mirrors on the other (no should slot: the pool holds no
+    should query); without it one row of the scalars, the values and the
+    embedding."""
+    from nakama_tpu.matchmaker import device, device2
 
     rev, (_, row, (k,)) = one_tick
+    assert row["stage2_gathers"] == k["stage2_gathers"] == (2 if rev else 1)
     assert row["stage2_words"] == k["stage2_words"] == (190 if rev else 62)
-    pool = dict(zip(
-        device2._stage2_tables(rev, False),
-        [np.empty((1, w)) for w in (24, 16, 16) + (24,) * 4 + (16,) * 2],
-    ))
-    assert device2.stage2_words(pool, rev, False) == row["stage2_words"]
+    pool = device.pool_schema(1, 24, 16, 16, 16)
+    assert device2.stage2_words(pool, rev, False, True) == row["stage2_words"]
+    assert device2.stage2_shape(pool, 1, 256, 64, rev, False, True)[
+        "stage2_gathers"] == row["stage2_gathers"]
     # the rehearsal's dispatch is smaller than one stripe at either width
     assert row["stage2_stripe_rows"] == k["stage2_stripe_rows"] == k["a_pad"]
     keep = device2.stage2_keep(256, config["candidates_per_ticket"])
@@ -209,7 +211,10 @@ def test_a_pairs_cohort_carries_no_walk_counter():
         cfg, tickets, big_row_block=128, big_col_block=128)
     assert k["kernel"] == "topk_candidates_big+pair_partners" and k["rev"]
     assert matches and row["pairs_formed"] >= len(matches)
-    assert (row["stage2_words"], row["stage2_stripe_rows"]) == (190, 512)
+    # two rows under `rev`; the tickets carry no embedding, so its 16
+    # words stay off them
+    assert (row["stage2_gathers"], row["stage2_words"],
+            row["stage2_stripe_rows"]) == (2, 174, 512)
     assert not set(WALK) & set(row)
 
 
