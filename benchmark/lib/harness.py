@@ -137,32 +137,48 @@ class Ctx:
         """`mm.add(..., embedding=)`: the socket envelope has no
         embedding field, so a pool with embeddings is loaded through the
         matchmaker's public add, each presence bound to a registered
-        session. The ack is the call's return."""
+        session. The ack is the call's return. A spec with `count` > 1
+        is a party: `count` sessions, one presence each, in ONE add with
+        no session id and a party id of the benchmark's, as a party
+        leader's add makes it (match/party.py); every member session
+        holds the ticket. Returns every session, a party's each."""
         from nakama_tpu.matchmaker.types import MatchmakerPresence
 
         mm = self.mm
         out = []
         for k, spec in enumerate(specs):
-            s = self.new_session(spec, in_window)
-            p = MatchmakerPresence(
+            party = [self.new_session(spec, in_window)
+                     for _ in range(spec.get("count", 1))]
+            presences = [MatchmakerPresence(
                 user_id=s.user_id, session_id=s.id, username=s.username
-            )
-            s.due_t = time.perf_counter()
-            s.ticket, _ = mm.add(
-                [p], s.id, "", spec["query"], spec["min_count"],
-                spec["max_count"], 1, spec["strs"], spec["nums"],
+            ) for s in party]
+            due_t = time.perf_counter()
+            if len(party) == 1:
+                session_id, party_id = party[0].id, ""
+            else:
+                session_id, party_id = "", f"bp{self._seq}"
+            ticket, _ = mm.add(
+                presences, session_id, party_id, spec["query"],
+                spec["min_count"], spec["max_count"],
+                spec.get("count_multiple", 1), spec["strs"], spec["nums"],
                 embedding=spec.get("emb"),
             )
-            s.ack_t = time.perf_counter()
-            out.append(s)
+            ack_t = time.perf_counter()
+            for s in party:
+                s.due_t, s.ticket, s.ack_t = due_t, ticket, ack_t
+            out.extend(party)
             if k % 256 == 255:
                 await asyncio.sleep(0)  # let the server's loop run
         return out
 
     async def add_enveloped(self, s, due_t: float) -> None:
         """One `matchmaker_add` envelope through `Pipeline.process`, as the
-        socket read loop hands it over; timed from `due_t`."""
+        socket read loop hands it over; timed from `due_t`. One session's
+        add: a party's has no envelope here."""
         spec = s.spec
+        if spec.get("count", 1) > 1:
+            raise ValueError("add_enveloped adds one session's ticket;"
+                             " a party (count > 1) loads with add_direct")
         s.due_t = due_t
         self.attempted += 1
         t_a = time.perf_counter()

@@ -9,6 +9,13 @@ tickets at the same ticks (`reference.py`). Nothing here reads the
 program's own view of a match; a match is reconstructed from the
 envelopes alone.
 
+A session is one player. A party ticket (a spec with `count` > 1) is
+held by `count` sessions that all hold its ticket id: a match's players
+are grouped by that id, so a party that is split, a size outside one
+ticket's bounds or off its `count_multiple`, or a query that accepts
+only its own party is an invalid match; the plain matcher is fed one
+row a ticket; the yield counts players.
+
 Numbers compared, each with its limit (PERF.md section 2 gives the
 readings the limits were set from):
 
@@ -18,15 +25,36 @@ readings the limits were set from):
                         twice, to a stranger, or naming another ticket  0
   ingest_mismatch       members whose properties in the envelope are
                         not the ones their client sent                  0
-  yield_shortfall       share of the eligible tickets that the plain
+  yield_shortfall       share of the eligible players that the plain
                         matcher matched and the program did not, net    cell's
   similarity_shortfall  1 - the program's mean pair similarity over
-                        the plain matcher's (tickets with embeddings)   cell's
+                        the plain matcher's (tickets with embeddings;
+                        pairs of players of different tickets)          cell's
 """
 
 from __future__ import annotations
 
 from . import reference
+
+
+def ticket_of(s) -> str:
+    """The ticket a session's client holds; a session never acknowledged
+    stands alone."""
+    return s.ticket if s.ticket is not None else s.id
+
+
+def tickets_of(indices: list[int], specs: list[dict]) -> tuple[int, ...]:
+    """A match as the ticket index of each of its players, with a
+    party's index once: the form `reference.mean_pair_similarity` reads."""
+    seen = set()
+    out = []
+    for i in indices:
+        if specs[i].get("count", 1) > 1:
+            if i in seen:
+                continue
+            seen.add(i)
+        out.append(i)
+    return tuple(out)
 
 
 def judge(sessions, rev: bool, eligible, limits: dict, ticks, k: int,
@@ -103,22 +131,30 @@ def judge(sessions, rev: bool, eligible, limits: dict, ticks, k: int,
             members.append(dict(
                 session=s.id, query=spec["query"],
                 min_count=spec["min_count"], max_count=spec["max_count"],
-                strs=strs, nums=nums,
+                strs=strs, nums=nums, ticket=ticket_of(s),
+                count=spec.get("count", 1),
+                count_multiple=spec.get("count_multiple", 1),
             ))
         fault = reference.match_fault(members, rev)
         if fault:
             invalid += 1
             note(f"match {key[:12]} of {len(members)}: {fault}")
 
-    # The plain matcher over the same tickets at the same ticks.
-    pooled = sorted((s for s in sessions if s.ack_t is not None),
-                    key=lambda s: (s.ack_t, s.id))
-    index = {s.id: i for i, s in enumerate(pooled)}
-    specs = [s.spec for s in pooled]
+    # The plain matcher over the same tickets at the same ticks: one
+    # row a ticket, a party's sessions together, oldest first.
+    held: dict[str, list] = {}
+    for s in sorted((s for s in sessions if s.ack_t is not None),
+                    key=lambda s: (s.ack_t, s.id)):
+        held.setdefault(ticket_of(s), []).append(s)
+    pooled = sorted(held.values(), key=lambda p: (p[0].ack_t, p[0].id))
+    index = {s.id: i for i, party in enumerate(pooled) for s in party}
+    specs = [party[0].spec for party in pooled]
     plain = reference.replay(
-        specs, [s.ack_t for s in pooled], ticks, k, rev, max_intervals
+        specs, [party[0].ack_t for party in pooled], ticks, k, rev,
+        max_intervals
     ) if len(pooled) >= 2 else []
     plain_matched = {i for g in plain for i in g}
+    # The yield counts players: a party's sessions each.
     eligible = [s for s in eligible if s.id in index]
     shortfall = 0.0
     n_plain = sum(1 for s in eligible if index[s.id] in plain_matched)
@@ -129,7 +165,8 @@ def judge(sessions, rev: bool, eligible, limits: dict, ticks, k: int,
     want = reference.mean_pair_similarity(specs, plain)
     if want:
         formed = [
-            tuple(index[u["presence"]["session_id"]] for u in rec["users"])
+            tickets_of([index[u["presence"]["session_id"]]
+                        for u in rec["users"]], specs)
             for rec in matches.values()
             if len(rec["users"]) >= 2 and all(
                 u["presence"]["session_id"] in index for u in rec["users"])
@@ -166,25 +203,41 @@ def judge(sessions, rev: bool, eligible, limits: dict, ticks, k: int,
 def delivered(specs: list[dict], groups, ack_t) -> list:
     """Sessions as a run leaves them, had `groups` (tuples of indices
     into `specs`) been delivered as matches: how the plain matcher and
-    the controls are put in the program's place."""
+    the controls are put in the program's place. A ticket's `count`
+    sessions all hold it (`t<index>`), in ticket order, and each of them
+    gets its match's envelope."""
     from .session import BenchSession
 
     out = []
+    parties = []
     for i, spec in enumerate(specs):
-        s = BenchSession(i, spec, True)
-        s.ticket = f"t{i}"
-        s.ack_t = ack_t[i]
-        out.append(s)
+        party = []
+        for _ in range(spec.get("count", 1)):
+            s = BenchSession(len(out), spec, True)
+            s.ticket = f"t{i}"
+            s.ack_t = ack_t[i]
+            out.append(s)
+            party.append(s)
+        parties.append(party)
     for n, group in enumerate(groups):
+        members = [(m, s) for m in group for s in parties[m]]
         users = [{
-            "presence": {"user_id": out[m].user_id, "session_id": out[m].id,
-                         "username": out[m].username},
-            "party_id": "", "string_properties": specs[m]["strs"],
+            "presence": {"user_id": s.user_id, "session_id": s.id,
+                         "username": s.username},
+            "party_id": f"p{m}" if len(parties[m]) > 1 else "",
+            "string_properties": specs[m]["strs"],
             "numeric_properties": specs[m]["nums"],
-        } for m in group]
-        for m, user in zip(group, users):
-            out[m].send({"matchmaker_matched": {
-                "ticket": out[m].ticket, "users": users, "token": f"tok{n}",
+        } for m, s in members]
+        for (_, s), user in zip(members, users):
+            s.send({"matchmaker_matched": {
+                "ticket": s.ticket, "users": users, "token": f"tok{n}",
                 "self": {"presence": user["presence"]},
             }})
     return out
+
+
+def players(sessions: list, tickets) -> list:
+    """The sessions `delivered` made for the tickets at these indices
+    into its `specs`: a party's each."""
+    held = {f"t{i}" for i in tickets}
+    return [s for s in sessions if s.ticket in held]

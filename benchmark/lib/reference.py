@@ -12,22 +12,43 @@ unchecked:
     +properties.<field>:>=<number>  numeric property >= number
     +properties.<field>:<=<number>  numeric property <= number
 
+A spec is one ticket as its client sent it: `query`, `min_count`,
+`max_count`, `strs`, `nums`, optionally `emb`, and optionally `count`,
+the players on the ticket (a party; default 1), and `count_multiple`
+(default 1). A spec without the last two is one player and multiple 1,
+exactly as before they existed. Sizes are counted in players (entries),
+never in tickets.
+
 A match is valid (the reference server's semantics, matchmaker_process.go
-processDefault, `rev_precision` off) when its sessions are distinct, its
-size lies inside every member's [min_count, max_count], and SOME
-member's query (the active ticket that searched) accepts every other
-member; with `rev_precision` on, every other member's query must accept
-it back.
+processDefault, `rev_precision` off) when its sessions are distinct,
+every ticket in it is there with all of its players, its size lies
+inside every ticket's [min_count, max_count] and is a multiple of every
+ticket's count_multiple, and SOME ticket's query (the active ticket that
+searched) accepts every player of the other tickets (its own party's
+are skipped); with `rev_precision` on, each of those other tickets'
+queries must accept it back.
 
 `replay` is the plain matcher: the reference server's interval loop
-restated over whole arrays. At every tick the tickets acknowledged since
-the last one search (a ticket whose min_count equals its max_count
-searches once, any other for `max_intervals` ticks); each, oldest
-first, lists its `k` best candidates still in the pool, by embedding
-similarity in float32 where the tickets carry one and oldest first
-among equals, and takes the first free ones until its match is full.
-The candidate lists are computed in blocks of rows (jax.numpy, on
-whatever backend the run has, after the program's state is freed).
+restated over whole arrays, one row a ticket. At every tick the tickets
+acknowledged since the last one search (a ticket whose min_count equals
+its max_count searches once, any other for `max_intervals` ticks); each,
+oldest first, lists its `k` best candidates still in the pool, by
+embedding similarity in float32 where the tickets carry one and oldest
+first among equals, and walks them best first: a free candidate is
+taken when the size with its players stays within the searcher's
+max_count, skipped otherwise, and the walk stops at max_count. A size
+that is no multiple of the searcher's count_multiple is trimmed by one
+group of taken tickets whose players sum to the remainder exactly, the
+oldest such group by mean acknowledgement order (upstream's
+groupIndexes, server/matchmaker.go:132-167, and
+matchmaker_process.go:237-281); then every ticket's bounds and multiple
+are checked again, the searcher's own bounds among them (the deliberate
+fix the program states too), and a match that fails is not formed.
+Where upstream keeps several combos a searcher and a hit that does not
+fit one opens the next, the plain matcher keeps ONE: what its walk takes
+is the match or nothing. The candidate lists are computed in blocks of
+rows (jax.numpy, on whatever backend the run has, after the program's
+state is freed).
 
 With its keywords `replay` is also the CONTROLS, the shortcuts a later
 change would be tempted by; each has to come out as not correct
@@ -42,7 +63,9 @@ change would be tempted by; each has to come out as not correct
 from __future__ import annotations
 
 import math
+import operator
 import re
+from collections import Counter
 
 import numpy as np
 
@@ -96,18 +119,29 @@ def accepts(terms, strs: dict, nums: dict) -> bool:
 
 def match_fault(members: list[dict], rev: bool) -> str | None:
     """None when the match is valid, else what is wrong with it. A
-    member is {"session", "query", "min_count", "max_count", "strs",
-    "nums"}: the query and counts as the client SENT them, the
-    properties as the match envelope carries them."""
+    member is one player: {"session", "query", "min_count", "max_count",
+    "strs", "nums"} and optionally "ticket" (the one its client holds;
+    default its session), "count" and "count_multiple" (default 1): the
+    query and counts as the client SENT them, the properties as the
+    match envelope carries them."""
     sessions = [m["session"] for m in members]
     if len(set(sessions)) != len(sessions):
         return "a session twice in one match"
     size = len(members)
     if size < 2:
         return "a match of one"
+    tickets = [m.get("ticket", m["session"]) for m in members]
+    held = Counter(tickets)
+    for m, t in zip(members, tickets):
+        if held[t] != m.get("count", 1):
+            return (f"ticket {t} split: {held[t]} of its {m.get('count', 1)}"
+                    " players in the match")
     for m in members:
         if not m["min_count"] <= size <= m["max_count"]:
             return f"size {size} outside [{m['min_count']}, {m['max_count']}]"
+    for m in members:
+        if size % m.get("count_multiple", 1):
+            return f"size {size} not a multiple of {m['count_multiple']}"
     terms = [parse(m["query"]) for m in members]
 
     def ok(i, j):
@@ -116,7 +150,7 @@ def match_fault(members: list[dict], rev: bool) -> str | None:
     for i in range(size):
         if all(
             ok(i, j) and (not rev or ok(j, i))
-            for j in range(size) if j != i
+            for j in range(size) if tickets[j] != tickets[i]
         ):
             return None
     return "no member's query accepts the others"
@@ -187,6 +221,19 @@ def encode(specs: list[dict]) -> dict:
 
 def has_embeddings(specs: list[dict]) -> bool:
     return bool(specs) and specs[0].get("emb") is not None
+
+
+def counts(specs: list[dict]) -> tuple[list[int], list[int]]:
+    """Each ticket's players and count_multiple, checked as the program
+    refuses an add: at least one of each, no more players than
+    max_count."""
+    count = [operator.index(s.get("count", 1)) for s in specs]
+    mult = [operator.index(s.get("count_multiple", 1)) for s in specs]
+    for s, c, m in zip(specs, count, mult):
+        if not (1 <= c <= s["max_count"] and m >= 1):
+            raise ValueError(f"bad count {c} or count_multiple {m}"
+                             f" for max_count {s['max_count']}")
+    return count, mult
 
 
 def _exact32(x: float) -> None:
@@ -290,13 +337,14 @@ def replay(specs: list[dict], ack_t, ticks, k: int, rev: bool,
            max_intervals: int = 2, precision: str = "float32",
            use_emb: bool = True) -> list[tuple[int, ...]]:
     """The matches a plain matcher forms: tuples of indices into `specs`,
-    the ticket that searched last. `specs` are oldest first and `ack_t`
-    (when each entered the pool) ascending; `ticks` are the times the
-    interval loop ran."""
+    one a ticket, the ticket that searched last. `specs` are oldest
+    first and `ack_t` (when each entered the pool) ascending; `ticks`
+    are the times the interval loop ran."""
     n = len(specs)
     ack_t = np.asarray(ack_t, float)
     if n and np.any(np.diff(ack_t) < 0):
         raise ValueError("specs are not oldest first")
+    count, mult = counts(specs)
     enc = encode(specs)
     search = Candidates(enc, k, rev, precision, use_emb)
     min_c, max_c = enc["min_c"].tolist(), enc["max_c"].tolist()
@@ -325,15 +373,30 @@ def replay(specs: list[dict], ack_t, ticks, k: int, rev: bool,
             if not last:
                 searching.append(a)
             got = []
+            size = count[a]
             for j in row:
-                if j < 0 or len(got) + 1 == max_c[a]:
+                if j < 0 or size == max_c[a]:
                     break
-                if free[j]:
+                if free[j] and size + count[j] <= max_c[a]:
                     got.append(j)
-            size = len(got) + 1
-            if size < 2 or not (
+                    size += count[j]
+            if not got or not (
                 size == max_c[a] or (last and size >= min_c[a])
-            ) or any(not min_c[j] <= size <= max_c[j] for j in got):
+            ):
+                continue
+            rem = size % mult[a]
+            if rem:
+                drop = oldest_group(got, count, rem)
+                if drop is None:
+                    continue
+                # What is left may be the searcher's party alone, as
+                # upstream and the program leave it; never one player.
+                got = [j for j in got if j not in drop]
+                size -= rem
+                if size < 2 or not min_c[a] <= size <= max_c[a]:
+                    continue
+            if any(not min_c[j] <= size <= max_c[j] or size % mult[j]
+                   for j in got):
                 continue
             for j in got:
                 free[j] = False
@@ -343,16 +406,57 @@ def replay(specs: list[dict], ack_t, ticks, k: int, rev: bool,
     return groups
 
 
+def oldest_group(got: list[int], count: list[int], rem: int):
+    """The set of tickets of `got` whose players sum to `rem` exactly
+    with the least mean index (acknowledgement order), or None. Groups
+    are listed as upstream's groupIndexes lists them, each ticket taken
+    before it is left out, in `got`'s order; the first of equal means
+    wins."""
+    pick = [j for j in got if count[j] <= rem]
+    best, best_mean = None, None
+
+    def walk(i: int, left: int, cur: list[int]) -> None:
+        nonlocal best, best_mean
+        if left == 0:
+            mean = sum(cur) / len(cur)
+            if best is None or mean < best_mean:
+                best, best_mean = set(cur), mean
+            return
+        if i == len(pick):
+            return
+        if count[pick[i]] <= left:
+            cur.append(pick[i])
+            walk(i + 1, left - count[pick[i]], cur)
+            cur.pop()
+        walk(i + 1, left, cur)
+
+    walk(0, rem, [])
+    return best
+
+
 def mean_pair_similarity(specs: list[dict], groups) -> float | None:
-    """Mean, over matches, of the mean embedding dot product over all
-    pairs of a match's members (f64)."""
+    """Mean, over matches, of the mean embedding dot product over the
+    pairs of a match's players that belong to different tickets (f64);
+    `groups` list a match's tickets once each, and a ticket weighs by
+    its players. A party matched alone has no such pair and is left
+    out; None where no match has one."""
     if not groups or not has_embeddings(specs):
         return None
     emb = np.stack([np.asarray(s["emb"], np.float64) for s in specs])
-    total = 0.0
+    count = np.asarray(counts(specs)[0])
+    total, n = 0.0, 0
     for g in groups:
         e = emb[list(g)]
         gram = e @ e.T
-        m = len(g)
-        total += (gram.sum() - np.trace(gram)) / (m * (m - 1))
-    return total / len(groups)
+        c = count[list(g)]
+        if (c == 1).all():  # solo tickets: the same sums, bit for bit
+            m = len(g)
+            total += (gram.sum() - np.trace(gram)) / (m * (m - 1))
+        else:
+            w = np.outer(c, c)
+            np.fill_diagonal(w, 0)
+            if not w.any():
+                continue
+            total += (gram * w).sum() / w.sum()
+        n += 1
+    return total / n if n else None

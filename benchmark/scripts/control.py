@@ -30,7 +30,7 @@ sys.path.insert(0, ROOT)
 sys.path.insert(0, BENCH)
 
 from lib import harness, reference  # noqa: E402
-from lib.judge import delivered, judge  # noqa: E402
+from lib.judge import delivered, judge, players  # noqa: E402
 
 
 def window_tickets(bench, config, traffic, recipe, seed):
@@ -77,7 +77,7 @@ def main(argv) -> int:
             groups = reference.replay(
                 specs, ack, ticks, kw.pop("k", k), rev, mi, **kw)
             sessions = delivered(specs, groups, ack)
-            v = judge(sessions, rev, [sessions[i] for i in eligible],
+            v = judge(sessions, rev, players(sessions, eligible),
                       traffic["limits"], ticks, k, mi)
             print(json.dumps({
                 "cell": cell_name, "seed": seed, "way": way,

@@ -25,7 +25,7 @@ sys.path.insert(0, ROOT)
 sys.path.insert(0, BENCH)
 
 from lib import harness, reference  # noqa: E402
-from lib.judge import delivered, judge  # noqa: E402
+from lib.judge import delivered, judge, players  # noqa: E402
 
 
 def main(argv) -> int:
@@ -49,7 +49,7 @@ def main(argv) -> int:
         t = time.perf_counter()
         groups = reference.replay(specs, ack, ticks, k, False, mi)
         sessions = delivered(specs, groups, ack)
-        v = judge(sessions, True, [sessions[i] for i in eligible],
+        v = judge(sessions, True, players(sessions, eligible),
                   traffic["limits"], ticks, k, mi)
         print(json.dumps({
             "cell": cell_name, "seed": seed, "way": "norev",

@@ -96,6 +96,9 @@ def main(argv) -> int:
     ack = np.arange(n) * 1e-6
     for seed in seeds:
         specs = recipe.specs([seed, 0], n, config["recipe_params"])
+        if any(s.get("count", 1) > 1 or s.get("count_multiple", 1) > 1
+               for s in specs):
+            raise SystemExit("the walk restates solo tickets only")
         t = time.perf_counter()
         walked = walk(specs, mi, mi)
         walk_s = time.perf_counter() - t

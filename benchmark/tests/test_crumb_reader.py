@@ -119,6 +119,6 @@ def test_metric_file_loads_and_names_what_its_reader_takes(name):
     assert entries[name]["source"] == (
         "program_counter" if name == "ingest.adds_counted"
         else "program_span")
-    assert entries[name]["workloads"] == [
+    assert (
         "ranked100k.burst" if name.endswith(".burst") else "duel1k.steady"
-    ]
+    ) in entries[name]["workloads"]
