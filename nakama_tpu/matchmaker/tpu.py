@@ -253,7 +253,7 @@ class Cohort:
             )
         walk, self.walk = self.walk, None
         if walk is not None:
-            # What validation did to the assembler's walk (the two
+            # What validation and counted tickets did to the walk (the
             # refusal sums are 0 unless the cohort ran under `rev`).
             out.update(zip(native.WALK_COUNTERS, walk.tolist()))
         pairs, self.pairs = self.pairs, None
@@ -1544,13 +1544,13 @@ class TpuBackend(ProcessBackend):
         dispatch) — and what the assembler made of them:
         `actives_unmatched` (searchers in no match), `matches_below_max`
         (matches smaller than their searcher's max_count), and the
-        four sums the native assembler's call returned for what
-        validation did to its walk (native.WALK_COUNTERS, described
-        there): `hits_walked`, `hits_rev_refused`,
-        `hits_combo_conflicts`, `matches_needing_host`; the middle two
-        are 0 unless the cohort ran under `rev`. O(actives x
-        k) numpy, which is why no stage between dispatch and publish
-        pays for it. A pairs cohort never walks (none of the four) and
+        sums the native assembler's call returned for what validation
+        and counted tickets did to its walk (native.WALK_COUNTERS, all
+        described there): `hits_walked` ... `matches_needing_host`,
+        `hits_overflow`, `trims`, `trims_failed`, `matches_trimmed`;
+        the refusals are 0 unless the cohort ran under `rev`. O(actives
+        x k) numpy, which is why no stage between dispatch and publish
+        pays for it. A pairs cohort never walks (none of these) and
         its lists never leave the device: of the rest it gets the last
         two, `candidates_valid` and `candidates_pool` as
         `pair_partners` counted them there (no `candidates_distinct`),

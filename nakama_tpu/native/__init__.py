@@ -216,11 +216,18 @@ def _ptr(arr: np.ndarray, dtype) -> ctypes.c_void_p:
 # walk reached (a ticket, not the searcher, not yet in a match), hits
 # whose own query refused the searcher (mutual validation), combos a hit
 # was kept out of by the pairwise query check, matches flagged for the
-# host's AST check. The cohort's ledger row carries them under these
-# names (tpu.Cohort.list_counts).
+# host's AST check; then the counted fill and the trim: hits some combo
+# turned away for room (its players, the hit's and the searcher's would
+# pass the searcher's max_count), combos at a formable size off the
+# searcher's count_multiple (a trim tried), of those the ones with no
+# exact group to drop (no match formed), matches emitted after a trim.
+# The last four read 0 where every ticket is solo, of multiple 1 and of
+# one [min_count, max_count]. The cohort's ledger row carries them under
+# these names (tpu.Cohort.list_counts).
 WALK_COUNTERS = (
     "hits_walked", "hits_rev_refused", "hits_combo_conflicts",
-    "matches_needing_host",
+    "matches_needing_host", "hits_overflow", "trims", "trims_failed",
+    "matches_trimmed",
 )
 
 
@@ -242,9 +249,9 @@ def assemble_arrays(
 ) -> tuple[int, np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
     """Greedy assembly with in-loop exact match validation; returns
     (n_matches, offsets i32 [n+1], flat slot array, needs_host u8 [n],
-    walk i64 [4]) — needs_host marks matches containing members without
-    exact query mirrors under mutual validation (caller AST-validates
-    those); walk holds the call's WALK_COUNTERS sums."""
+    walk i64 [len(WALK_COUNTERS)]) — needs_host marks matches containing
+    members without exact query mirrors under mutual validation (caller
+    AST-validates those); walk holds the call's WALK_COUNTERS sums."""
     lib = load()
     a = len(active_slots)
     if a == 0:

@@ -174,11 +174,17 @@ extern "C" {
 //   out_needs_host: [max_matches] 1 where a match involved a ticket with
 //                no exact query mirror (host-only member under mutual
 //                validation) — the caller AST-validates those on host.
-//   out_walk:    [4] sums over the call, what validation did to the walk:
+//   out_walk:    [8] sums over the call, what validation did to the walk:
 //                hits the walk reached (not -1, not self, not already
 //                selected), hits whose own query refused the searcher
 //                (mutual validation), combos a hit was kept out of by the
-//                pairwise query check, matches flagged in out_needs_host.
+//                pairwise query check, matches flagged in out_needs_host;
+//                then what counted tickets did to it: hits some combo
+//                turned away because its players, the hit's and the
+//                searcher's would pass the searcher's max_count, combos
+//                at a formable size off the searcher's count_multiple (a
+//                trim tried), of those the ones with no exact group to
+//                drop (no match), and matches emitted after a trim.
 // A return of -1 means the output buffers were too small.
 int32_t mm_assemble(
     // Active rows, already ordered oldest-first.
@@ -215,7 +221,8 @@ int32_t mm_assemble(
     int32_t n_matches = 0;
     int64_t slots_used = 0;
     int64_t hits_walked = 0, hits_rev_refused = 0, hits_combo_conflicts = 0,
-            matches_needing_host = 0;
+            matches_needing_host = 0, hits_overflow = 0, trims = 0,
+            trims_failed = 0, matches_trimmed = 0;
     out_offsets[0] = 0;
 
     // Scratch combo storage: combos of ticket slots (entry counts tracked).
@@ -246,7 +253,9 @@ int32_t mm_assemble(
                 !(size >= active.min_count && size <= active.max_count))
                 return false;
             int32_t rem = size % active.count_multiple;
-            if (rem != 0) {
+            bool trimmed = rem != 0;
+            if (trimmed) {
+                ++trims;
                 // Trim an exact-size group: drop the group with the
                 // smallest average created_at, matching the reference's
                 // observed behavior (ascending sort, remove index 0 —
@@ -257,7 +266,10 @@ int32_t mm_assemble(
                 std::vector<Group> groups;
                 std::vector<int32_t> cur;
                 group_tickets(pool, eligible, 0, rem, cur, groups);
-                if (groups.empty()) return false;
+                if (groups.empty()) {
+                    ++trims_failed;
+                    return false;
+                }
                 const Group* best = &groups[0];
                 for (const Group& g : groups)
                     if (g.avg_created < best->avg_created) best = &g;
@@ -306,6 +318,7 @@ int32_t mm_assemble(
             selected[aslot] = 1;
             out_needs_host[n_matches] = needs_host;
             matches_needing_host += needs_host;
+            matches_trimmed += trimmed;
             ++n_matches;
             out_offsets[n_matches] = static_cast<int32_t>(slots_used);
             combos.erase(combos.begin() + found_idx);
@@ -353,6 +366,7 @@ int32_t mm_assemble(
             std::vector<int32_t>* found = nullptr;
             size_t found_idx = 0;
             bool h_exact = ex.exact_ok[hslot];
+            bool no_room = false;
             for (size_t c = 0; c < combos.size(); ++c) {
                 int32_t combo_entries = 0;
                 bool conflict = false;
@@ -367,13 +381,16 @@ int32_t mm_assemble(
                 }
                 if (conflict) continue;
                 if (combo_entries + hit.count + active.count >
-                    active.max_count)
+                    active.max_count) {
+                    no_room = true;
                     continue;
+                }
                 combos[c].push_back(hslot);
                 found = &combos[c];
                 found_idx = c;
                 break;
             }
+            hits_overflow += no_room;
             if (!found) {
                 combos.push_back({hslot});
                 found = &combos.back();
@@ -398,6 +415,10 @@ int32_t mm_assemble(
     out_walk[1] = hits_rev_refused;
     out_walk[2] = hits_combo_conflicts;
     out_walk[3] = matches_needing_host;
+    out_walk[4] = hits_overflow;
+    out_walk[5] = trims;
+    out_walk[6] = trims_failed;
+    out_walk[7] = matches_trimmed;
     return overflow ? -1 : n_matches;
 }
 }

@@ -204,6 +204,15 @@ def test_big_kernel_squad_pool_dispatch(one_chip, rows):
     )[0] == 2
 
 
+@pytest.mark.parametrize("rows", [1 << 16, 2048])
+def test_big_kernel_party_pool_dispatch(one_chip, rows):
+    """`party50k.burst`'s two dispatches: 50,000 tickets of one to five
+    players, each with an embedding, pad to 65,536 rows against 65,536
+    columns; the fill tick sends the leftovers against the same
+    columns."""
+    _compile_big(one_chip, rows, 1 << 16, emb=True, rev=False)
+
+
 @pytest.mark.parametrize("str_cols", [(), (2,)])
 def test_big_kernel_sharded_mutual(topo, str_cols):
     """The 4-device mesh variant with embeddings and mutual matching,

@@ -293,7 +293,8 @@ def test_a_cohort_of_fewer_rows_than_rounds_keeps_every_round():
 
 # A cohort row's keys at the parent (commit 3bdfc14) for a pool under
 # `big_pool_threshold`: exact kernel, native assembler, pipelined; and
-# the assembler's four walk counters, on every assembled row since PR 33;
+# the assembler's walk counters, on every assembled row (validation's,
+# then the counted fill's and the trim's);
 # since PR 35 the worker's and the delivery call's CPU beside their wall
 # and the gap passes that ran during the flight.
 SMALL_PATH_ROW_KEYS = {
@@ -307,6 +308,7 @@ SMALL_PATH_ROW_KEYS = {
     "fetch_lag_s", "hits_combo_conflicts", "hits_rev_refused",
     "hits_walked", "interval_seq", "matches", "matches_below_max",
     "matches_needing_host", "publish_gc_collections", "publish_lag_s", "ready_lag_s", "seq",
+    "hits_overflow", "trims", "trims_failed", "matches_trimmed",
     "slipped", "status", "trace_id", "ts",
 }
 

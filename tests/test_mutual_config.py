@@ -242,11 +242,12 @@ def test_assemble_arrays_returns_the_four_sums():
         np.array([a], np.int32), np.array([1], np.uint8), cand, True)
     assert dict(zip(WALK, walk.tolist())) == dict(
         hits_walked=2, hits_rev_refused=1, hits_combo_conflicts=0,
-        matches_needing_host=0)
+        matches_needing_host=0, hits_overflow=0, trims=0, trims_failed=0,
+        matches_trimmed=0)
     assert n == 1 and flat[:2].tolist() == [c, a] and ok.all()
     (n, _, flat, _), walk = backend._assemble(
         np.array([a], np.int32), np.array([1], np.uint8), cand, False)
-    assert walk.tolist() == [1, 0, 0, 0] and flat[:2].tolist() == [b, a]
+    assert walk.tolist() == [1] + [0] * 7 and flat[:2].tolist() == [b, a]
     mm.stop()
 
 
